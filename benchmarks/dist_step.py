@@ -40,6 +40,8 @@ def main():
                          "Pallas kernel path (interpret mode on CPU)")
     ap.add_argument("--out", default=BENCH_DISTRIBUTED_STEP_JSON)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.launch.diststep import measure_distributed_step
     rec = measure_distributed_step(args.n_devices, use_kernel=args.kernel,

@@ -37,6 +37,8 @@ def main(argv=None):
     ap.add_argument("--n-devices", type=int, default=8)
     ap.add_argument("--out", default=BENCH_ELASTIC_JSON)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.launch.diststep import measure_elastic
     rec = measure_elastic(args.n_devices)

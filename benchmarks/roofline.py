@@ -24,7 +24,10 @@ import jax
 
 from repro.configs import get_config
 from repro.configs.base import INPUT_SHAPES, ModelConfig
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import peaks
+
+# the dry-run's production mesh is v5e (launch/mesh.py)
+V5E = peaks("TPU v5 lite")
 
 
 def param_counts(cfg: ModelConfig):
@@ -99,9 +102,9 @@ def analyze(rec: Dict) -> Dict:
     flops_dev = rec.get("flops", rec.get("flops_raw", 0.0))
     bytes_dev = max(rec.get("bytes", 0.0), rec.get("bytes_raw", 0.0))
     coll_dev = sum(rec.get("collectives", {}).values())
-    t_comp = flops_dev / PEAK_FLOPS_BF16
-    t_mem = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    t_comp = flops_dev / V5E["flops_bf16"]
+    t_mem = bytes_dev / V5E["hbm_bw"]
+    t_coll = coll_dev / V5E["ici_bw"]
     cfg = get_config(rec["arch"])
     mf = model_flops(cfg, rec["shape"])
     useful = mf / max(flops_dev * chips, 1.0)

@@ -24,6 +24,7 @@ from repro.configs.base import D2FTConfig
 from repro.core.cost_model import comm_cost, compute_cost, workload_variance
 from repro.core.knapsack import scalarized_select
 from repro.core.schedule import Schedule, merge_tables
+from repro.launch.compile_cache import enable_compile_cache
 from benchmarks import common
 from benchmarks.common import (VIT, N_MB, d2ft_schedule_fn,
                                dpruning_schedule_fn, emit, gshard_schedule_fn,
@@ -556,6 +557,17 @@ def bench_kernel_backward():
 
 
 # ------------------------------------------- distributed-step comm savings
+def _refuse_on_tpu(name: str):
+    """The distributed_step / elastic entries are 8-host-device CPU
+    emulations run in a child process. On a TPU backend this process holds
+    the chip, so the child could only measure the CPU: refuse instead."""
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            f"{name} is an 8-host-device CPU emulation and does not run on "
+            "a TPU backend (this process holds the chip; a child would "
+            "measure the CPU). Run it where JAX_PLATFORMS=cpu.")
+
+
 def bench_distributed_step():
     """Paper Eq. 4 executed: the shard_map gated train step on an
     8-host-device CPU mesh over a schedule x sync-mode matrix — paper-mix
@@ -571,6 +583,7 @@ def bench_distributed_step():
     import os
     import subprocess
 
+    _refuse_on_tpu("distributed_step")
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
     # dist_step.py appends the host-device-count flag to XLA_FLAGS itself
@@ -600,6 +613,7 @@ def bench_elastic():
     import os
     import subprocess
 
+    _refuse_on_tpu("elastic")
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run([sys.executable, "-m", "benchmarks.elastic"],
@@ -662,6 +676,7 @@ def main() -> None:
         # exit 0, which reads as "all green" in a script
         ap.error(f"unknown benchmark(s): {', '.join(unknown)}\n"
                  f"valid names: {', '.join(BENCHES)}")
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for n in names:
         BENCHES[n]()

@@ -183,6 +183,8 @@ def main(argv=None) -> None:
                          "kernel (interpret mode on CPU)")
     ap.add_argument("--out", default=BENCH_SERVING_JSON)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     payload = run_serving_bench(use_kernel=args.use_kernel)
     with open(args.out, "w") as f:

@@ -68,3 +68,11 @@ def live_permutation(gate_flat, n_dispatch: int):
     gate 0 and are skipped block-level inside the kernels."""
     dead = (gate_flat == 0).astype(jnp.int32)
     return jnp.argsort(dead, stable=True)[:n_dispatch]
+
+
+def gate_operand(gate_flat):
+    """Gates as the kernels' int32 scalar-prefetch operand: the whole [n]
+    vector sits in SMEM and each grid step reads its slice's gate as a
+    scalar (``gate_ref[pl.program_id(0)]``) — the TPU lowering has no
+    (1, 1) VMEM block of an (n, 1) array."""
+    return (gate_flat != 0).astype(jnp.int32)

@@ -64,7 +64,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import contract as _contract
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -2.0 ** 30
 # logsumexp stored for rows that never saw a live key: large *positive* so
@@ -135,7 +134,7 @@ def _fwd_kernel(gate_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                 block_q: int, block_k: int, n_k: int, seq_len: int):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
-    gate = gate_ref[0, 0]
+    gate = gate_ref[pl.program_id(0)]
 
     @pl.when(ik == 0)
     def _init():
@@ -160,22 +159,21 @@ def _fwd_kernel(gate_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         mask = _tile_mask(qpos0, kpos0, block_q, block_k, seq_len, causal,
                           window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                            # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + \
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + \
             jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
         m_ref[...] = m_new
 
     @pl.when(ik == n_k - 1)
     def _finalize():
-        l = l_ref[...]
+        l = l_ref[...]                                 # [bq, 1]
         safe = jnp.where(l > 0, l, 1.0)
-        out = acc_ref[...] / safe[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
-        out = out * gate.astype(jnp.float32)
+        out = jnp.where(l > 0, acc_ref[...] / safe, 0.0)
+        out = out * (gate != 0).astype(jnp.float32)
         o_ref[0] = out.astype(o_ref.dtype)
         lse_ref[0] = jnp.where(l > 0, m_ref[...] + jnp.log(safe),
                                LSE_MASKED)
@@ -184,7 +182,7 @@ def _fwd_kernel(gate_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
 def _forward(q, k, v, g_f, *, causal: bool, window: int, block_q: int,
              block_k: int, interpret: bool, seq_len: int = 0,
              live: int = None):
-    """Returns (o [B,H,S,hd], lse [B,H,S] f32). seq_len is the true length
+    """Returns (o [B,H,S,hd], lse [B,H,S,1] f32). seq_len is the true length
     when the arrays carry tile padding (0 means unpadded). ``live`` is the
     static live-slice upper bound enabling compaction dispatch."""
     B, H, S, hd = q.shape
@@ -211,30 +209,36 @@ def _forward(q, k, v, g_f, *, causal: bool, window: int, block_q: int,
     _report_dispatch("fwd", grid)
     o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s, iq, ik: (s, 0)),            # g_f
-            pl.BlockSpec((1, block_q, hd), lambda s, iq, ik: (s, iq, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda s, iq, ik: (s, ik, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda s, iq, ik: (s, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, hd), lambda s, iq, ik: (s, iq, 0)),
-            pl.BlockSpec((1, block_q), lambda s, iq, ik: (s, iq)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                   # g_f
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, hd),
+                             lambda s, iq, ik, g: (s, iq, 0)),
+                pl.BlockSpec((1, block_k, hd),
+                             lambda s, iq, ik, g: (s, ik, 0)),
+                pl.BlockSpec((1, block_k, hd),
+                             lambda s, iq, ik, g: (s, ik, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, hd),
+                             lambda s, iq, ik, g: (s, iq, 0)),
+                pl.BlockSpec((1, block_q, 1),
+                             lambda s, iq, ik, g: (s, iq, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, hd), jnp.float32),   # acc
+                pltpu.VMEM((block_q, 1), jnp.float32),    # m
+                pltpu.VMEM((block_q, 1), jnp.float32),    # l
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((n_disp, S, hd), q.dtype),
-            jax.ShapeDtypeStruct((n_disp, S), jnp.float32),
+            jax.ShapeDtypeStruct((n_disp, S, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),   # acc
-            pltpu.VMEM((block_q,), jnp.float32),      # m
-            pltpu.VMEM((block_q,), jnp.float32),      # l
-        ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(g.reshape(n_disp, 1), q, k, v)
+    )(_contract.gate_operand(g), q, k, v)
 
     if idx is not None:
         # scatter live results back; dead (never-dispatched) slices are the
@@ -242,9 +246,9 @@ def _forward(q, k, v, g_f, *, causal: bool, window: int, block_q: int,
         # LSE_MASKED themselves so the set() is a no-op value-wise.
         o = jnp.zeros((N, S, hd), o.dtype).at[idx].set(
             o, unique_indices=True)
-        lse = jnp.full((N, S), LSE_MASKED, jnp.float32).at[idx].set(
+        lse = jnp.full((N, S, 1), LSE_MASKED, jnp.float32).at[idx].set(
             lse, unique_indices=True)
-    return o.reshape(B, H, S, hd), lse.reshape(B, H, S)
+    return o.reshape(B, H, S, hd), lse.reshape(B, H, S, 1)
 
 
 def d2ft_flash_attention(q, k, v, gates, *, causal: bool = True,
@@ -288,7 +292,7 @@ def _bwd_fused_kernel(gate_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     zeros are written once per slice."""
     ik = pl.program_id(1)
     iq = pl.program_id(2)
-    gate = gate_ref[0, 0]
+    gate = gate_ref[pl.program_id(0)]
 
     @pl.when(jnp.logical_and(ik == 0, iq == 0))
     def _init_dq():
@@ -312,18 +316,19 @@ def _bwd_fused_kernel(gate_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         k = k_ref[0].astype(jnp.float32)               # [bk, hd]
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)             # [bq, hd]
-        lse = lse_ref[0]                               # [bq]
-        delta = delta_ref[0]                           # [bq]
+        lse = lse_ref[0]                               # [bq, 1]
+        delta = delta_ref[0]                           # [bq, 1]
         s = jax.lax.dot_general(q * scale, k,
                                 (((1,), (1,)), ((), ())))   # [bq, bk]
         mask = _tile_mask(qpos0, kpos0, block_q, block_k, seq_len, causal,
                           window)
         s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                  # [bq, bk]
+        p = jnp.exp(s - lse)                           # [bq, bk]
         dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None])                 # [bq, bk]
-        dq_ref[0, pl.dslice(qpos0, block_q), :] += jax.lax.dot_general(
+        ds = p * (dp - delta)                          # [bq, bk]
+        rows = pl.ds(pl.multiple_of(qpos0, 8), block_q)
+        dq_ref[0, rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ()))) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ()))) * scale
@@ -345,7 +350,7 @@ def _backward(q, k, v, g_b, o, lse, do, *, causal: bool, window: int,
 
     N = B * H
     q, k, v, o, do = (a.reshape(N, S, hd) for a in (q, k, v, o, do))
-    lse = lse.reshape(N, S)
+    lse = lse.reshape(N, S, 1)
     g = g_b.reshape(N)
     n_disp = _dispatch_count(live, N)
     idx = None
@@ -357,7 +362,8 @@ def _backward(q, k, v, g_b, o, lse, do, *, causal: bool, window: int,
     # delta_i = sum_d dO_id * O_id — cheap elementwise reduce, done outside
     # the kernel (standard flash-bwd preprocessing) on the *compacted*
     # operands so gated-off slices don't pay it either.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
 
     grid = (n_disp, n_k, n_q)
     _report_dispatch("bwd", grid)
@@ -365,35 +371,44 @@ def _backward(q, k, v, g_b, o, lse, do, *, causal: bool, window: int,
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
                           n_q=n_q, seq_len=seq_len),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s, ik, iq: (s, 0)),            # g_b
-            pl.BlockSpec((1, block_q, hd), lambda s, ik, iq: (s, iq, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda s, ik, iq: (s, ik, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda s, ik, iq: (s, ik, 0)),
-            pl.BlockSpec((1, block_q, hd), lambda s, ik, iq: (s, iq, 0)),
-            pl.BlockSpec((1, block_q), lambda s, ik, iq: (s, iq)),
-            pl.BlockSpec((1, block_q), lambda s, ik, iq: (s, iq)),
-        ],
-        out_specs=[
-            # dq: per-slice block, VMEM-resident across the whole slice —
-            # S*hd*4 bytes of VMEM on real TPU (interpret mode is unbounded;
-            # ~16MB/core caps S around 16-32k at hd=128: docs/kernels.md)
-            pl.BlockSpec((1, S, hd), lambda s, ik, iq: (s, 0, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda s, ik, iq: (s, ik, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda s, ik, iq: (s, ik, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                   # g_b
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, hd),
+                             lambda s, ik, iq, g: (s, iq, 0)),       # q
+                pl.BlockSpec((1, block_k, hd),
+                             lambda s, ik, iq, g: (s, ik, 0)),       # k
+                pl.BlockSpec((1, block_k, hd),
+                             lambda s, ik, iq, g: (s, ik, 0)),       # v
+                pl.BlockSpec((1, block_q, hd),
+                             lambda s, ik, iq, g: (s, iq, 0)),       # do
+                pl.BlockSpec((1, block_q, 1),
+                             lambda s, ik, iq, g: (s, iq, 0)),       # lse
+                pl.BlockSpec((1, block_q, 1),
+                             lambda s, ik, iq, g: (s, iq, 0)),       # delta
+            ],
+            out_specs=[
+                # dq: per-slice block, VMEM-resident across the whole
+                # slice — S*hd*4 bytes of VMEM (~16MB/core caps S around
+                # 16-32k at hd=128: docs/kernels.md)
+                pl.BlockSpec((1, S, hd), lambda s, ik, iq, g: (s, 0, 0)),
+                pl.BlockSpec((1, block_k, hd),
+                             lambda s, ik, iq, g: (s, ik, 0)),
+                pl.BlockSpec((1, block_k, hd),
+                             lambda s, ik, iq, g: (s, ik, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
+                            pltpu.VMEM((block_k, hd), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((n_disp, S, hd), jnp.float32),
             jax.ShapeDtypeStruct((n_disp, S, hd), k.dtype),
             jax.ShapeDtypeStruct((n_disp, S, hd), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
-                        pltpu.VMEM((block_k, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(g.reshape(n_disp, 1), q, k, v, do, lse, delta)
+    )(_contract.gate_operand(g), q, k, v, do, lse, delta)
 
     dq = dq.astype(q.dtype)
     if idx is not None:
@@ -451,10 +466,14 @@ gated_flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 # ======================================================= tile selection
+SUBLANE = 8   # f32 sublane tile: every q/k tile is a multiple of it
+
+
 def _largest_divisor(S: int, block: int) -> int:
-    b = min(block, S)
-    while S % b:
-        b -= 1
+    """Largest multiple of SUBLANE <= block dividing S (0 if none)."""
+    b = block - block % SUBLANE
+    while b and S % b:
+        b -= SUBLANE
     return b
 
 
@@ -463,13 +482,16 @@ def select_blocks(S: int, block_q: int, block_k: int):
     ``d2ft_flash_attention`` AND the FLOP/DMA accounting below — one source
     of truth for tile geometry.
 
-    Exact fit when S divides the requested tiles; otherwise shrink to a
+    Tiles are always multiples of the 8-row sublane tile, as the TPU
+    lowering requires of a block's second-minor dim. Exact fit when S
+    divides the requested tiles; otherwise shrink to a multiple-of-8
     divisor if one exists within 2x of the request (stays near MXU width);
     otherwise keep the requested tiles and pad S up to a common multiple —
-    never degenerate slivers (e.g. S=257 pads to 384 with 128-tiles instead
-    of running 1-wide tiles the TPU lowering would reject)."""
-    bq = min(block_q, S)
-    bk = min(block_k, S)
+    never degenerate slivers (e.g. S=257 pads to 384 with 128-tiles, S=5
+    pads to one 8-row tile)."""
+    cap = -(-S // SUBLANE) * SUBLANE
+    bq = min(block_q, cap)
+    bk = min(block_k, cap)
     if S % bq == 0 and S % bk == 0:
         return bq, bk, S
     dq_ = _largest_divisor(S, bq)

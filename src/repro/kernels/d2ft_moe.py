@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels import contract as _contract
 
 # Test hooks — same contract as d2ft_attention / d2ft_ssd / d2ft_rglru.
 on_backward_block = None
@@ -87,9 +87,15 @@ def act_pair(name: str):
 
 
 # ================================================================== forward
+def _block_mask(m_ref):
+    """This (expert, capacity-block) tile's mask bit from the flattened
+    [E * n_cb] scalar-prefetch operand (``contract.gate_operand``)."""
+    return m_ref[pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)]
+
+
 def _fwd_kernel(fm_ref, x_ref, wu_ref, wg_ref, wd_ref, y_ref, *, act: str):
     f, _ = act_pair(act)
-    live = fm_ref[0, 0]
+    live = _block_mask(fm_ref)
 
     @pl.when(live != 0)
     def _compute():
@@ -117,20 +123,23 @@ def _forward(xb, w_up, w_gate, w_down, fm, *, act: str, block_c: int,
     _report_dispatch("fwd", grid)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, act=act),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda e, ic: (e, ic)),            # fm
-            pl.BlockSpec((1, block_c, D), lambda e, ic: (e, ic, 0)),
-            pl.BlockSpec((1, D, F), lambda e, ic: (e, 0, 0)),       # w_up
-            pl.BlockSpec((1, D, F), lambda e, ic: (e, 0, 0)),       # w_gate
-            pl.BlockSpec((1, F, D), lambda e, ic: (e, 0, 0)),       # w_down
-        ],
-        out_specs=pl.BlockSpec((1, block_c, D), lambda e, ic: (e, ic, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                  # fm
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_c, D), lambda e, ic, m: (e, ic, 0)),
+                pl.BlockSpec((1, D, F), lambda e, ic, m: (e, 0, 0)),  # w_up
+                pl.BlockSpec((1, D, F), lambda e, ic, m: (e, 0, 0)),  # w_gate
+                pl.BlockSpec((1, F, D), lambda e, ic, m: (e, 0, 0)),  # w_down
+            ],
+            out_specs=pl.BlockSpec((1, block_c, D),
+                                   lambda e, ic, m: (e, ic, 0))),
         out_shape=jax.ShapeDtypeStruct((E, C, D), xb.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(fm, xb, w_up, w_gate, w_down)
+    )(_contract.gate_operand(fm.reshape(-1)), xb, w_up, w_gate,
+      w_down)
 
 
 # ================================================================= backward
@@ -141,7 +150,7 @@ def _bwd_kernel(bm_ref, x_ref, wu_ref, wg_ref, wd_ref, dy_ref, dx_ref,
     capacity-block dim (VMEM-resident per expert, init at ic == 0)."""
     f, df = act_pair(act)
     ic = pl.program_id(1)
-    live = bm_ref[0, 0]
+    live = _block_mask(bm_ref)
 
     @pl.when(ic == 0)
     def _init():
@@ -184,31 +193,35 @@ def _backward(xb, w_up, w_gate, w_down, bm, dy, *, act: str, block_c: int,
     _report_dispatch("bwd", grid)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, act=act),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda e, ic: (e, ic)),            # bm
-            pl.BlockSpec((1, block_c, D), lambda e, ic: (e, ic, 0)),
-            pl.BlockSpec((1, D, F), lambda e, ic: (e, 0, 0)),
-            pl.BlockSpec((1, D, F), lambda e, ic: (e, 0, 0)),
-            pl.BlockSpec((1, F, D), lambda e, ic: (e, 0, 0)),
-            pl.BlockSpec((1, block_c, D), lambda e, ic: (e, ic, 0)),  # dy
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_c, D), lambda e, ic: (e, ic, 0)),  # dx
-            pl.BlockSpec((1, D, F), lambda e, ic: (e, 0, 0)),         # dwu
-            pl.BlockSpec((1, D, F), lambda e, ic: (e, 0, 0)),         # dwg
-            pl.BlockSpec((1, F, D), lambda e, ic: (e, 0, 0)),         # dwd
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                  # bm
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_c, D), lambda e, ic, m: (e, ic, 0)),
+                pl.BlockSpec((1, D, F), lambda e, ic, m: (e, 0, 0)),
+                pl.BlockSpec((1, D, F), lambda e, ic, m: (e, 0, 0)),
+                pl.BlockSpec((1, F, D), lambda e, ic, m: (e, 0, 0)),
+                pl.BlockSpec((1, block_c, D),
+                             lambda e, ic, m: (e, ic, 0)),          # dy
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_c, D),
+                             lambda e, ic, m: (e, ic, 0)),          # dx
+                pl.BlockSpec((1, D, F), lambda e, ic, m: (e, 0, 0)),  # dwu
+                pl.BlockSpec((1, D, F), lambda e, ic, m: (e, 0, 0)),  # dwg
+                pl.BlockSpec((1, F, D), lambda e, ic, m: (e, 0, 0)),  # dwd
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((E, C, D), jnp.float32),
             jax.ShapeDtypeStruct((E, D, F), jnp.float32),
             jax.ShapeDtypeStruct((E, D, F), jnp.float32),
             jax.ShapeDtypeStruct((E, F, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(bm, xb, w_up, w_gate, w_down, dy)
+    )(_contract.gate_operand(bm.reshape(-1)), xb, w_up, w_gate,
+      w_down, dy)
 
 
 # =============================================================== custom VJP

@@ -45,7 +45,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import contract as _contract
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 # Test hooks — same contract as d2ft_attention / d2ft_ssd.
 on_backward_block = None
@@ -73,13 +72,13 @@ def _decay_matrix(lc):
 # ================================================================== forward
 def _fwd_kernel(gate_ref, la_ref, b_ref, h_ref, carry_ref):
     j = pl.program_id(1)
-    gate = gate_ref[0, 0]
+    gate = gate_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    prev = carry_ref[...]                                   # [Wg] f32
+    prev = carry_ref[...]                                   # [1, Wg] f32
 
     @pl.when(gate != 0)
     def _compute():
@@ -88,9 +87,9 @@ def _fwd_kernel(gate_ref, la_ref, b_ref, h_ref, carry_ref):
         Q = la.shape[0]
         lc = jnp.cumsum(la, axis=0)
         h = jnp.sum(_decay_matrix(lc) * b[None, :, :], axis=1)
-        h = h + jnp.exp(lc) * prev[None, :]
+        h = h + jnp.exp(lc) * prev
         h_ref[0] = h.astype(h_ref.dtype)
-        carry_ref[...] = h[Q - 1]
+        carry_ref[...] = h[Q - 1:Q]
 
     @pl.when(gate == 0)
     def _dead():
@@ -131,19 +130,20 @@ def _forward(la, b, g_f, *, chunk: int, interpret: bool, live=None):
     _report_dispatch("fwd", grid)
     h = pl.pallas_call(
         _fwd_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s, j: (s, 0)),             # g_f
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, j, 0)),      # log_a
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, j, 0)),      # b
-        ],
-        out_specs=pl.BlockSpec((1, Q, Wg), lambda s, j: (s, j, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                 # g_f
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, Q, Wg), lambda s, j, g: (s, j, 0)),   # log_a
+                pl.BlockSpec((1, Q, Wg), lambda s, j, g: (s, j, 0)),   # b
+            ],
+            out_specs=pl.BlockSpec((1, Q, Wg), lambda s, j, g: (s, j, 0)),
+            scratch_shapes=[pltpu.VMEM((1, Wg), jnp.float32)]),    # carry
         out_shape=jax.ShapeDtypeStruct((n_disp, S, Wg), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((Wg,), jnp.float32)],           # carry
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(g.reshape(n_disp, 1), las, bs)
+    )(_contract.gate_operand(g), las, bs)
 
     if idx is not None:
         h = jnp.zeros((NS, S, Wg), h.dtype).at[idx].set(
@@ -155,7 +155,7 @@ def _forward(la, b, g_f, *, chunk: int, interpret: bool, live=None):
 def _bwd_kernel(gate_ref, la_ref, b_ref, h_ref, dy_ref, dla_ref, db_ref,
                 dcarry_ref):
     j = pl.program_id(1)
-    gate = gate_ref[0, 0]
+    gate = gate_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -171,10 +171,10 @@ def _bwd_kernel(gate_ref, la_ref, b_ref, h_ref, dy_ref, dla_ref, db_ref,
         Q = la.shape[0]
         lc = jnp.cumsum(la, axis=0)
         last = (jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0) == Q - 1)
-        dh = dy + jnp.where(last, dcarry_ref[...][None, :], 0.0)
+        dh = dy + jnp.where(last, dcarry_ref[...], 0.0)
         Lm = _decay_matrix(lc)
         db = jnp.sum(Lm * dh[:, None, :], axis=0)           # [Q, Wg]
-        dprev = jnp.sum(jnp.exp(lc) * dh, axis=0)           # [Wg]
+        dprev = jnp.sum(jnp.exp(lc) * dh, axis=0, keepdims=True)  # [1, Wg]
         dlc = dh * h - b * db
         dla = jnp.cumsum(dlc[::-1], axis=0)[::-1]           # cumsum adjoint
         dla_ref[0] = dla.astype(dla_ref.dtype)
@@ -206,29 +206,26 @@ def _backward(la, b, g_b, h, dy, *, chunk: int, interpret: bool, live=None):
     rev = nc - 1
     grid = (n_disp, nc)
     _report_dispatch("bwd", grid)
+
+    def rmap(s, j, g):
+        return (s, rev - j, 0)
+
     dla, db = pl.pallas_call(
         _bwd_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s, j: (s, 0)),                # g_b
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, rev - j, 0)),   # log_a
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, rev - j, 0)),   # b
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, rev - j, 0)),   # h
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, rev - j, 0)),   # dy
-        ],
-        out_specs=[
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, rev - j, 0)),   # dla
-            pl.BlockSpec((1, Q, Wg), lambda s, j: (s, rev - j, 0)),   # db
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                  # g_b
+            grid=grid,
+            in_specs=[pl.BlockSpec((1, Q, Wg), rmap)] * 4,  # log_a, b, h, dy
+            out_specs=[pl.BlockSpec((1, Q, Wg), rmap)] * 2,  # dla, db
+            scratch_shapes=[pltpu.VMEM((1, Wg), jnp.float32)]),     # dcarry
         out_shape=[
             jax.ShapeDtypeStruct((n_disp, S, Wg), jnp.float32),
             jax.ShapeDtypeStruct((n_disp, S, Wg), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((Wg,), jnp.float32)],            # dcarry
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(g.reshape(n_disp, 1), las, bs, hs, dys)
+    )(_contract.gate_operand(g), las, bs, hs, dys)
 
     if idx is not None:
         dla, db = (jnp.zeros((NS, S, Wg), a.dtype).at[idx].set(

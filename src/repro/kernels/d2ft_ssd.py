@@ -47,7 +47,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import contract as _contract
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 # Test hooks — same contract as d2ft_attention: on_backward_block fires once
 # per *executed* backward chunk (via jax.debug.callback), on_dispatch fires
@@ -66,21 +65,45 @@ def _report_dispatch(kind: str, grid):
         on_dispatch(kind, tuple(grid))
 
 
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _tril(Q: int):
+    """[Q, Q] causal mask, tril[q, k] = k <= q (diagonal included)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    return col <= row
+
+
+def _exp_lanes(x11, n: int):
+    """exp of a [1, 1] value as a [1, n] row. Broadcasting a [1, 1] value
+    straight onto a [P, N] tile is refused by the TPU lowering (sublanes
+    and lanes at once): broadcast along lanes here, before the exp so the
+    two broadcasts are not folded into one, and let the use site
+    broadcast the row along sublanes."""
+    return jnp.exp(jnp.broadcast_to(x11, (1, n)))
+
+
 def _causal_decay(da):
-    """cum (inclusive cumsum), L[q, k] = exp(cum_q - cum_k) masked causal
-    (diagonal included, = 1) — the reference's intra-chunk decay matrix."""
-    Q = da.shape[0]
-    cum = jnp.cumsum(da)
-    diff = cum[:, None] - cum[None, :]
-    tril = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
-    return cum, jnp.where(tril, jnp.exp(diff), 0.0)
+    """da [Q, 1] -> (cum [Q, 1] inclusive cumsum, L [Q, Q]) with
+    L[q, k] = exp(cum_q - cum_k) masked causal (diagonal = 1) — the
+    reference's intra-chunk decay matrix. The cumsums are matmuls against
+    the causal mask (column and row form), which the TPU lowering supports
+    where a cumsum/transpose of a [Q, 1] column is not."""
+    tril = _tril(da.shape[0])
+    ones = tril.astype(jnp.float32)
+    cum = jax.lax.dot_general(ones, da, (((1,), (0,)), ((), ())),
+                              precision=_HI)                     # [Q, 1]
+    cum_row = jax.lax.dot_general(da, ones, (((0,), (1,)), ((), ())),
+                                  precision=_HI)                 # [1, Q]
+    return cum, jnp.where(tril, jnp.exp(cum - cum_row), 0.0)
 
 
 # ================================================================== forward
 def _fwd_kernel(gate_ref, da_ref, x_ref, b_ref, c_ref, y_ref, prev_ref,
                 state_ref):
     j = pl.program_id(1)
-    gate = gate_ref[0, 0]
+    gate = gate_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -91,20 +114,21 @@ def _fwd_kernel(gate_ref, da_ref, x_ref, b_ref, c_ref, y_ref, prev_ref,
     @pl.when(gate != 0)
     def _compute():
         x = x_ref[0].astype(jnp.float32)                    # [Q, P]
-        da = da_ref[0].astype(jnp.float32)                  # [Q]
+        da = da_ref[0].astype(jnp.float32)                  # [Q, 1]
         b = b_ref[0].astype(jnp.float32)                    # [Q, N]
         c = c_ref[0].astype(jnp.float32)                    # [Q, N]
         Q = da.shape[0]
         cum, L = _causal_decay(da)
+        tot = cum[Q - 1:Q]                                  # [1, 1]
         cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))  # [Q, Q]
+        N = b.shape[1]
         y = jax.lax.dot_general(cb * L, x, (((1,), (0,)), ((), ())))
-        e_cum = jnp.exp(cum)
         y = y + jax.lax.dot_general(
-            c, prev, (((1,), (1,)), ((), ()))) * e_cum[:, None]
+            c, prev, (((1,), (1,)), ((), ()))) * jnp.exp(cum)
         y_ref[0] = y.astype(y_ref.dtype)
         prev_ref[0, 0] = prev
-        xw = x * jnp.exp(cum[Q - 1] - cum)[:, None]         # decay-to-end
-        state_ref[...] = jnp.exp(cum[Q - 1]) * prev + \
+        xw = x * jnp.exp(tot - cum)                         # decay-to-end
+        state_ref[...] = _exp_lanes(tot, N) * prev + \
             jax.lax.dot_general(xw, b, (((0,), (0,)), ((), ())))
 
     @pl.when(gate == 0)
@@ -119,7 +143,7 @@ def _slice_major(x, da, Bm, Cm):
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     xs = x.transpose(0, 2, 1, 3).reshape(B * H, S, P)
-    das = da.transpose(0, 2, 1).reshape(B * H, S)
+    das = da.transpose(0, 2, 1).reshape(B * H, S, 1)
     Bs = jnp.broadcast_to(Bm[:, None], (B, H, S, N)).reshape(B * H, S, N)
     Cs = jnp.broadcast_to(Cm[:, None], (B, H, S, N)).reshape(B * H, S, N)
     return xs, das, Bs, Cs
@@ -148,27 +172,29 @@ def _forward(x, da, Bm, Cm, g_f, *, chunk: int, interpret: bool, live=None):
     _report_dispatch("fwd", grid)
     y, prevs = pl.pallas_call(
         _fwd_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s, j: (s, 0)),              # g_f
-            pl.BlockSpec((1, Q), lambda s, j: (s, j)),              # da
-            pl.BlockSpec((1, Q, P), lambda s, j: (s, j, 0)),        # x
-            pl.BlockSpec((1, Q, N), lambda s, j: (s, j, 0)),        # B
-            pl.BlockSpec((1, Q, N), lambda s, j: (s, j, 0)),        # C
-        ],
-        out_specs=[
-            pl.BlockSpec((1, Q, P), lambda s, j: (s, j, 0)),        # y
-            pl.BlockSpec((1, 1, P, N), lambda s, j: (s, j, 0, 0)),  # prevs
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                  # g_f
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, Q, 1), lambda s, j, g: (s, j, 0)),    # da
+                pl.BlockSpec((1, Q, P), lambda s, j, g: (s, j, 0)),    # x
+                pl.BlockSpec((1, Q, N), lambda s, j, g: (s, j, 0)),    # B
+                pl.BlockSpec((1, Q, N), lambda s, j, g: (s, j, 0)),    # C
+            ],
+            out_specs=[
+                pl.BlockSpec((1, Q, P), lambda s, j, g: (s, j, 0)),    # y
+                pl.BlockSpec((1, 1, P, N),
+                             lambda s, j, g: (s, j, 0, 0)),            # prevs
+            ],
+            scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)]),      # state
         out_shape=[
             jax.ShapeDtypeStruct((n_disp, S, P), x.dtype),
             jax.ShapeDtypeStruct((n_disp, nc, P, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],           # state
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(g.reshape(n_disp, 1), das, xs, Bs, Cs)
+    )(_contract.gate_operand(g), das, xs, Bs, Cs)
 
     if idx is not None:
         y = jnp.zeros((NS, S, P), y.dtype).at[idx].set(
@@ -185,7 +211,7 @@ def _bwd_kernel(gate_ref, da_ref, x_ref, b_ref, c_ref, prev_ref, dy_ref,
     state cotangent. Per live chunk: recompute the decay/state quantities
     and emit dx / ddA / dB / dC plus the carry for the previous chunk."""
     j = pl.program_id(1)
-    gate = gate_ref[0, 0]
+    gate = gate_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -195,18 +221,18 @@ def _bwd_kernel(gate_ref, da_ref, x_ref, b_ref, c_ref, prev_ref, dy_ref,
     def _compute():
         _maybe_count_block()
         x = x_ref[0].astype(jnp.float32)                    # [Q, P]
-        da = da_ref[0].astype(jnp.float32)                  # [Q]
+        da = da_ref[0].astype(jnp.float32)                  # [Q, 1]
         b = b_ref[0].astype(jnp.float32)                    # [Q, N]
         c = c_ref[0].astype(jnp.float32)                    # [Q, N]
         prev = prev_ref[0, 0]                               # [P, N] f32
         dy = dy_ref[0].astype(jnp.float32)                  # [Q, P]
         ds = dstate_ref[...]                                # [P, N] f32
         Q = da.shape[0]
-        cum, L = _causal_decay(da)
-        tot = cum[Q - 1]
+        cum, L = _causal_decay(da)                          # [Q, 1], [Q, Q]
+        tot = cum[Q - 1:Q]                                  # [1, 1]
         e_cum = jnp.exp(cum)
         d2e = jnp.exp(tot - cum)
-        e_tot = jnp.exp(tot)
+        e_tot = _exp_lanes(tot, b.shape[1])            # [1, N]
         cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())))  # [Q, Q]
 
         # intra-chunk: y_intra = (CB * L) @ x
@@ -218,26 +244,32 @@ def _bwd_kernel(gate_ref, da_ref, x_ref, b_ref, c_ref, prev_ref, dy_ref,
         dx_intra = jax.lax.dot_general(cb * L, dy, (((0,), (0,)), ((), ())))
 
         # inter-chunk output: y_inter = (c @ prev^T) * e_cum
-        t1 = dy * e_cum[:, None]
+        t1 = dy * e_cum
         dc_inter = jax.lax.dot_general(t1, prev, (((1,), (0,)), ((), ())))
         dprev_y = jax.lax.dot_general(t1, c, (((0,), (0,)), ((), ())))
         y_int = jax.lax.dot_general(
-            c, prev, (((1,), (1,)), ((), ()))) * e_cum[:, None]
-        dcum_yint = jnp.sum(dy * y_int, axis=1)
+            c, prev, (((1,), (1,)), ((), ()))) * e_cum
+        dcum_yint = jnp.sum(dy * y_int, axis=1, keepdims=True)
 
         # state update: state' = e_tot * prev + (x * d2e)^T @ b
         dprev_state = e_tot * ds
-        dtot_state = e_tot * jnp.sum(ds * prev)
+        dtot_state = jnp.exp(tot) * jnp.sum(ds * prev, keepdims=True)
         dxw = jax.lax.dot_general(b, ds, (((1,), (1,)), ((), ())))  # [Q, P]
-        xw = x * d2e[:, None]
+        xw = x * d2e
         db_state = jax.lax.dot_general(xw, ds, (((1,), (0,)), ((), ())))
-        dx_state = dxw * d2e[:, None]
-        dd2e = jnp.sum(dxw * x, axis=1)
+        dx_state = dxw * d2e
+        dd2e = jnp.sum(dxw * x, axis=1, keepdims=True)      # [Q, 1]
 
         w = dd2e * d2e
-        dcum = jnp.sum(m, axis=1) - jnp.sum(m, axis=0) + dcum_yint - w
-        dtot = dtot_state + jnp.sum(w)
-        dda = jnp.cumsum(dcum[::-1])[::-1] + dtot           # cumsum adjoint
+        tril = _tril(Q).astype(jnp.float32)
+        ones = jnp.ones((Q, 1), jnp.float32)
+        m_col = jax.lax.dot_general(m, ones, (((0,), (0,)), ((), ())),
+                                    precision=_HI)          # column sums
+        dcum = jnp.sum(m, axis=1, keepdims=True) - m_col + dcum_yint - w
+        dtot = dtot_state + jnp.sum(w, keepdims=True)
+        # cumsum adjoint: reverse cumsum = tril^T @ dcum
+        dda = jax.lax.dot_general(tril, dcum, (((0,), (0,)), ((), ())),
+                                  precision=_HI) + dtot
 
         dx_ref[0] = (dx_intra + dx_state).astype(dx_ref.dtype)
         dda_ref[0] = dda.astype(dda_ref.dtype)
@@ -274,36 +306,41 @@ def _backward(x, da, Bm, Cm, g_b, prevs, dy, *, chunk: int, interpret: bool,
     rev = nc - 1
     grid = (n_disp, nc)
     _report_dispatch("bwd", grid)
+
+    def rmap(s, j, g):
+        return (s, rev - j, 0)
+
     dx, dda, db, dc = pl.pallas_call(
         _bwd_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda s, j: (s, 0)),                # g_b
-            pl.BlockSpec((1, Q), lambda s, j: (s, rev - j)),          # da
-            pl.BlockSpec((1, Q, P), lambda s, j: (s, rev - j, 0)),    # x
-            pl.BlockSpec((1, Q, N), lambda s, j: (s, rev - j, 0)),    # B
-            pl.BlockSpec((1, Q, N), lambda s, j: (s, rev - j, 0)),    # C
-            pl.BlockSpec((1, 1, P, N),
-                         lambda s, j: (s, rev - j, 0, 0)),            # prevs
-            pl.BlockSpec((1, Q, P), lambda s, j: (s, rev - j, 0)),    # dy
-        ],
-        out_specs=[
-            pl.BlockSpec((1, Q, P), lambda s, j: (s, rev - j, 0)),    # dx
-            pl.BlockSpec((1, Q), lambda s, j: (s, rev - j)),          # dda
-            pl.BlockSpec((1, Q, N), lambda s, j: (s, rev - j, 0)),    # dB
-            pl.BlockSpec((1, Q, N), lambda s, j: (s, rev - j, 0)),    # dC
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                  # g_b
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, Q, 1), rmap),                      # da
+                pl.BlockSpec((1, Q, P), rmap),                      # x
+                pl.BlockSpec((1, Q, N), rmap),                      # B
+                pl.BlockSpec((1, Q, N), rmap),                      # C
+                pl.BlockSpec((1, 1, P, N),
+                             lambda s, j, g: (s, rev - j, 0, 0)),   # prevs
+                pl.BlockSpec((1, Q, P), rmap),                      # dy
+            ],
+            out_specs=[
+                pl.BlockSpec((1, Q, P), rmap),                      # dx
+                pl.BlockSpec((1, Q, 1), rmap),                      # dda
+                pl.BlockSpec((1, Q, N), rmap),                      # dB
+                pl.BlockSpec((1, Q, N), rmap),                      # dC
+            ],
+            scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)]),      # dstate
         out_shape=[
             jax.ShapeDtypeStruct((n_disp, S, P), jnp.float32),
-            jax.ShapeDtypeStruct((n_disp, S), jnp.float32),
+            jax.ShapeDtypeStruct((n_disp, S, 1), jnp.float32),
             jax.ShapeDtypeStruct((n_disp, S, N), jnp.float32),
             jax.ShapeDtypeStruct((n_disp, S, N), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],           # dstate
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(g.reshape(n_disp, 1), das, xs, Bs, Cs, prevs, dys)
+    )(_contract.gate_operand(g), das, xs, Bs, Cs, prevs, dys)
 
     if idx is not None:
         dx, dda, db, dc = (

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, *, scale: float):
     x = x_ref[...]
@@ -59,7 +57,7 @@ def lora_matmul(x, w, a, b, scale: float = 1.0, *, block_m: int = 256,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, w, a, b)

@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 NEG_INF = -2.0 ** 30
 
 # Test hook: when set to a callable, ``paged_flash_decode`` reports its
@@ -86,20 +84,19 @@ def _paged_decode_kernel(tbl_ref, len_ref, gate_ref, q_ref, k_ref, v_ref,
             mask = jnp.logical_and(mask, pos > t - window)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        pr = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pr = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + \
+        l_ref[...] = l_ref[...] * corr + jnp.sum(pr, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + \
             jax.lax.dot_general(pr, v, (((1,), (0,)), ((), ())))
         m_ref[...] = m_new
 
     @pl.when(p == n_pmax - 1)
     def _finalize():
-        l = l_ref[...]
+        l = l_ref[...]                                   # [1, 1]
         safe = jnp.where(l > 0, l, 1.0)
-        out = acc_ref[...] / safe[:, None]
-        out = jnp.where((l > 0)[:, None], out, 0.0)
+        out = jnp.where(l > 0, acc_ref[...] / safe, 0.0)
         out = out * gate.astype(jnp.float32)
         o_ref[0] = out.astype(o_ref.dtype)
 
@@ -132,28 +129,31 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, gates, *,
     def kv_map(b, h, p, tbl, ln, g):
         return (tbl[b, p], 0, h // rep, 0)
 
+    def q_map(b, h, p, tbl, ln, g):      # q / o laid out [B*H, 1, hd]
+        return (b * H + h, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # page_table, lengths, gates
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, p, tbl, ln, g: (b, h, 0)),
+            pl.BlockSpec((1, 1, hd), q_map),
             pl.BlockSpec((1, page_size, 1, hd), kv_map),
             pl.BlockSpec((1, page_size, 1, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda b, h, p, tbl, ln, g: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, hd), q_map),
         scratch_shapes=[
             pltpu.VMEM((1, hd), jnp.float32),    # acc
-            pltpu.VMEM((1,), jnp.float32),       # m
-            pltpu.VMEM((1,), jnp.float32),       # l
+            pltpu.VMEM((1, 1), jnp.float32),     # m
+            pltpu.VMEM((1, 1), jnp.float32),     # l
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B * H, 1, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      gates.astype(jnp.float32), q, k_pages, v_pages)
+      gates.astype(jnp.float32), q.reshape(B * H, 1, hd), k_pages,
+      v_pages).reshape(B, H, hd)

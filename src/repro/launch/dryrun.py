@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × input shape × mesh).
 
-The two lines above MUST run before any other import — jax locks the device
-count on first init. Never set that flag globally (smoke tests and benches
-must see 1 device).
+The lines above MUST run before any other import — jax locks the platform
+and device count on first init. The dry-run lowers onto 512 host CPU
+devices, so it pins the CPU platform even on a machine with a TPU (whose
+backend would otherwise start and then fail to build a 512-device mesh).
+Never set those flags globally (smoke tests and benches must see 1 device).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-1b --shape train_4k

@@ -16,13 +16,32 @@ _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                 "u8": 1, "pred": 1, "f64": 8, "s64": 8, "u64": 8, "c64": 8,
                 "s16": 2, "u16": 2, "f8e4m3fn": 1, "f8e5m2": 1}
 
-# Async pairs (`-start`/`-done`, GPU backends) count once, at `-done`,
-# whose result shape is the plain array (`-start` results are often
-# tuple-shaped and would not parse).
+# One collective instruction: `name = <result type> <op>(operands...)`. The
+# result type is one array (`f32[4,8]{1,0}`, TPU layouts may carry
+# `{1,0:T(8,128)}`) or a tuple of them when XLA combines several
+# collectives into one (`(f32[4,8]{1,0}, f32[16]{0}) all-reduce(...)`,
+# long tuples interleaved with `/*index=5*/` comments); every array of the
+# tuple is priced. Async pairs (`-start`/`-done`) count
+# once, at `-done`, whose result is the plain (or combined) output —
+# `-start` results also carry the operands.
 _COLL_RE = re.compile(
-    r"=\s*(\w+)\[([\d,]*)\][^=]*?"
+    r"=\s*(\((?:[^()\n]|\([^()\n]*\))*\)|\w+\[[\d,]*\](?:\{[^}\n]*\})?)\s*"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start|-done)?\(([^\n]*)")
+_ARRAY_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+
+
+def _result_bytes(result_type: str) -> int:
+    """Bytes of an instruction's result: one array or a tuple of arrays."""
+    total = 0
+    for dt, dims in _ARRAY_RE.findall(result_type):
+        n = _DTYPE_BYTES.get(dt, 4)
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        total += n
+    return total
+
 
 # replica_groups comes in two prints: explicit lists `{{0,1,2},{3,4,5}}`
 # (group size = members of the first group) and the iota form
@@ -71,13 +90,10 @@ def collective_bytes(hlo_text: str,
             start_groups[ch.group(1)] = k
     out: Dict[str, float] = Counter()
     for m in _COLL_RE.finditer(hlo_text):
-        dt, dims, op, phase, rest = m.groups()
+        result_type, op, phase, rest = m.groups()
         if phase == "-start":
             continue                 # counted once, at the matching -done
-        nbytes = _DTYPE_BYTES.get(dt, 4)
-        for d in dims.split(","):
-            if d:
-                nbytes *= int(d)
+        nbytes = _result_bytes(result_type)
         k = _group_size(rest, 0)
         if not k and phase == "-done":
             ch = _CHANNEL_RE.search(rest)
@@ -122,7 +138,7 @@ def collective_counts(hlo_text: str) -> Dict[str, int]:
     byte totals alone."""
     out: Dict[str, int] = Counter()
     for m in _COLL_RE.finditer(hlo_text):
-        _, _, op, phase, _ = m.groups()
+        _, op, phase, _ = m.groups()
         if phase == "-start":
             continue
         out[op] += 1

@@ -18,21 +18,6 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax.sharding.AxisType (and the axis_types kwarg) appeared after 0.4.37
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: passes axis_types=Auto when the
-    installed jax supports it, plain make_mesh otherwise."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     from repro.launch.parallel import MeshSpec
     if multi_pod:
@@ -72,7 +57,22 @@ def make_data_mesh(n_devices=None):
     return MeshSpec(data=n).build(axis_names=("data",))
 
 
-# TPU v5e hardware constants used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (system architecture): 197 TFLOP/s
+# bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect (4 links: 50 GB/s each way per link).
+TPU_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "ops_int8": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; an unknown kind raises
+    (a roofline against a guessed peak is not a measurement)."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(TPU_PEAKS)}") \
+            from None

@@ -98,8 +98,8 @@ class MeshSpec:
         (data, stage, tensor) positions in order; axes beyond its length
         must be singleton and are dropped. ``make_data_mesh`` passes
         ``("data",)``, ``make_host_mesh`` passes ``("data", "model")``.
-        auto_axes: route through ``compat_make_mesh`` (jax.make_mesh with
-        Auto axis types over ALL local devices) — what the GSPMD policy
+        auto_axes: ``jax.make_mesh`` with Auto axis types over ALL local
+        devices — what the GSPMD policy
         paths expect; the default builds an explicit
         ``Mesh(devices[:size])`` so a sub-mesh can be carved out of a
         larger host pool (bench/dry-run idiom).
@@ -119,8 +119,9 @@ class MeshSpec:
                     f"but its size is {n} (must be 1)")
         shape = self.shape[:len(names)]
         if auto_axes:
-            from repro.launch.mesh import compat_make_mesh
-            return compat_make_mesh(shape, names)
+            return jax.make_mesh(
+                shape, names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(names))
         devs = list(jax.devices()) if devices is None else list(devices)
         n = int(np.prod(shape))
         if n > len(devs):

@@ -1,12 +1,13 @@
-"""Production train launcher.
+"""Train launcher.
 
-On a real TPU pod each host runs (with jax.distributed auto-init):
+  python -m repro.launch.train --arch stablelm-3b --d2ft --n-pf 3 \
+      --n-po 1 --steps 500 --ckpt ckpt.npz
 
-  python -m repro.launch.train --arch gemma3-1b --shape train_4k \
-      --d2ft --n-pf 3 --n-po 1 --steps 500 --ckpt /tmp/ckpt
-
-On this CPU container it runs the same code path on a 1-device mesh with a
-reduced config unless --full is passed (the full configs only fit a pod).
+Without --full it runs the arch's reduced smoke config (CPU tests and quick
+drives; Pallas kernels in interpret mode off the TPU). --full runs the
+published config on the local devices: a data mesh over ``jax.devices()``
+unless --mesh says otherwise. The 256-chip production mesh is the dry-run's
+(``launch/dryrun.py``), not this launcher's.
 """
 from __future__ import annotations
 
@@ -18,10 +19,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.configs import INPUT_SHAPES, get_config, get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.configs.base import D2FTConfig
 from repro.data.synthetic import lm_batches
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_data_mesh, make_host_mesh
 from repro.launch.parallel import MeshSpec, ParallelConfig
 from repro.models.transformer import init_model
 from repro.optim.optimizers import adamw, sgd
@@ -33,7 +35,6 @@ from repro.train.loop import finetune, finetune_distributed
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--shape", default="train_4k")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -79,8 +80,8 @@ def main():
     ap.add_argument("--n-po", type=int, default=1)
     ap.add_argument("--n-microbatches", type=int, default=4)
     ap.add_argument("--full", action="store_true",
-                    help="full-size config on the production mesh "
-                         "(requires a pod)")
+                    help="published (full-size) config on a data mesh over "
+                         "the local devices (--mesh overrides)")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--elastic", action="store_true",
                     help="run the fault-tolerant elastic loop "
@@ -107,6 +108,7 @@ def main():
                          "checkpoint (save_train_state format), on the "
                          "original mesh size or a shrunk one")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = MeshSpec.parse(args.mesh) if args.mesh else None
     if spec is not None and not args.distributed:
@@ -117,7 +119,7 @@ def main():
                          "--mesh data=N (stage=tensor=1)")
     if args.full:
         cfg = get_config(args.arch)
-        mesh = spec.build() if spec is not None else make_production_mesh()
+        mesh = spec.build() if spec is not None else make_data_mesh()
     else:
         cfg = get_smoke_config(args.arch)
         mesh = spec.build() if spec is not None else make_host_mesh()

@@ -178,7 +178,6 @@ def apply_moe_ep(params, x, cfg: MoEConfig, act: str, mesh, batch_axes,
     capacity C_l = ceil(T_l * K / E) * capacity_factor.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     E, K = cfg.n_experts, cfg.top_k
     M = mesh.shape[model_axis]
@@ -256,12 +255,12 @@ def apply_moe_ep(params, x, cfg: MoEConfig, act: str, mesh, batch_axes,
     else:
         up_spec = gate_spec = P(None, None, model_axis)
         down_spec = P(None, model_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(xspec, P(None, None), up_spec, gate_spec, down_spec),
         out_specs=(xspec, {"load_balance": P(), "router_z": P(),
                            "drop_frac": P()}),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(x, params["router"], params["w_up"], params["w_gate"],
                 params["w_down"])
 

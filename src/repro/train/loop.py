@@ -317,7 +317,7 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
       ``dist_zero3_streamed`` parity arm pins value equality and the
       8-device suite pins wire-byte equality. ``opt_chunk=n`` streams the
       shard-resident update ``n`` elements at a time
-      (``optim.optimizers.chunked``, bit-identical);
+      (``optim.optimizers.chunked``, equal to a few ulp);
       ``residency_recorder`` (a ``sharding.sync.ResidencyRecorder``)
       counts the streamed schedule's per-unit gather bytes at trace time
       for the ``check_zero3_residency`` cross-check. Both options require
@@ -357,7 +357,6 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
     with params replicated, batch sharded on the leading axis and gates
     [L, B, G] sharded on the sample axis.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.optim.optimizers import chunked
@@ -597,7 +596,7 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
 
         return jax.jit(step)
 
-    # check_rep=False: skipped (dead-subnet) grad leaves are device-invariant
+    # check_vma=False: skipped (dead-subnet) grad leaves are device-invariant
     # — identically zero everywhere — but shard_map's replication tracker
     # cannot prove that through an elided psum.
     param_specs = P()
@@ -620,11 +619,11 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
                 (P(None, axis_name), P(None, axis_name)))
     if guard:
         in_specs = in_specs + (P(axis_name), P())
-    step = shard_map(
+    step = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=(param_specs, state_specs, P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(step)
 
 

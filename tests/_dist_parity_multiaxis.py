@@ -123,7 +123,6 @@ all_diff = max_leaf_diff(p_all, p_ref)
 assert all_diff <= TOL, f"(data=2,stage=2,tensor=2) diverged: {all_diff}"
 
 # ---- (data=4, tensor=2) + LoRA: adapters-only grads through TP -----------
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 lora0 = init_lora(jax.random.PRNGKey(3), params, rank=2)
@@ -150,10 +149,10 @@ def lora_tp_local(lora_p, st, batch, gates):
     return opt.update(g, st, lora_p)
 
 
-lora_tp_step = jax.jit(shard_map(
+lora_tp_step = jax.jit(jax.shard_map(
     lora_tp_local, mesh=mesh_tp,
     in_specs=(P(), P(), P("data"), (P(None, "data"), P(None, "data"))),
-    out_specs=(P(), P()), check_rep=False))
+    out_specs=(P(), P()), check_vma=False))
 jref = jax.jit(lora_ref_step)
 p_lr, s_lr = lora0, opt.init(lora0)
 p_lt, s_lt = lora0, opt.init(lora0)

@@ -91,7 +91,6 @@ def test_sync_bytes_all_ps_only_loss_path():
 def test_apply_grad_sync_structure_single_device():
     """On a 1-device mesh pmean is the identity, so applying the plan must
     return every leaf (incl. sliced/stacked reassembly) bit-identical."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     params = _params()
@@ -99,9 +98,9 @@ def test_apply_grad_sync_structure_single_device():
     fake_grads = jax.tree.map(
         lambda a: jax.random.normal(jax.random.PRNGKey(1), a.shape), params)
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         lambda g: apply_grad_sync(g, plan, "data"), mesh=mesh,
-        in_specs=(P(),), out_specs=P(), check_rep=False))(fake_grads)
+        in_specs=(P(),), out_specs=P(), check_vma=False))(fake_grads)
     for a, b in zip(jax.tree.leaves(fake_grads), jax.tree.leaves(out)):
         assert a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -131,6 +130,25 @@ def test_collective_bytes_group_size_forms():
     assert got["all-gather"] == pytest.approx(7 / 8 * 3200)
     assert collective_bytes(empty, default_group_size=8)["all-reduce"] \
         == pytest.approx(2 * 7 / 8 * 400)
+
+
+def test_collective_parser_counts_combined_tuple_results():
+    """XLA combines several psums into one tuple-shaped all-reduce; every
+    array of the tuple is priced and the instruction counts once. TPU
+    layouts (`{1,0:T(8,128)}`) parse too."""
+    from repro.launch.hlo import collective_bytes, collective_counts
+
+    combined = ("%all-reduce = (f32[4,8]{1,0}, /*index=1*/f32[16]{0}) "
+                "all-reduce(%a, %b), channel_id=1, "
+                "replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%sum")
+    tpu = ("%all-gather = f32[8,128]{1,0:T(8,128)} all-gather("
+           "f32[2,128]{1,0:T(2,128)} %p), channel_id=2, "
+           "replica_groups=[1,4]<=[4], dimensions={0}")
+    got = collective_bytes("\n".join([combined, tpu]))
+    assert got["all-reduce"] == pytest.approx(2 * 7 / 8 * (32 + 16) * 4)
+    assert got["all-gather"] == pytest.approx(3 / 4 * 8 * 128 * 4)
+    assert collective_counts("\n".join([combined, tpu])) == {
+        "all-reduce": 1, "all-gather": 1}
 
 
 # ------------------------------------------------------------- ZeRO plans

@@ -152,7 +152,7 @@ def test_awkward_seq_len_pads_and_matches():
 def test_select_blocks_geometry():
     from repro.kernels.d2ft_attention import select_blocks
     assert select_blocks(256, 128, 128) == (128, 128, 256)   # exact
-    assert select_blocks(5, 128, 128) == (5, 5, 5)           # tiny seq
+    assert select_blocks(5, 128, 128) == (8, 8, 8)           # tiny seq pads
     assert select_blocks(192, 128, 128) == (96, 96, 192)     # near divisor
     assert select_blocks(257, 128, 128) == (128, 128, 384)   # pad, no slivers
 

@@ -161,7 +161,6 @@ def test_parity_matrix_lora(path, setup, lora_reference):
         for _ in range(STEPS):
             p, s = step(p, s, batch, gates)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.mesh import make_data_mesh
@@ -188,10 +187,10 @@ def test_parity_matrix_lora(path, setup, lora_reference):
             g = apply_grad_sync(g, plan, "data")
             return opt.update(g, st, lora_p)
 
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(), P("data"), (P(None, "data"), P(None, "data"))),
-            out_specs=(P(), P()), check_rep=False))
+            out_specs=(P(), P()), check_vma=False))
         p, s = lora0, opt.init(lora0)
         for _ in range(STEPS):
             p, s = step(p, s, batch, gates)

@@ -94,7 +94,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import ModelConfig, MoEConfig
-from repro.launch.mesh import compat_make_mesh
+from jax.sharding import AxisType
 from repro.models.transformer import init_model, lm_loss
 from repro.sharding.policy import ShardingPolicy
 
@@ -102,7 +102,7 @@ cfg = ModelConfig(name="t", arch_type="moe", n_layers=2, d_model=32,
                   n_heads=4, n_kv_heads=2, d_ff=0, vocab_size=64,
                   moe=MoEConfig(n_experts=4, top_k=2, d_ff=32,
                                 capacity_factor=4.0))
-mesh = compat_make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 params = init_model(jax.random.PRNGKey(0), cfg)
 toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
 l0, _ = lm_loss(params, cfg, toks, toks)
@@ -139,13 +139,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.configs.base import ModelConfig
-from repro.launch.mesh import compat_make_mesh
+from jax.sharding import AxisType
 from repro.models.transformer import init_model, forward
 from repro.sharding.policy import ShardingPolicy
 
 cfg = ModelConfig(name="t", arch_type="dense", n_layers=2, d_model=32,
                   n_heads=5, n_kv_heads=5, d_ff=64, vocab_size=64)
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 params = init_model(jax.random.PRNGKey(0), cfg)
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
 l0, _ = forward(params, cfg, tokens=toks)
