@@ -384,6 +384,11 @@ def _apply_rglru_inner(p, h, cfg: ModelConfig, layer_gates,
                                  live_bounds=live_bounds)
 
 
+# the named scope of each token-mixer kind in the compiled step's metadata
+MIXER_SCOPE = {ATTN_GLOBAL: "attn", ATTN_LOCAL: "attn", SSD: "ssd",
+               RGLRU: "rglru"}
+
+
 def apply_block(p, x, kind: str, cfg: ModelConfig, layer_gates=None,
                 policy=None, use_kernel: bool = False, live_bounds=None,
                 tp=None):
@@ -391,35 +396,42 @@ def apply_block(p, x, kind: str, cfg: ModelConfig, layer_gates=None,
 
     tp: optional (axis_name, T) shard_map tensor-parallel spec — attention
     heads and FFN columns shard over the axis, SSD/RG-LRU/MoE blocks run
-    replicated (their grads stay replicated, so no psum is needed)."""
-    h = apply_norm(p["norm1"], x, cfg.norm)
-    if kind in (ATTN_GLOBAL, ATTN_LOCAL):
-        c = _apply_attn_inner(p["attn"], h, kind, cfg, layer_gates, policy,
-                              use_kernel, live_bounds, tp)
-    elif kind == SSD:
-        c = _apply_ssd_inner(p["ssd"], h, cfg, layer_gates, use_kernel,
-                             live_bounds)
-    elif kind == RGLRU:
-        c = _apply_rglru_inner(p["rglru"], h, cfg, layer_gates, use_kernel,
-                               live_bounds)
-    if policy is not None:
-        # constrain the CONTRIBUTION before the residual add so GSPMD emits
-        # a reduce-scatter of the partial-sum projection instead of
-        # all-reduce + slice (Megatron sequence-parallel; §Perf iter q2)
-        c = policy.residual(c)
-    x = x + c
-    if policy is not None:
-        x = policy.residual(x)
-    aux = None
-    if "norm2" in p:
-        h2 = apply_norm(p["norm2"], x, cfg.norm)
-        y, aux = _apply_ffn(p, h2, cfg, layer_gates, policy, use_kernel,
-                            live_bounds, tp)
+    replicated (their grads stay replicated, so no psum is needed).
+
+    Each half runs under a ``jax.named_scope``: the token mixer under
+    ``attn`` (or ``ssd``, ``rglru``), the FFN under ``mlp``, each with its
+    norm and residual add."""
+    with jax.named_scope(MIXER_SCOPE[kind]):
+        h = apply_norm(p["norm1"], x, cfg.norm)
+        if kind in (ATTN_GLOBAL, ATTN_LOCAL):
+            c = _apply_attn_inner(p["attn"], h, kind, cfg, layer_gates,
+                                  policy, use_kernel, live_bounds, tp)
+        elif kind == SSD:
+            c = _apply_ssd_inner(p["ssd"], h, cfg, layer_gates, use_kernel,
+                                 live_bounds)
+        elif kind == RGLRU:
+            c = _apply_rglru_inner(p["rglru"], h, cfg, layer_gates,
+                                   use_kernel, live_bounds)
         if policy is not None:
-            y = policy.residual(y)
-        x = x + y
+            # constrain the CONTRIBUTION before the residual add so GSPMD
+            # emits a reduce-scatter of the partial-sum projection instead
+            # of all-reduce + slice (Megatron sequence-parallel; §Perf
+            # iter q2)
+            c = policy.residual(c)
+        x = x + c
         if policy is not None:
             x = policy.residual(x)
+    aux = None
+    if "norm2" in p:
+        with jax.named_scope("mlp"):
+            h2 = apply_norm(p["norm2"], x, cfg.norm)
+            y, aux = _apply_ffn(p, h2, cfg, layer_gates, policy, use_kernel,
+                                live_bounds, tp)
+            if policy is not None:
+                y = policy.residual(y)
+            x = x + y
+            if policy is not None:
+                x = policy.residual(x)
     return x, aux
 
 
@@ -478,75 +490,82 @@ def forward(params, cfg: ModelConfig, tokens=None, features=None,
         ``apply_block``). Embedding/norm/logits compute stays replicated.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
-    parts = []
-    if features is not None:
-        parts.append((features.astype(cdt) @ params["frontend_proj"].astype(cdt)))
-    if tokens is not None:
-        from repro.models.layers import apply_embedding
-        parts.append(apply_embedding(params["embed"], tokens).astype(cdt))
-    x = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
-    if policy is not None:
-        x = policy.residual(x)
+    with jax.named_scope("embed"):
+        parts = []
+        if features is not None:
+            parts.append(features.astype(cdt)
+                         @ params["frontend_proj"].astype(cdt))
+        if tokens is not None:
+            from repro.models.layers import apply_embedding
+            parts.append(apply_embedding(params["embed"], tokens).astype(cdt))
+        x = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+        if policy is not None:
+            x = policy.residual(x)
 
     n_cycles, pat, rem = layer_groups(cfg)
     P = len(pat)
     aux_sum = jnp.zeros((), jnp.float32)
 
-    if gates is not None:
-        g_f, g_b = gates
-        g_f_c = g_f[:n_cycles * P].reshape(n_cycles, P, *g_f.shape[1:])
-        g_b_c = g_b[:n_cycles * P].reshape(n_cycles, P, *g_b.shape[1:])
-        g_rest = (g_f[n_cycles * P:], g_b[n_cycles * P:])
-    else:
-        g_f_c = g_b_c = g_rest = None
-
-    if n_cycles > 0:
-        def cycle_body(carry, xs):
-            x, aux = carry
-            if gates is not None:
-                blocks, gfc, gbc = xs
-            else:
-                (blocks,) = xs
-            for i in range(P):
-                lg = (gfc[i], gbc[i]) if gates is not None else None
-                x, a = apply_block(blocks[i], x, pat[i], cfg, lg, policy,
-                                   use_kernel, live_bounds, tp)
-                if a is not None:
-                    aux = aux + a["load_balance"] + a["router_z"]
-            return (x, aux), None
-
-        body = cycle_body
-        if remat:
-            body = jax.checkpoint(cycle_body, prevent_cse=False)
-        xs = (params["cycles"],) if gates is None else (
-            params["cycles"], g_f_c, g_b_c)
-        if n_cycles <= 2:
-            # Unrolled: XLA's cost_analysis counts a while body ONCE
-            # regardless of trip count, so the dry-run's depth-1/depth-2
-            # FLOP extrapolation needs shallow models fully inlined.
-            for c in range(n_cycles):
-                xs_c = jax.tree.map(lambda a: a[c], xs)
-                (x, aux_sum), _ = body((x, aux_sum), xs_c)
-        else:
-            (x, aux_sum), _ = jax.lax.scan(body, (x, aux_sum), xs)
-
-    for i, kind in enumerate(rem):
-        lg = None
+    # the layer stack's own ops (gate and stacked-parameter slices, the
+    # scan's saved residuals, per-cycle gradient assembly) under "layers";
+    # each block's compute under its own scopes (apply_block)
+    with jax.named_scope("layers"):
         if gates is not None:
-            lg = (g_rest[0][i], g_rest[1][i])
-        x, a = apply_block(params["rest"][i], x, kind, cfg, lg, policy,
-                           use_kernel, live_bounds, tp)
-        if a is not None:
-            aux_sum = aux_sum + a["load_balance"] + a["router_z"]
+            g_f, g_b = gates
+            g_f_c = g_f[:n_cycles * P].reshape(n_cycles, P, *g_f.shape[1:])
+            g_b_c = g_b[:n_cycles * P].reshape(n_cycles, P, *g_b.shape[1:])
+            g_rest = (g_f[n_cycles * P:], g_b[n_cycles * P:])
+        else:
+            g_f_c = g_b_c = g_rest = None
 
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["table"].T.astype(cdt)
-    else:
-        logits = x @ params["unembed"].astype(cdt)
-    if policy is not None:
-        logits = policy.logits(logits)
-    logits = softcap(logits, cfg.logit_softcap)
+        if n_cycles > 0:
+            def cycle_body(carry, xs):
+                x, aux = carry
+                if gates is not None:
+                    blocks, gfc, gbc = xs
+                else:
+                    (blocks,) = xs
+                for i in range(P):
+                    lg = (gfc[i], gbc[i]) if gates is not None else None
+                    x, a = apply_block(blocks[i], x, pat[i], cfg, lg, policy,
+                                       use_kernel, live_bounds, tp)
+                    if a is not None:
+                        aux = aux + a["load_balance"] + a["router_z"]
+                return (x, aux), None
+
+            body = cycle_body
+            if remat:
+                body = jax.checkpoint(cycle_body, prevent_cse=False)
+            xs = (params["cycles"],) if gates is None else (
+                params["cycles"], g_f_c, g_b_c)
+            if n_cycles <= 2:
+                # Unrolled: XLA's cost_analysis counts a while body ONCE
+                # regardless of trip count, so the dry-run's depth-1/depth-2
+                # FLOP extrapolation needs shallow models fully inlined.
+                for c in range(n_cycles):
+                    xs_c = jax.tree.map(lambda a: a[c], xs)
+                    (x, aux_sum), _ = body((x, aux_sum), xs_c)
+            else:
+                (x, aux_sum), _ = jax.lax.scan(body, (x, aux_sum), xs)
+
+        for i, kind in enumerate(rem):
+            lg = None
+            if gates is not None:
+                lg = (g_rest[0][i], g_rest[1][i])
+            x, a = apply_block(params["rest"][i], x, kind, cfg, lg, policy,
+                               use_kernel, live_bounds, tp)
+            if a is not None:
+                aux_sum = aux_sum + a["load_balance"] + a["router_z"]
+
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"]["table"].T.astype(cdt)
+        else:
+            logits = x @ params["unembed"].astype(cdt)
+        if policy is not None:
+            logits = policy.logits(logits)
+        logits = softcap(logits, cfg.logit_softcap)
     return logits, {"aux_loss": aux_sum}
 
 
@@ -787,8 +806,10 @@ def lm_loss(params, cfg: ModelConfig, tokens, labels, features=None,
                           gates=gates, policy=policy, remat=remat,
                           use_kernel=use_kernel, live_bounds=live_bounds,
                           tp=tp)
-    if features is not None and tokens is not None:
-        # VLM: loss only over the text region (labels align to text tokens)
-        logits = logits[:, -labels.shape[1]:]
-    loss = fused_xent(logits, labels)
-    return loss + aux["aux_loss"], {"ce": loss, "aux": aux["aux_loss"]}
+    with jax.named_scope("head"):
+        if features is not None and tokens is not None:
+            # VLM: loss only over the text region (labels align to text
+            # tokens)
+            logits = logits[:, -labels.shape[1]:]
+        loss = fused_xent(logits, labels)
+        return loss + aux["aux_loss"], {"ce": loss, "aux": aux["aux_loss"]}

@@ -82,25 +82,29 @@ def vit_forward(params, images, cfg: ViTConfig, gates=None,
     Returns logits [B, n_classes].
     """
     bb = cfg.backbone()
-    x = patchify(images, cfg.patch) @ params["patch_proj"] + params["patch_bias"]
-    cls = jnp.broadcast_to(params["cls"], (x.shape[0], 1, cfg.d_model))
-    x = jnp.concatenate([cls, x], axis=1) + params["pos"]
+    with jax.named_scope("embed"):
+        x = (patchify(images, cfg.patch) @ params["patch_proj"]
+             + params["patch_bias"])
+        cls = jnp.broadcast_to(params["cls"], (x.shape[0], 1, cfg.d_model))
+        x = jnp.concatenate([cls, x], axis=1) + params["pos"]
     for i, blk in enumerate(params["blocks"]):
         lg = None
         if gates is not None:
             lg = (gates[0][i], gates[1][i])
         x, _ = apply_block(blk, x, ATTN_GLOBAL, bb, lg,
                            use_kernel=use_kernel, live_bounds=live_bounds)
-    x = apply_norm(params["final_norm"], x, "layer")
-    return x[:, 0] @ params["head"]
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, "layer")
+        return x[:, 0] @ params["head"]
 
 
 def vit_loss(params, images, labels, cfg: ViTConfig, gates=None,
              use_kernel: bool = False, live_bounds=None):
     logits = vit_forward(params, images, cfg, gates, use_kernel=use_kernel,
                          live_bounds=live_bounds)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    ll = jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
-    loss = -jnp.mean(ll)
-    acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
+    with jax.named_scope("head"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        ll = jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+        loss = -jnp.mean(ll)
+        acc = jnp.mean((jnp.argmax(logits, -1) == labels).astype(jnp.float32))
     return loss, {"acc": acc}

@@ -5,7 +5,6 @@ paper's ViT experiments and is what the paper-table benchmarks call.
 """
 from __future__ import annotations
 
-import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Optional
@@ -25,18 +24,34 @@ from repro.models.transformer import lm_loss
 from repro.models.vit import ViTConfig, vit_loss
 from repro.optim.optimizers import (Optimizer, clip_by_global_norm,
                                     clip_scale)
+from repro.train.spans import each_step, span
 
 
 @dataclass
 class TrainLog:
     losses: list = field(default_factory=list)
     metrics: list = field(default_factory=list)
+    # per step: dispatch of the jitted step to its loss being ready
     step_times: list = field(default_factory=list)
     # distributed path: rebalance report + sync-plan byte report
     extras: dict = field(default_factory=dict)
+    # per step: host phases and compile events (train.spans.StepRecord)
+    steps: list = field(default_factory=list)
+    # replans: schedules planned; step_builds: jitted steps made
+    counters: dict = field(default_factory=lambda: {"replans": 0,
+                                                    "step_builds": 0})
 
     def last(self, k: str):
         return self.metrics[-1][k] if self.metrics else None
+
+
+def _read_back(log: TrainLog, metrics):
+    """The step's time from its dispatch and wait spans, then its metrics
+    on the host (the ``readback`` phase)."""
+    log.step_times.append(log.steps[-1].seconds("dispatch", "wait"))
+    with span(log, "readback"):
+        log.losses.append(float(metrics["loss"]))
+        log.metrics.append({k: float(v) for k, v in metrics.items()})
 
 
 # ------------------------------------------------------------------ LLM path
@@ -74,8 +89,10 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, *, use_gates: bool,
     def step(params, opt_state, batch, sched_args=None):
         (loss, metrics), grads = jax.value_and_grad(
             loss_of, has_aux=True)(params, batch, sched_args)
-        grads, gnorm = clip_by_global_norm(grads, clip)
-        params, opt_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, clip)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(grads, opt_state, params)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         return params, opt_state, metrics
 
@@ -111,40 +128,44 @@ def finetune(params, cfg: ModelConfig, d2: Optional[D2FTConfig],
             step_fns[bounds] = jax.jit(make_train_step(
                 cfg, opt, use_gates=d2 is not None, packed=packed,
                 use_kernel=use_kernel, live_bounds=bounds))
+            log.counters["step_builds"] += 1
         return step_fns[bounds]
 
     sched = None
-    for i, batch in enumerate(batches):
-        if i >= steps:
-            break
+    for i, batch in each_step(log, batches, steps):
         if d2 is not None and sched is None:
             from repro.data.synthetic import split_microbatches
-            mbs = split_microbatches(batch, d2.n_microbatches)
-            sched = plan_from_scores(
-                cfg, d2, params, mbs,
-                lambda p, mb: lm_loss(p, cfg, mb.get("tokens"), mb["labels"],
-                                      features=mb.get("features"))[0])
-        sched_args = None
-        bounds = None
-        if d2 is not None:
-            B = batch["labels"].shape[0]
-            mb_of = microbatch_assignment(B, d2.n_microbatches)
-            if packed:
-                idx, bwd, val, _ = packed_indices(sched, mb_of)
+            with span(log, "plan"):
+                mbs = split_microbatches(batch, d2.n_microbatches)
+                sched = plan_from_scores(
+                    cfg, d2, params, mbs,
+                    lambda p, mb: lm_loss(p, cfg, mb.get("tokens"),
+                                          mb["labels"],
+                                          features=mb.get("features"))[0])
+            log.counters["replans"] += 1
+        with span(log, "prepare"):
+            sched_args = None
+            bounds = None
+            if d2 is not None:
+                B = batch["labels"].shape[0]
+                mb_of = microbatch_assignment(B, d2.n_microbatches)
+                if packed:
+                    idx, bwd, val, _ = packed_indices(sched, mb_of)
+                else:
+                    sched_args = gates_from_schedule(sched, mb_of)
+                    if use_kernel:
+                        bounds = live_slice_bounds(sched, mb_of)
+            step_fn = get_step(bounds)
+        if d2 is not None and packed:
+            with span(log, "h2d"):
                 sched_args = (jnp.asarray(idx), jnp.asarray(bwd),
                               jnp.asarray(val))
-            else:
-                sched_args = gates_from_schedule(sched, mb_of)
-                if use_kernel:
-                    bounds = live_slice_bounds(sched, mb_of)
-        step_fn = get_step(bounds)
-        t0 = time.perf_counter()
-        params, opt_state, metrics = step_fn(params, opt_state, batch,
-                                             sched_args)
-        jax.block_until_ready(metrics["loss"])
-        log.step_times.append(time.perf_counter() - t0)
-        log.losses.append(float(metrics["loss"]))
-        log.metrics.append({k: float(v) for k, v in metrics.items()})
+        with span(log, "dispatch"):
+            params, opt_state, metrics = step_fn(params, opt_state, batch,
+                                                 sched_args)
+        with span(log, "wait"):
+            jax.block_until_ready(metrics["loss"])
+        _read_back(log, metrics)
     return params, opt_state, log
 
 
@@ -448,6 +469,17 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
                      bad_devices=n_bad,
                      bad_blocks=jax.lax.psum(n_bad_blocks, axis_name)))
 
+    def clip_shards(gsync):
+        """Global-norm clip of grads in the plan's shard layout: zero-leaf
+        shards tile their tensors disjointly across devices, so one scalar
+        psum completes their square sum; fallback leaves are replicated
+        and added locally."""
+        with jax.named_scope("clip"):
+            shard_sq, full_sq = zero_norm_sq(gsync, sync_plan)
+            gnorm = jnp.sqrt(jax.lax.psum(shard_sq, axis_name) + full_sq)
+            scale = clip_scale(gnorm, clip)
+            return jax.tree.map(lambda g: g * scale, gsync), gnorm
+
     def local_step(params, opt_state, batch, gates, fault=None, thresh=None):
         (loss, metrics), grads = grads_of(params, batch, gates)
         if guard:
@@ -457,8 +489,10 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
         metrics = {k: jax.lax.pmean(v, axis_name) for k, v in metrics.items()}
         # post-sync grads are the global mean on every device, so the norm,
         # clip and optimizer update stay replicated without more collectives
-        grads, gnorm = clip_by_global_norm(grads, clip)
-        new_params, new_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, clip)
+        with jax.named_scope("optimizer"):
+            new_params, new_state = opt.update(grads, opt_state, params)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         if guard:
             return finish_guarded(params, opt_state, new_params, new_state,
@@ -476,18 +510,13 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
         gsync = apply_zero_scatter(grads, sync_plan, axis_name)
         loss = jax.lax.pmean(loss, axis_name)
         metrics = {k: jax.lax.pmean(v, axis_name) for k, v in metrics.items()}
-        # global grad norm: zero-leaf shards tile their tensors disjointly
-        # across devices, so one scalar psum completes their square sum;
-        # fallback leaves are replicated and added locally
-        shard_sq, full_sq = zero_norm_sq(gsync, sync_plan)
-        gnorm = jnp.sqrt(jax.lax.psum(shard_sq, axis_name) + full_sq)
-        scale = clip_scale(gnorm, clip)
-        gsync = jax.tree.map(lambda g: g * scale, gsync)
+        gsync, gnorm = clip_shards(gsync)
         # each device updates only its owned shard (moments arrive sharded
         # through in_specs); the schedule-masked all-gather re-replicates
         # exactly the runs whose params can have changed
-        pshard = zero_shard_params(params, sync_plan, axis_name)
-        new_shard, new_state = opt.update(gsync, opt_state, pshard)
+        with jax.named_scope("optimizer"):
+            pshard = zero_shard_params(params, sync_plan, axis_name)
+            new_shard, new_state = opt.update(gsync, opt_state, pshard)
         new_params = apply_zero_gather(new_shard, params, sync_plan,
                                        axis_name)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
@@ -509,14 +538,12 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
         gsync = apply_zero_scatter(grads, sync_plan, axis_name)
         loss = jax.lax.pmean(loss, axis_name)
         metrics = {k: jax.lax.pmean(v, axis_name) for k, v in metrics.items()}
-        shard_sq, full_sq = zero_norm_sq(gsync, sync_plan)
-        gnorm = jnp.sqrt(jax.lax.psum(shard_sq, axis_name) + full_sq)
-        scale = clip_scale(gnorm, clip)
-        gsync = jax.tree.map(lambda g: g * scale, gsync)
+        gsync, gnorm = clip_shards(gsync)
         # grads and params are both shard-resident at zero leaves: the
         # update never touches a full tensor and there is no post-update
         # gather — next step's materialization starts from the new shards.
-        new_params, new_state = upd_opt.update(gsync, opt_state, params)
+        with jax.named_scope("optimizer"):
+            new_params, new_state = upd_opt.update(gsync, opt_state, params)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         if guard:
             return finish_guarded(params, opt_state, new_params, new_state,
@@ -541,11 +568,9 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
             fn, has_aux=True)(params)
         loss = jax.lax.pmean(loss, axis_name)
         metrics = {k: jax.lax.pmean(v, axis_name) for k, v in metrics.items()}
-        shard_sq, full_sq = zero_norm_sq(gsync, sync_plan)
-        gnorm = jnp.sqrt(jax.lax.psum(shard_sq, axis_name) + full_sq)
-        scale = clip_scale(gnorm, clip)
-        gsync = jax.tree.map(lambda g: g * scale, gsync)
-        new_params, new_state = upd_opt.update(gsync, opt_state, params)
+        gsync, gnorm = clip_shards(gsync)
+        with jax.named_scope("optimizer"):
+            new_params, new_state = upd_opt.update(gsync, opt_state, params)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm)
         return new_params, new_state, metrics
 
@@ -564,8 +589,10 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
                 bad, n_bad_blocks = _grad_anomaly(grads, thresh)
                 grads = jax.tree.map(
                     lambda g: jnp.where(bad, jnp.zeros_like(g), g), grads)
-            grads, gnorm = clip_by_global_norm(grads, clip)
-            new_params, new_state = opt.update(grads, opt_state, params)
+            with jax.named_scope("clip"):
+                grads, gnorm = clip_by_global_norm(grads, clip)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = opt.update(grads, opt_state, params)
             metrics = dict(metrics, loss=loss, grad_norm=gnorm)
             if guard:
                 # per-replica skip: only the anomalous replica holds back
@@ -754,56 +781,58 @@ def finetune_distributed(params, cfg: ModelConfig, d2: D2FTConfig,
             record["stages"] = stage_rep
         return sched, assignment, stage_assign, sync_plan, record
 
-    for i, batch in enumerate(batches):
-        if i >= steps:
-            break
+    for i, batch in each_step(log, batches, steps):
         if sched is None or (refresh_every and i % refresh_every == 0
                              and i > 0):
-            old_plan = sync_plan
-            if sync_mode == "zero3" and old_plan is not None:
-                # back to canonical before scoring: the scoring pass reads
-                # param values whose group structure the shard layout
-                # permutes
-                params = zero_reshard(params, old_plan, None)
-            sched, assignment, stage_assign, sync_plan, record = \
-                replan(batch)
-            if sync_mode == "zero":
-                # canonical -> shard layout at the first plan (zeros are
-                # layout-invariant, but a params-shaped state initialized
-                # from values, e.g. an EMA copy, is not), then between
-                # layouts on refresh
-                opt_state = _reshard_opt_state(opt_state, old_plan,
-                                               sync_plan)
-            elif sync_mode == "zero3":
-                params = zero_reshard(params, None, sync_plan)
-                opt_state = _reshard_opt_state(opt_state, old_plan,
-                                               sync_plan)
-                log.extras["zero3_params"] = record["zero3_params"]
-            record["step"] = i
-            log.extras["rebalance"] = record["rebalance"]
-            log.extras["sync"] = record["sync"]
-            if "stages" in record:
-                log.extras["stages"] = record["stages"]
-            log.extras.setdefault("refreshes", []).append(record)
+            with span(log, "plan"):
+                old_plan = sync_plan
+                if sync_mode == "zero3" and old_plan is not None:
+                    # back to canonical before scoring: the scoring pass reads
+                    # param values whose group structure the shard layout
+                    # permutes
+                    params = zero_reshard(params, old_plan, None)
+                sched, assignment, stage_assign, sync_plan, record = \
+                    replan(batch)
+                if sync_mode == "zero":
+                    # canonical -> shard layout at the first plan (zeros are
+                    # layout-invariant, but a params-shaped state initialized
+                    # from values, e.g. an EMA copy, is not), then between
+                    # layouts on refresh
+                    opt_state = _reshard_opt_state(opt_state, old_plan,
+                                                   sync_plan)
+                elif sync_mode == "zero3":
+                    params = zero_reshard(params, None, sync_plan)
+                    opt_state = _reshard_opt_state(opt_state, old_plan,
+                                                   sync_plan)
+                    log.extras["zero3_params"] = record["zero3_params"]
+                record["step"] = i
+                log.extras["rebalance"] = record["rebalance"]
+                log.extras["sync"] = record["sync"]
+                if "stages" in record:
+                    log.extras["stages"] = record["stages"]
+                log.extras.setdefault("refreshes", []).append(record)
+            log.counters["replans"] += 1
             step_fn = None
-        B = batch["labels"].shape[0]
-        mb_of = microbatch_assignment(B, d2.n_microbatches)
-        perm = device_sample_order(assignment, mb_of)
-        batch = jax.tree.map(lambda a: a[perm], batch)
-        gates = gates_from_schedule(sched, mb_of[perm])
-        if step_fn is None:
-            bounds = distributed_live_bounds(sched, mb_of, assignment) \
-                if use_kernel else None
-            step_fn = make_distributed_train_step(
-                cfg, opt, mesh, sync_plan, clip=clip,
-                live_bounds=bounds, params=params, parallel=parallel,
-                stage_assignment=stage_assign)
-        t0 = time.perf_counter()
-        params, opt_state, metrics = step_fn(params, opt_state, batch, gates)
-        jax.block_until_ready(metrics["loss"])
-        log.step_times.append(time.perf_counter() - t0)
-        log.losses.append(float(metrics["loss"]))
-        log.metrics.append({k: float(v) for k, v in metrics.items()})
+        with span(log, "prepare"):
+            B = batch["labels"].shape[0]
+            mb_of = microbatch_assignment(B, d2.n_microbatches)
+            perm = device_sample_order(assignment, mb_of)
+            batch = jax.tree.map(lambda a: a[perm], batch)
+            gates = gates_from_schedule(sched, mb_of[perm])
+            if step_fn is None:
+                bounds = distributed_live_bounds(sched, mb_of, assignment) \
+                    if use_kernel else None
+                step_fn = make_distributed_train_step(
+                    cfg, opt, mesh, sync_plan, clip=clip,
+                    live_bounds=bounds, params=params, parallel=parallel,
+                    stage_assignment=stage_assign)
+                log.counters["step_builds"] += 1
+        with span(log, "dispatch"):
+            params, opt_state, metrics = step_fn(params, opt_state, batch,
+                                                 gates)
+        with span(log, "wait"):
+            jax.block_until_ready(metrics["loss"])
+        _read_back(log, metrics)
     if sync_mode in ("zero", "zero3") and sync_plan is not None:
         # hand back canonical element order: the shard layout is an
         # internal representation a checkpoint or another path must not see
@@ -826,8 +855,10 @@ def make_vit_step(cfg: ViTConfig, opt: Optimizer, use_gates: bool,
                             use_kernel=use_kernel,
                             live_bounds=live_bounds if use_gates else None)
         (loss, metrics), grads = jax.value_and_grad(loss_of, has_aux=True)(params)
-        grads, gnorm = clip_by_global_norm(grads, clip)
-        params, opt_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, clip)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(grads, opt_state, params)
         return params, opt_state, dict(metrics, loss=loss, grad_norm=gnorm)
     return step
 
@@ -855,29 +886,34 @@ def finetune_vit(params, cfg: ViTConfig, opt: Optimizer, batches,
             step_fns[bounds] = jax.jit(make_vit_step(
                 cfg, opt, use_gates, use_kernel=use_kernel,
                 live_bounds=bounds))
+            log.counters["step_builds"] += 1
         return step_fns[bounds]
 
     step_fn = get_step(None)
     sched = None
-    for i, (images, labels) in enumerate(batches):
-        if i >= steps:
-            break
+    for i, (images, labels) in each_step(log, batches, steps):
         gates = None
         if schedule_fn is not None:
-            new = schedule_fn(i, params, images, labels)
-            sched = new if new is not None else sched
-            mb_of = microbatch_assignment(images.shape[0], n_microbatches)
-            gates = gates_from_schedule(sched, mb_of)
-            if use_kernel:
-                step_fn = get_step(live_slice_bounds(sched, mb_of))
-        t0 = time.perf_counter()
-        params, opt_state, metrics = step_fn(
-            params, opt_state, jnp.asarray(images), jnp.asarray(labels),
-            gates)
-        jax.block_until_ready(metrics["loss"])
-        log.step_times.append(time.perf_counter() - t0)
-        log.losses.append(float(metrics["loss"]))
-        log.metrics.append({k: float(v) for k, v in metrics.items()})
+            with span(log, "plan") as plan:
+                new = schedule_fn(i, params, images, labels)
+                plan.keep = new is not None
+            if new is not None:
+                sched = new
+                log.counters["replans"] += 1
+            with span(log, "prepare"):
+                mb_of = microbatch_assignment(images.shape[0],
+                                              n_microbatches)
+                gates = gates_from_schedule(sched, mb_of)
+                if use_kernel:
+                    step_fn = get_step(live_slice_bounds(sched, mb_of))
+        with span(log, "h2d"):
+            x, y = jnp.asarray(images), jnp.asarray(labels)
+        with span(log, "dispatch"):
+            params, opt_state, metrics = step_fn(params, opt_state, x, y,
+                                                 gates)
+        with span(log, "wait"):
+            jax.block_until_ready(metrics["loss"])
+        _read_back(log, metrics)
     return params, opt_state, log
 
 
