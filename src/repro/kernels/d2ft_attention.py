@@ -36,6 +36,27 @@ the *live* work instead of merely skipping the MXU:
 Supports causal and sliding-window masks (the assigned archs' local
 -attention layers).
 
+Two tilings of the same algorithm, chosen by the sequence length
+(``attention_geometry``):
+
+* **short path** — a sequence of at most ``SHORT_SEQ_ROWS`` (256) rows,
+  rounded up to the 8-row sublane, is one whole, unpadded tile per slice
+  (ViT-S/16's 197 tokens). The grid is 1-D over the dispatched slices, and
+  each step takes ``spb`` slices as [spb, S, hd] blocks (lse and delta as
+  [spb, S, 1] columns). ``slices_per_step`` derives ``spb`` from (S,
+  head_dim, itemsize) so that the double-buffered blocks fill half the
+  default scoped VMEM (``SHORT_VMEM``), counting head_dim and the columns
+  at whole 128-lane widths: 8 forward and 4 backward slices a step at
+  S=197, head_dim 64. A step loops over its slices, each under its own
+  gate's ``@pl.when``. The forward is a plain full-row softmax (no m/l
+  scratch, no rescaling); the backward one pass per slice with s, p, dp
+  and ds in VMEM — the same 5 matmuls, no dq residency across steps. The
+  launch rounds the dispatch count up to whole steps with dead (gate 0)
+  slices. Causal and window masks are applied element-wise only: the
+  masked triangle is computed, which at this length costs less than the
+  grid steps a tiling would add.
+* **flash path** — longer sequences, described below.
+
 Tiling: q tiles [block_q, head_dim], kv tiles [block_k, head_dim] — both
 MXU-aligned (multiples of 128 for fp32/bf16 lanes). Forward scratch: the
 fp32 accumulator (block_q × head_dim) plus m/l online-softmax statistics in
@@ -128,6 +149,41 @@ _dispatch_count = _contract.dispatch_count
 _live_permutation = _contract.live_permutation
 
 
+def launch_shape(n_disp: int, spb_max: int):
+    """(grid steps, slices per step) for ``n_disp`` dispatched slices at
+    most ``spb_max`` to a step: the fewest steps, then the fewest slices
+    per step that cover ``n_disp`` — so fewer than one dead slice per step
+    pads the launch."""
+    steps = -(-n_disp // spb_max)
+    return steps, -(-n_disp // steps)
+
+
+def _launch(g, live, N: int, spb_max: int):
+    """(idx, spb): the gather permutation of the launched slices (None for
+    all N in order) and the slices per grid step. The launch is the
+    dispatch count rounded up to whole steps: past the live bound the
+    stable permutation holds only dead slices, and past N the indices are
+    out of range, which ``_gather`` fills with zeros (gate 0) and
+    ``_scatter`` drops. No live work is added either way."""
+    steps, spb = launch_shape(_dispatch_count(live, N), spb_max)
+    n_launch = steps * spb
+    if n_launch == N:
+        return None, spb
+    idx = _live_permutation(g, min(n_launch, N))
+    if n_launch > N:
+        idx = jnp.concatenate(
+            [idx, jnp.arange(N, n_launch, dtype=idx.dtype)])
+    return idx, spb
+
+
+def _gather(a, idx):
+    return jnp.take(a, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _scatter(base, idx, a):
+    return base.at[idx].set(a, mode="drop", unique_indices=True)
+
+
 # ================================================================== forward
 def _fwd_kernel(gate_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                 m_ref, l_ref, *, scale: float, causal: bool, window: int,
@@ -179,35 +235,82 @@ def _fwd_kernel(gate_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                                LSE_MASKED)
 
 
-def _forward(q, k, v, g_f, *, causal: bool, window: int, block_q: int,
-             block_k: int, interpret: bool, seq_len: int = 0,
-             live: int = None):
-    """Returns (o [B,H,S,hd], lse [B,H,S,1] f32). seq_len is the true length
-    when the arrays carry tile padding (0 means unpadded). ``live`` is the
-    static live-slice upper bound enabling compaction dispatch."""
-    B, H, S, hd = q.shape
-    assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
-    seq_len = seq_len or S
+def _fwd_short_kernel(gate_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                      scale: float, causal: bool, window: int, seq_len: int,
+                      spb: int):
+    """Short-sequence forward, grid (n_steps,): ``spb`` slices per step,
+    each one whole [S, hd] tile. A plain full-row softmax per slice — the
+    whole score row is in VMEM, so no m/l scratch and no rescaling. A
+    gated-off slice writes zeros and LSE_MASKED without a matmul."""
+    S = q_ref.shape[1]
+    base = pl.program_id(0) * spb
+
+    def one_slice(j, carry):
+        gate = gate_ref[base + j]
+
+        @pl.when(gate != 0)
+        def _compute():
+            q = q_ref[j].astype(jnp.float32)           # [S, hd]
+            k = k_ref[j].astype(jnp.float32)
+            v = v_ref[j].astype(jnp.float32)
+            s = jax.lax.dot_general(q * scale, k,
+                                    (((1,), (1,)), ((), ())))   # [S, S]
+            s = jnp.where(_tile_mask(0, 0, S, S, seq_len, causal, window),
+                          s, NEG_INF)
+            m = jnp.max(s, axis=1, keepdims=True)      # [S, 1]
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=1, keepdims=True)
+            acc = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+            o_ref[j] = (acc / l).astype(o_ref.dtype)
+            lse_ref[j] = m + jnp.log(l)
+
+        @pl.when(gate == 0)
+        def _skip():
+            o_ref[j] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+            lse_ref[j] = jnp.full(lse_ref.shape[1:], LSE_MASKED, jnp.float32)
+
+        return carry
+
+    jax.lax.fori_loop(0, spb, one_slice, 0)
+
+
+def _fwd_short_call(gate, q, k, v, *, spb: int, scale: float, causal: bool,
+                    window: int, seq_len: int, interpret: bool):
+    n, S, hd = q.shape
+    grid = (n // spb,)
+    _report_dispatch("fwd", grid)
+    mat = pl.BlockSpec((spb, S, hd), lambda i, g: (i, 0, 0))
+    col = pl.BlockSpec((spb, S, 1), lambda i, g: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_fwd_short_kernel, scale=scale, causal=causal,
+                          window=window, seq_len=seq_len, spb=spb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                   # g_f
+            grid=grid, in_specs=[mat, mat, mat], out_specs=[mat, col]),
+        out_shape=[
+            jax.ShapeDtypeStruct((n, S, hd), q.dtype),
+            jax.ShapeDtypeStruct((n, S, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="d2ft_attn_fwd_short",
+    )(gate, q, k, v)
+
+
+def _fwd_flash_call(gate, q, k, v, *, block_q: int, block_k: int,
+                    scale: float, causal: bool, window: int, seq_len: int,
+                    interpret: bool):
+    n, S, hd = q.shape
     n_q = S // block_q
     n_k = S // block_k
-    scale = 1.0 / (hd ** 0.5)
-
-    N = B * H
-    q, k, v = (a.reshape(N, S, hd) for a in (q, k, v))
-    g = g_f.reshape(N)
-    n_disp = _dispatch_count(live, N)
-    idx = None
-    if n_disp < N:
-        idx = _live_permutation(g, n_disp)
-        q, k, v, g = (jnp.take(a, idx, axis=0) for a in (q, k, v, g))
-
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, n_k=n_k, seq_len=seq_len)
 
-    grid = (n_disp, n_q, n_k)
+    grid = (n, n_q, n_k)
     _report_dispatch("fwd", grid)
-    o, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,                                   # g_f
@@ -232,22 +335,55 @@ def _forward(q, k, v, g_f, *, causal: bool, window: int, block_q: int,
                 pltpu.VMEM((block_q, 1), jnp.float32),    # l
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((n_disp, S, hd), q.dtype),
-            jax.ShapeDtypeStruct((n_disp, S, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, S, hd), q.dtype),
+            jax.ShapeDtypeStruct((n, S, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(_contract.gate_operand(g), q, k, v)
+        name="d2ft_attn_fwd_flash",
+    )(gate, q, k, v)
+
+
+def _forward(q, k, v, g_f, *, causal: bool, window: int, block_q: int,
+             block_k: int, interpret: bool, seq_len: int = 0,
+             live: int = None):
+    """Returns (o [B,H,S,hd], lse [B,H,S,1] f32). seq_len is the true length
+    when the arrays carry tile padding (0 means unpadded). ``live`` is the
+    static live-slice upper bound enabling compaction dispatch. One
+    whole-sequence tile (``is_short``) takes the short kernel, any other
+    tiling the flash kernel."""
+    B, H, S, hd = q.shape
+    assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
+    seq_len = seq_len or S
+    scale = 1.0 / (hd ** 0.5)
+
+    N = B * H
+    q, k, v = (a.reshape(N, S, hd) for a in (q, k, v))
+    g = g_f.reshape(N)
+    short = is_short(S, block_q, block_k)
+    spb_max = (slices_per_step(S, hd, q.dtype.itemsize, "fwd") if short
+               else 1)
+    idx, spb = _launch(g, live, N, spb_max)
+    if idx is not None:
+        q, k, v, g = (_gather(a, idx) for a in (q, k, v, g))
+
+    common = dict(scale=scale, causal=causal, window=window,
+                  seq_len=seq_len, interpret=interpret)
+    gate = _contract.gate_operand(g)
+    if short:
+        o, lse = _fwd_short_call(gate, q, k, v, spb=spb, **common)
+    else:
+        o, lse = _fwd_flash_call(gate, q, k, v, block_q=block_q,
+                                 block_k=block_k, **common)
 
     if idx is not None:
         # scatter live results back; dead (never-dispatched) slices are the
         # zero-fill, dispatched-but-gated-off padding slices wrote zeros /
         # LSE_MASKED themselves so the set() is a no-op value-wise.
-        o = jnp.zeros((N, S, hd), o.dtype).at[idx].set(
-            o, unique_indices=True)
-        lse = jnp.full((N, S, 1), LSE_MASKED, jnp.float32).at[idx].set(
-            lse, unique_indices=True)
+        o = _scatter(jnp.zeros((N, S, hd), o.dtype), idx, o)
+        lse = _scatter(jnp.full((N, S, 1), LSE_MASKED, jnp.float32), idx,
+                       lse)
     return o.reshape(B, H, S, hd), lse.reshape(B, H, S, 1)
 
 
@@ -258,9 +394,9 @@ def d2ft_flash_attention(q, k, v, gates, *, causal: bool = True,
     """Forward-only gated flash attention (no VJP registered).
 
     q, k, v: [B, H, S, hd] (kv heads already expanded to H);
-    gates: [B, H] float {0,1}. Returns [B, H, S, hd]. Sequence lengths that
-    don't divide the tiles go through the same ``select_blocks`` shrink-or
-    -pad wrapper as ``ops.gated_attention`` (padded rows are masked via the
+    gates: [B, H] float {0,1}. Returns [B, H, S, hd]. The tiles come from
+    the same ``attention_geometry`` wrapper as ``ops.gated_attention``
+    (a short sequence is one whole tile; padded rows are masked via the
     kernel's seq_len bound and sliced off). ``live`` optionally enables
     compaction dispatch with a static live-slice upper bound. For the
     differentiable path use ``gated_flash_attention`` / ``ops
@@ -339,35 +475,89 @@ def _bwd_fused_kernel(gate_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _backward(q, k, v, g_b, o, lse, do, *, causal: bool, window: int,
-              block_q: int, block_k: int, interpret: bool, seq_len: int = 0,
-              live: int = None):
-    B, H, S, hd = q.shape
-    seq_len = seq_len or S
+def _bwd_short_kernel(gate_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, dq_ref, dk_ref, dv_ref, *, scale: float,
+                      causal: bool, window: int, seq_len: int, spb: int):
+    """Short-sequence backward, grid (n_steps,): ``spb`` slices per step,
+    each one pass over its whole [S, hd] tile with ``s``, ``p``, ``dp`` and
+    ``ds`` all in VMEM — the same 5 matmuls as the flash kernel's tile, and
+    no dq residency across grid steps. ``g_b == 0`` skips every matmul and
+    writes zeros."""
+    S = q_ref.shape[1]
+    base = pl.program_id(0) * spb
+
+    def one_slice(j, carry):
+        gate = gate_ref[base + j]
+
+        @pl.when(gate != 0)
+        def _compute():
+            _maybe_count_block()
+            q = q_ref[j].astype(jnp.float32)           # [S, hd]
+            k = k_ref[j].astype(jnp.float32)
+            v = v_ref[j].astype(jnp.float32)
+            do = do_ref[j].astype(jnp.float32)
+            lse = lse_ref[j]                           # [S, 1]
+            delta = delta_ref[j]                       # [S, 1]
+            s = jax.lax.dot_general(q * scale, k,
+                                    (((1,), (1,)), ((), ())))   # [S, S]
+            s = jnp.where(_tile_mask(0, 0, S, S, seq_len, causal, window),
+                          s, NEG_INF)
+            p = jnp.exp(s - lse)
+            dv = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
+            ds = p * (dp - delta)
+            dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ()))) * scale
+            dk = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ()))) * scale
+            dq_ref[j] = dq.astype(dq_ref.dtype)
+            dk_ref[j] = dk.astype(dk_ref.dtype)
+            dv_ref[j] = dv.astype(dv_ref.dtype)
+
+        @pl.when(gate == 0)
+        def _skip():
+            for ref in (dq_ref, dk_ref, dv_ref):
+                ref[j] = jnp.zeros(ref.shape[1:], ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, spb, one_slice, 0)
+
+
+def _bwd_short_call(gate, q, k, v, do, lse, delta, *, spb: int,
+                    scale: float, causal: bool, window: int, seq_len: int,
+                    interpret: bool):
+    n, S, hd = q.shape
+    grid = (n // spb,)
+    _report_dispatch("bwd", grid)
+    mat = pl.BlockSpec((spb, S, hd), lambda i, g: (i, 0, 0))
+    col = pl.BlockSpec((spb, S, 1), lambda i, g: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_short_kernel, scale=scale, causal=causal,
+                          window=window, seq_len=seq_len, spb=spb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                                   # g_b
+            grid=grid, in_specs=[mat, mat, mat, mat, col, col],
+            out_specs=[mat, mat, mat]),
+        out_shape=[
+            jax.ShapeDtypeStruct((n, S, hd), jnp.float32),
+            jax.ShapeDtypeStruct((n, S, hd), k.dtype),
+            jax.ShapeDtypeStruct((n, S, hd), v.dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="d2ft_attn_bwd_short",
+    )(gate, q, k, v, do, lse, delta)
+
+
+def _bwd_flash_call(gate, q, k, v, do, lse, delta, *, block_q: int,
+                    block_k: int, scale: float, causal: bool, window: int,
+                    seq_len: int, interpret: bool):
+    n, S, hd = q.shape
     n_q = S // block_q
     n_k = S // block_k
-    scale = 1.0 / (hd ** 0.5)
-
-    N = B * H
-    q, k, v, o, do = (a.reshape(N, S, hd) for a in (q, k, v, o, do))
-    lse = lse.reshape(N, S, 1)
-    g = g_b.reshape(N)
-    n_disp = _dispatch_count(live, N)
-    idx = None
-    if n_disp < N:
-        idx = _live_permutation(g, n_disp)
-        q, k, v, o, do = (jnp.take(a, idx, axis=0)
-                          for a in (q, k, v, o, do))
-        lse, g = jnp.take(lse, idx, axis=0), jnp.take(g, idx, axis=0)
-    # delta_i = sum_d dO_id * O_id — cheap elementwise reduce, done outside
-    # the kernel (standard flash-bwd preprocessing) on the *compacted*
-    # operands so gated-off slices don't pay it either.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
-
-    grid = (n_disp, n_k, n_q)
+    grid = (n, n_k, n_q)
     _report_dispatch("bwd", grid)
-    dq, dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           window=window, block_q=block_q, block_k=block_k,
                           n_q=n_q, seq_len=seq_len),
@@ -401,19 +591,56 @@ def _backward(q, k, v, g_b, o, lse, do, *, causal: bool, window: int,
             scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                             pltpu.VMEM((block_k, hd), jnp.float32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((n_disp, S, hd), jnp.float32),
-            jax.ShapeDtypeStruct((n_disp, S, hd), k.dtype),
-            jax.ShapeDtypeStruct((n_disp, S, hd), v.dtype),
+            jax.ShapeDtypeStruct((n, S, hd), jnp.float32),
+            jax.ShapeDtypeStruct((n, S, hd), k.dtype),
+            jax.ShapeDtypeStruct((n, S, hd), v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(_contract.gate_operand(g), q, k, v, do, lse, delta)
+        name="d2ft_attn_bwd_flash",
+    )(gate, q, k, v, do, lse, delta)
+
+
+def _backward(q, k, v, g_b, o, lse, do, *, causal: bool, window: int,
+              block_q: int, block_k: int, interpret: bool, seq_len: int = 0,
+              live: int = None):
+    B, H, S, hd = q.shape
+    seq_len = seq_len or S
+    scale = 1.0 / (hd ** 0.5)
+
+    N = B * H
+    q, k, v, o, do = (a.reshape(N, S, hd) for a in (q, k, v, o, do))
+    lse = lse.reshape(N, S, 1)
+    g = g_b.reshape(N)
+    short = is_short(S, block_q, block_k)
+    spb_max = (slices_per_step(S, hd, q.dtype.itemsize, "bwd") if short
+               else 1)
+    idx, spb = _launch(g, live, N, spb_max)
+    if idx is not None:
+        q, k, v, o, do, lse, g = (_gather(a, idx)
+                                  for a in (q, k, v, o, do, lse, g))
+    # delta_i = sum_d dO_id * O_id — cheap elementwise reduce, done outside
+    # the kernel (standard flash-bwd preprocessing) on the *compacted*
+    # operands so gated-off slices don't pay it either.
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+
+    common = dict(scale=scale, causal=causal, window=window,
+                  seq_len=seq_len, interpret=interpret)
+    gate = _contract.gate_operand(g)
+    if short:
+        dq, dk, dv = _bwd_short_call(gate, q, k, v, do, lse, delta, spb=spb,
+                                     **common)
+    else:
+        dq, dk, dv = _bwd_flash_call(gate, q, k, v, do, lse, delta,
+                                     block_q=block_q, block_k=block_k,
+                                     **common)
 
     dq = dq.astype(q.dtype)
     if idx is not None:
-        dq, dk, dv = (jnp.zeros((N, S, hd), a.dtype).at[idx].set(
-            a, unique_indices=True) for a in (dq, dk, dv))
+        dq, dk, dv = (_scatter(jnp.zeros((N, S, hd), a.dtype), idx, a)
+                      for a in (dq, dk, dv))
     return (dq.reshape(B, H, S, hd), dk.reshape(B, H, S, hd),
             dv.reshape(B, H, S, hd))
 
@@ -467,6 +694,42 @@ gated_flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 
 # ======================================================= tile selection
 SUBLANE = 8   # f32 sublane tile: every q/k tile is a multiple of it
+LANE = 128    # lane width: a VMEM block's last dim is padded up to it
+
+# Sequences of at most this many rows (S rounded up to SUBLANE) run the
+# short kernels: one whole-sequence tile per slice. Their backward holds a
+# slice's [S, S] f32 scores, p, dp and ds in VMEM at once — 1 MiB at 256
+# rows beside the double-buffered blocks of several slices (SHORT_VMEM), in
+# the 16 MiB of scoped VMEM a v5e kernel gets by default. At 512 rows those
+# four would take 4 MiB and leave room for about one slice a step, while
+# the flash tiling's 128x128 tiles already amortise its per-step overhead.
+SHORT_SEQ_ROWS = 256
+# VMEM for the short kernels' double-buffered blocks: half the default
+# scoped VMEM, the other half for one slice's score-sized temporaries.
+SHORT_VMEM = 8 * 2 ** 20
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def is_short(S: int, block_q: int, block_k: int) -> bool:
+    """Whether the kernels run the short path: one tile spans the whole
+    (padded) sequence of at most ``SHORT_SEQ_ROWS`` rows."""
+    return block_q == block_k == S <= SHORT_SEQ_ROWS
+
+
+def slices_per_step(S: int, hd: int, itemsize: int, kind: str) -> int:
+    """Most slices one short-kernel grid step takes (``kind`` "fwd" or
+    "bwd") so that their double-buffered blocks fit ``SHORT_VMEM``. A block
+    [S, hd] occupies S rows of hd rounded up to whole lanes; the f32
+    [S, 1] columns (lse, delta) a whole lane each. Forward blocks: q, k, v,
+    o and the lse column; backward: q, k, v, do, dk, dv, the f32 dq, and
+    the lse and delta columns."""
+    lanes = _round_up(hd, LANE)
+    n_mat, n_f32, n_col = {"fwd": (4, 0, 1), "bwd": (6, 1, 2)}[kind]
+    row = lanes * (n_mat * itemsize + n_f32 * 4) + n_col * LANE * 4
+    return max(1, SHORT_VMEM // (2 * _round_up(S, SUBLANE) * row))
 
 
 def _largest_divisor(S: int, block: int) -> int:
@@ -478,9 +741,8 @@ def _largest_divisor(S: int, block: int) -> int:
 
 
 def select_blocks(S: int, block_q: int, block_k: int):
-    """(block_q, block_k, padded_S) used by ``ops.gated_attention``,
-    ``d2ft_flash_attention`` AND the FLOP/DMA accounting below — one source
-    of truth for tile geometry.
+    """(block_q, block_k, padded_S) of the flash path, for sequences longer
+    than ``SHORT_SEQ_ROWS`` (``attention_geometry`` picks the path).
 
     Tiles are always multiples of the 8-row sublane tile, as the TPU
     lowering requires of a block's second-minor dim. Exact fit when S
@@ -489,7 +751,7 @@ def select_blocks(S: int, block_q: int, block_k: int):
     otherwise keep the requested tiles and pad S up to a common multiple —
     never degenerate slivers (e.g. S=257 pads to 384 with 128-tiles, S=5
     pads to one 8-row tile)."""
-    cap = -(-S // SUBLANE) * SUBLANE
+    cap = _round_up(S, SUBLANE)
     bq = min(block_q, cap)
     bk = min(block_k, cap)
     if S % bq == 0 and S % bk == 0:
@@ -502,9 +764,23 @@ def select_blocks(S: int, block_q: int, block_k: int):
     return bq, bk, -(-S // m) * m
 
 
+def attention_geometry(S: int, block_q: int, block_k: int):
+    """(block_q, block_k, padded_S) used by ``ops.gated_attention``,
+    ``d2ft_flash_attention`` AND the FLOP/DMA accounting below — one source
+    of truth for tile geometry. A sequence of at most ``SHORT_SEQ_ROWS``
+    rows, once rounded up to the sublane, is one whole unpadded tile (the
+    short path): a block equal to the array's full extent needs no
+    multiple of 8, and the TPU lowering pads the VMEM tile itself (ViT-S's
+    S=197). A longer one takes ``select_blocks``' flash tiles."""
+    if _round_up(S, SUBLANE) <= SHORT_SEQ_ROWS:
+        return S, S, S
+    return select_blocks(S, block_q, block_k)
+
+
 def pad_to_blocks(q, k, v, block_q: int, block_k: int):
-    """Shared select_blocks + zero-pad step for every kernel entry point
-    (``ops.gated_attention`` and the forward-only ``d2ft_flash_attention``).
+    """Shared attention_geometry + zero-pad step for every kernel entry
+    point (``ops.gated_attention`` and the forward-only
+    ``d2ft_flash_attention``).
 
     Returns (q, k, v, bq, bk, S, Sp): operands padded along the sequence
     axis to Sp when S doesn't divide the chosen tiles (padded rows are
@@ -512,7 +788,7 @@ def pad_to_blocks(q, k, v, block_q: int, block_k: int):
     outputs back to S, and jnp.pad's VJP keeps the padding out of the
     gradients)."""
     S = q.shape[2]
-    bq, bk, Sp = select_blocks(S, block_q, block_k)
+    bq, bk, Sp = attention_geometry(S, block_q, block_k)
     if Sp != S:
         pad = ((0, 0), (0, 0), (0, Sp - S), (0, 0))
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
@@ -524,7 +800,9 @@ def live_block_count(S: int, block_q: int, block_k: int, causal: bool,
                      window: int, seq_len: int = 0) -> int:
     """Number of (iq, ik) tiles the kernels execute per live (batch, head)
     slice — the same block-granular predicate as the ``@pl.when`` skip.
-    S is the (possibly padded) grid extent; seq_len the true length."""
+    S is the (possibly padded) grid extent; seq_len the true length. The
+    short path's single whole-sequence tile always counts 1: it computes
+    the masked entries too."""
     seq_len = seq_len or S
     n_q, n_k = S // block_q, S // block_k
     return sum(
@@ -542,16 +820,17 @@ def gated_attention_flops(g_f, g_b, S: int, hd: int, *, causal: bool = True,
                           block_k: int = 128):
     """Executed MXU FLOPs (fwd, bwd) of the kernel path under concrete gates.
 
-    Uses the same tile geometry as ``ops.gated_attention`` (select_blocks,
-    including padding) and the same block-granular skip predicate: 2 matmuls
-    per live tile forward (qk^T, pv); 5 backward — the fused one-pass kernel
-    computes ``s`` and ``dp`` once per tile and emits dq/dk/dv together
-    (the former split dq / dkv kernels paid 3 + 4 = 7, recomputing both).
-    Each matmul is 2·bq·bk·hd FLOPs. Static HLO FLOP counts can't report
+    Uses the same tile geometry as ``ops.gated_attention``
+    (attention_geometry, including padding) and the same block-granular
+    skip predicate: 2 matmuls per live tile forward (qk^T, pv); 5 backward
+    — the fused one-pass kernel computes ``s`` and ``dp`` once per tile and
+    emits dq/dk/dv together (the former split dq / dkv kernels paid 3 + 4 =
+    7, recomputing both). Each matmul is 2·bq·bk·hd FLOPs; the short path's
+    tile is the whole padded sequence. Static HLO FLOP counts can't report
     this (interpret mode lowers the grid to a loop whose body XLA counts
     once), hence this mirror of the kernel's own skip logic.
     """
-    bq, bk, Sp = select_blocks(S, block_q, block_k)
+    bq, bk, Sp = attention_geometry(S, block_q, block_k)
     tiles = live_block_count(Sp, bq, bk, causal, window, seq_len=S)
     per_matmul = 2 * bq * bk * hd
     fwd = float(np.sum(np.asarray(g_f) != 0)) \
@@ -571,23 +850,35 @@ def gated_attention_dispatched_bytes(g_f, g_b, S: int, hd: int, *,
     one fwd / one bwd ``pallas_call`` under the given dispatch.
 
     Mirrors the kernels' grids and index maps: a block is (re)fetched only
-    when its index-map output changes between consecutive grid steps, so per
-    dispatched slice the forward streams q once per q-tile, k/v once per
-    (iq, ik) step and writes o/lse once; the fused backward keeps k/v
-    resident per kv sweep, streams q/do/lse/delta once per (ik, iq) step,
-    writes dk/dv once per kv tile and the VMEM-resident dq block exactly
-    once. The ``@pl.when`` gate/mask skip does NOT skip this traffic — only
-    compaction dispatch does: without ``live_fwd``/``live_bwd`` every one of
-    the B*H slices is streamed; with bounds, only the compacted grid's
-    slices are. Gate scalars and the jnp-level gather/scatter/pad copies are
-    not modelled (they are O(live) and fuse outside the kernels).
+    when its index-map output changes between consecutive grid steps. On
+    the short path every launched slice streams each of its blocks once:
+    q, k, v in and o, lse out forward; q, k, v, do, lse, delta in and dq,
+    dk, dv out backward. The launch is the dispatch count rounded up to
+    whole steps of ``slices_per_step`` slices (``launch_shape``). On the
+    flash path, per dispatched slice the forward streams q once per q-tile,
+    k/v once per (iq, ik) step and writes o/lse once; the fused backward
+    keeps k/v resident per kv sweep, streams q/do/lse/delta once per (ik,
+    iq) step, writes dk/dv once per kv tile and the VMEM-resident dq block
+    exactly once. The ``@pl.when`` gate/mask skip does NOT skip this
+    traffic — only compaction dispatch does: without
+    ``live_fwd``/``live_bwd`` every one of the B*H slices is streamed; with
+    bounds, only the compacted grid's slices are. Gate scalars and the
+    jnp-level gather/scatter/pad copies are not modelled (they are O(live)
+    and fuse outside the kernels).
     """
-    bq, bk, Sp = select_blocks(S, block_q, block_k)
-    n_q, n_k = Sp // bq, Sp // bk
+    bq, bk, Sp = attention_geometry(S, block_q, block_k)
     N = int(np.asarray(g_f).size)
     assert int(np.asarray(g_b).size) == N
     disp_f = _dispatch_count(live_fwd, N)
     disp_b = _dispatch_count(live_bwd, N)
+    if is_short(Sp, bq, bk):
+        disp_f = math.prod(launch_shape(
+            disp_f, slices_per_step(Sp, hd, itemsize, "fwd")))
+        disp_b = math.prod(launch_shape(
+            disp_b, slices_per_step(Sp, hd, itemsize, "bwd")))
+        return (disp_f * (4 * Sp * hd + Sp) * itemsize,
+                disp_b * (7 * Sp * hd + 2 * Sp) * itemsize)
+    n_q, n_k = Sp // bq, Sp // bk
     fwd_slice = (n_q * bq * hd                 # q: fetched once per q tile
                  + 2 * n_q * n_k * bk * hd    # k, v: refetched per (iq, ik)
                  + n_q * bq * hd              # o written once per q tile

@@ -110,10 +110,12 @@ def gated_attention(q, k, v, g_f, g_b=None, *, causal: bool = True,
     slices pay neither grid steps nor DMA. Bounds must be >= the actual
     live counts for the gates passed; None dispatches all B*H slices.
 
-    Sequence lengths that don't divide the tiles either shrink the tiles
-    (near-divisor case) or zero-pad S (select_blocks); padded rows/tiles
-    are masked via the kernels' seq_len bound and sliced off, and jnp.pad's
-    VJP routes the padding out of the gradients.
+    Sequences of at most 256 rows run as one whole tile per slice, with no
+    padding (the short path; the block sizes do not apply). Longer ones
+    that don't divide the tiles either shrink the tiles (near-divisor
+    case) or zero-pad S (select_blocks); padded rows/tiles are masked via
+    the kernels' seq_len bound and sliced off, and jnp.pad's VJP routes the
+    padding out of the gradients.
     """
     if g_b is None:
         g_b = g_f

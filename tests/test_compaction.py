@@ -64,11 +64,14 @@ def test_gb_le_gf_accepted_and_shapes_checked():
 
 
 # ------------------------------------------- compacted dispatch grids
-def test_compacted_grid_leading_dim_and_parity():
+@pytest.mark.parametrize("S", [192, 384], ids=["short", "flash"])
+def test_compacted_grid_leading_dim_and_parity(S):
     """With live bounds, the pallas_call grids lead with the bound instead
-    of B*H, executed backward blocks stay exactly the live share, and
-    outputs/gradients equal the uncompacted dispatch."""
-    B, H, S, hd = 2, 10, 192, 16
+    of B*H — on the short path (S <= 256) with the bound over the slices
+    a grid step takes, rounded up — executed backward blocks stay exactly
+    the live share, and outputs/gradients equal the uncompacted
+    dispatch."""
+    B, H, hd = 2, 10, 16
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q, k, v, do = (jax.random.normal(kk, (B, H, S, hd)) for kk in ks)
     g_f, g_b, live_f, live_b = _mix_40po_20ps(B, H, np.random.default_rng(3))
@@ -97,11 +100,20 @@ def test_compacted_grid_leading_dim_and_parity():
 
         blocks["n"] = 0
         out_u, grads_u = run(live=False)
-        assert grids["fwd"][0] == B * H and grids["bwd"][0] == B * H
 
         # grid leading dims shrink to the live bounds (not B*H)
-        assert fwd_grid[0] == live_f and fwd_grid[1:] == grids["fwd"][1:]
-        assert bwd_grid[0] == live_b and bwd_grid[1:] == grids["bwd"][1:]
+        if S <= d2a.SHORT_SEQ_ROWS:
+            spb_f = d2a.slices_per_step(S, hd, 4, "fwd")
+            spb_b = d2a.slices_per_step(S, hd, 4, "bwd")
+            assert (spb_f, spb_b) == (8, 4)
+            assert grids["fwd"] == (-(-B * H // spb_f),)
+            assert grids["bwd"] == (-(-B * H // spb_b),)
+            assert fwd_grid == (-(-live_f // spb_f),)
+            assert bwd_grid == (-(-live_b // spb_b),)
+        else:
+            assert grids["fwd"][0] == B * H and grids["bwd"][0] == B * H
+            assert fwd_grid[0] == live_f and fwd_grid[1:] == grids["fwd"][1:]
+            assert bwd_grid[0] == live_b and bwd_grid[1:] == grids["bwd"][1:]
         # executed backward tiles: identical live share either way
         assert compacted_blocks == blocks["n"] > 0
         # numerics identical
@@ -118,8 +130,8 @@ def test_compacted_grid_leading_dim_and_parity():
 def test_dispatched_bytes_shrink_proportionally():
     """The DMA model: dispatched bytes scale with the compacted slice
     count, not with B*H — the 40% p_o + 20% p_s mix streams 40% of the
-    backward bytes and 80% of the forward bytes."""
-    B, H, S, hd = 2, 10, 256, 64
+    backward bytes and 80% of the forward bytes (flash path)."""
+    B, H, S, hd = 2, 10, 384, 64
     g_f, g_b, live_f, live_b = _mix_40po_20ps(B, H, np.random.default_rng(0))
     full_f, full_b = d2a.gated_attention_dispatched_bytes(g_f, g_b, S, hd)
     comp_f, comp_b = d2a.gated_attention_dispatched_bytes(
@@ -131,6 +143,23 @@ def test_dispatched_bytes_shrink_proportionally():
     ones_f, ones_b = d2a.gated_attention_dispatched_bytes(
         jnp.ones((B, H)), jnp.ones((B, H)), S, hd)
     assert (ones_f, ones_b) == (full_f, full_b)
+
+
+def test_short_dispatched_bytes_count_launched_slices():
+    """On the short path every launched slice streams each block once, and
+    a launch is the dispatch count rounded up to whole grid steps (8
+    forward, 4 backward slices at S=197, hd=64): 16 live forward slices
+    launch 16, all 20 launch 3 steps of 7; 8 and 20 backward slices launch
+    exactly."""
+    B, H, S, hd = 2, 10, 197, 64
+    g_f, g_b, live_f, live_b = _mix_40po_20ps(B, H, np.random.default_rng(0))
+    full_f, full_b = d2a.gated_attention_dispatched_bytes(g_f, g_b, S, hd)
+    comp_f, comp_b = d2a.gated_attention_dispatched_bytes(
+        g_f, g_b, S, hd, live_fwd=live_f, live_bwd=live_b)
+    assert full_f == 21 * (4 * S * hd + S) * 4
+    assert full_b == 20 * (7 * S * hd + 2 * S) * 4
+    assert comp_f / full_f == 16 / 21
+    assert comp_b / full_b == live_b / (B * H) == 0.4
 
 
 # --------------------------------- sliding window + padded seq backward

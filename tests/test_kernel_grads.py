@@ -25,7 +25,7 @@ def _random_mix(rng, B, H):
 
 
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0)])
-@pytest.mark.parametrize("S,hd", [(128, 64), (256, 32)])
+@pytest.mark.parametrize("S,hd", [(128, 64), (256, 32), (384, 32)])
 def test_grad_parity_vs_reference_vjp(causal, window, S, hd):
     B, H = 2, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -94,7 +94,7 @@ def test_gb_zero_slices_do_no_backward_matmul_work():
     old split dq/dkv pair fired twice), all-p_o/p_s executes none, and a
     mix executes exactly the p_f share.
     """
-    B, H, S, hd = 1, 4, 256, 32
+    B, H, S, hd = 1, 4, 384, 32        # a flash-path sequence: 3x3 tiles
     bq = bk = 128
     q = jax.random.normal(jax.random.PRNGKey(3), (B, H, S, hd))
     count = {"n": 0}
@@ -110,7 +110,7 @@ def test_gb_zero_slices_do_no_backward_matmul_work():
             jax.effects_barrier()       # debug callbacks are async
             return count["n"]
 
-        # causal live tiles per (b, h): 3 of 4; ONE fused backward kernel
+        # causal live tiles per (b, h): 6 of 9; ONE fused backward kernel
         per_head = d2a.live_block_count(S, bq, bk, True, 0)
         assert run(np.ones((B, H), np.float32)) == B * H * per_head
         assert run(np.zeros((B, H), np.float32)) == 0
@@ -147,6 +147,81 @@ def test_awkward_seq_len_pads_and_matches():
     for name, a, b in zip(("dq", "dk", "dv"), vjp_k(do), vjp_r(do)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=TOL,
                                    rtol=TOL, err_msg=name)
+
+
+@pytest.mark.parametrize("bounds", [False, True], ids=["all", "bounds"])
+@pytest.mark.parametrize("causal,window", [(False, 0), (True, 0), (True, 3)],
+                         ids=["full", "causal", "window"])
+@pytest.mark.parametrize("S", [5, 197, 200, 256])
+def test_short_path_parity_vs_reference(S, causal, window, bounds):
+    """Sequences of at most 256 rows run one whole tile per slice, several
+    slices per grid step: forward and dq/dk/dv match the reference VJP,
+    g_b == 0 slices get exact zero gradients, and the backward executes
+    exactly one block per live g_b slice. The bounds are one above the
+    live counts, so the compacted launch carries dead slices, and 13
+    slices are no multiple of a step's slices."""
+    B, H, hd = 1, 13, 32
+    ks = jax.random.split(jax.random.PRNGKey(S), 4)
+    q, k, v, do = (jax.random.normal(kk, (B, H, S, hd)) for kk in ks)
+    ops_, g_f, g_b = _random_mix(np.random.default_rng(S + window), B, H)
+    n_f, n_b = int((ops_ != 2).sum()), int((ops_ == 0).sum())
+    assert 0 < n_b < n_f < B * H
+    lf, lb = (n_f + 1, n_b + 1) if bounds else (None, None)
+
+    grids = {}
+    count = {"n": 0}
+    d2a.on_dispatch = lambda kind, grid: grids.__setitem__(kind, grid)
+    d2a.on_backward_block = lambda: count.__setitem__("n", count["n"] + 1)
+    jax.clear_caches()                   # hooks are read at trace time
+    try:
+        out_k, vjp_k = jax.vjp(
+            lambda q, k, v: gated_attention(
+                q, k, v, g_f, g_b, causal=causal, window=window,
+                interpret=True, live_fwd=lf, live_bwd=lb), q, k, v)
+        grads_k = vjp_k(do)
+        jax.effects_barrier()
+    finally:
+        d2a.on_dispatch = None
+        d2a.on_backward_block = None
+
+    assert len(grids["fwd"]) == len(grids["bwd"]) == 1   # the short grids
+    assert count["n"] == n_b
+
+    @jax.jit
+    def ref(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: gated_attention_ref(
+            q, k, v, g_f, g_b, causal=causal, window=window), q, k, v)
+        return out, vjp(do)
+
+    out_r, grads_r = ref(q, k, v, do)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                               atol=TOL, rtol=TOL)
+    gb = np.asarray(g_b)
+    for name, a, b in zip(("dq", "dk", "dv"), grads_k, grads_r):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=TOL,
+                                   rtol=TOL, err_msg=name)
+        assert np.all(np.asarray(a)[gb == 0] == 0.0), name
+
+
+def test_attention_geometry():
+    """The short path takes every sequence of at most 256 rows (rounded up
+    to the sublane) as one unpadded tile; longer ones keep select_blocks'
+    flash tiles. At ViT-S/16's shapes a grid step takes 8 forward and 4
+    backward slices, and a launch rounds up to whole steps."""
+    geo = d2a.attention_geometry
+    assert geo(197, 128, 128) == (197, 197, 197)
+    assert geo(5, 128, 128) == (5, 5, 5)
+    assert geo(256, 128, 128) == (256, 256, 256)
+    assert geo(257, 128, 128) == d2a.select_blocks(257, 128, 128) \
+        == (128, 128, 384)
+    assert d2a.is_short(256, 256, 256)
+    assert not d2a.is_short(512, 512, 512)
+    assert not d2a.is_short(256, 128, 128)
+    assert d2a.slices_per_step(197, 64, 4, "fwd") == 8
+    assert d2a.slices_per_step(197, 64, 4, "bwd") == 4
+    assert d2a.launch_shape(960, 8) == (120, 8)
+    assert d2a.launch_shape(720, 4) == (180, 4)
+    assert d2a.launch_shape(13, 8) == (2, 7)      # one dead slice, not three
 
 
 def test_select_blocks_geometry():

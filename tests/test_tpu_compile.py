@@ -60,23 +60,34 @@ def _grad(fn, n_diff: int):
     return g
 
 
-# (B, H, S, hd, causal): ViT-S/16 (6 heads of 64, 197 tokens — select_blocks
-# pads to 256) and stablelm-3b (32 heads of 80, causal, S=512)
-ATTN_SHAPES = {"vit_s16": (8, 6, 197, 64, False),
-               "stablelm_3b": (2, 32, 512, 80, True)}
+# (B, H, S, hd, causal, live_fwd, live_bwd, path): ViT-S/16 (6 heads of 64,
+# 197 tokens — one unpadded 197-row tile per slice, the short path), the
+# benchmark cell's exact dispatch (batch 200, 3 p_f + 1 p_o + 1 p_s of 5
+# micro-batches: 960 and 720 live slices of 1200), and stablelm-3b (32
+# heads of 80, causal, S=512 — 128x128 tiles, the flash path)
+ATTN_SHAPES = {"vit_s16": (8, 6, 197, 64, False, None, None, "short"),
+               "vit_s16_cell": (200, 6, 197, 64, False, 960, 720, "short"),
+               "stablelm_3b": (2, 32, 512, 80, True, None, None, "flash")}
 
 
 @pytest.mark.parametrize("name", sorted(ATTN_SHAPES))
 @pytest.mark.parametrize("pass_", ["fwd", "grad"])
 def test_gated_attention_compiles(one_chip, name, pass_):
-    B, H, S, hd, causal = ATTN_SHAPES[name]
+    B, H, S, hd, causal, live_fwd, live_bwd, path = ATTN_SHAPES[name]
 
     def attn(q, k, v, gf, gb):
         return ops.gated_attention(q, k, v, gf, gb, causal=causal,
-                                   interpret=False)
+                                   interpret=False, live_fwd=live_fwd,
+                                   live_bwd=live_bwd)
 
     fn = attn if pass_ == "fwd" else _grad(attn, 3)
-    _compile(fn, *_shapes(one_chip, *[(B, H, S, hd)] * 3, (B, H), (B, H)))
+    hlo = _compile(fn, *_shapes(one_chip, *[(B, H, S, hd)] * 3, (B, H),
+                                (B, H)))
+    # the kernels' pallas_call names say which path lowered
+    other = {"short": "flash", "flash": "short"}[path]
+    for kind in ("fwd",) if pass_ == "fwd" else ("fwd", "bwd"):
+        assert f"d2ft_attn_{kind}_{path}" in hlo
+        assert f"d2ft_attn_{kind}_{other}" not in hlo
 
 
 @pytest.mark.parametrize("pass_", ["fwd", "grad"])
