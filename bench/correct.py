@@ -36,7 +36,9 @@ the best. A subnet (layer, group) is the group's slice of each width-
 partitionable weight of the layer (the output columns of the query, key,
 value and MLP input projections, the input rows of the attention output
 and MLP output projections) and the whole of every other weight of the
-layer.
+layer; a reference module that defines ``group_sums(block, G, power)``
+splits its own layers instead (a fused weight whose columns belong to
+different groups, or to none).
 """
 from __future__ import annotations
 
@@ -196,28 +198,35 @@ COLUMNS = ("wq", "wk", "wv", "w_up", "w_gate")   # sliced by output column
 ROWS = ("wo", "w_down")                           # sliced by input row
 
 
-def _subnet_sums(G: int, power: int):
-    """[L, G]: per layer and group, the sum of |x| ** power over the
-    group's subnet (module docstring)."""
+def group_sums(block, G: int, power: int):
+    """[G]: the sum of |x| ** power over each group's subnet of one layer's
+    weights ``block`` (module docstring)."""
     import jax
     import jax.numpy as jnp
+    tot = jnp.zeros((G,), jnp.float32)
+    for path, x in jax.tree_util.tree_flatten_with_path(block)[0]:
+        name = path[-1].key
+        v = jnp.abs(x.astype(jnp.float32)) ** power
+        if name in COLUMNS and x.shape[-1] % G == 0:
+            tot = tot + v.reshape(-1, G, x.shape[-1] // G).sum((0, 2))
+        elif name in ROWS and x.shape[0] % G == 0:
+            tot = tot + v.reshape(G, -1).sum(1)
+        else:
+            tot = tot + jnp.sum(v)
+    return tot
+
+
+def _subnet_sums(ref, G: int, power: int):
+    """[L, G]: per layer and group, the sum of |x| ** power over the
+    group's subnet, by the reference's own ``group_sums`` where it has
+    one."""
+    import jax
+    import jax.numpy as jnp
+    split = getattr(ref, "group_sums", group_sums)
 
     @jax.jit
     def sums(blocks):
-        out = []
-        for blk in blocks:
-            tot = jnp.zeros((G,), jnp.float32)
-            for path, x in jax.tree_util.tree_flatten_with_path(blk)[0]:
-                name = path[-1].key
-                v = jnp.abs(x.astype(jnp.float32)) ** power
-                if name in COLUMNS and x.shape[-1] % G == 0:
-                    tot = tot + v.reshape(-1, G, x.shape[-1] // G).sum((0, 2))
-                elif name in ROWS and x.shape[0] % G == 0:
-                    tot = tot + v.reshape(G, -1).sum(1)
-                else:
-                    tot = tot + jnp.sum(v)
-            out.append(tot)
-        return jnp.stack(out)
+        return jnp.stack([split(blk, G, power) for blk in blocks])
     return sums
 
 
@@ -226,8 +235,9 @@ def subnet_scores(ref, vg, params, batch, gates, G: int, M: int) -> tuple:
     and its Fisher information (summed squared gradient of the mean loss)
     on each contiguous micro-batch of ``batch``, every gate open."""
     import jax.numpy as jnp
-    back = np.asarray(_subnet_sums(G, 1)(ref.blocks(params)), np.float64)
-    fisher = _subnet_sums(G, 2)
+    back = np.asarray(_subnet_sums(ref, G, 1)(ref.blocks(params)),
+                      np.float64)
+    fisher = _subnet_sums(ref, G, 2)
     B = ref.rows(batch)
     b = B // M
     ones = jnp.ones((gates[0].shape[0], b, gates[0].shape[2]), jnp.float32)

@@ -4,7 +4,11 @@ A cell of ``BENCHMARK.json`` names a configuration (a JSON file of sizes
 with a plain reference beside it, ``<name>.reference.py``) and a traffic
 mix; the mix's parameters are the data file ``workloads/<cell>.json``.
 Per-layer metrics are readers in ``metrics/<metric>.py``. All three are
-found by name, so a cell or a metric is added by adding files.
+found by name, so a cell or a metric is added by adding files. The
+configuration's published keys are what the reference and the work
+counts (``flops.py``) read; its ``program`` object holds the keyword
+arguments of the program's own model configuration, which the harness
+builds without knowing any of them (``program_config``).
 
 A run:
 
@@ -26,6 +30,8 @@ A run:
 """
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import importlib.util
 import json
 import os
@@ -33,6 +39,7 @@ import shutil
 import sys
 import tempfile
 import time
+import typing
 from dataclasses import dataclass, field
 from pathlib import Path
 
@@ -69,6 +76,7 @@ class Layout:
             if c["name"] == name:
                 path = Path(self.root) / c["file"]
                 cfg = json.loads(path.read_text())
+                check_config(cfg, path)
                 ref = path.with_name(path.name[:-len(".json")]
                                      + ".reference.py")
                 return cfg, _load_module(ref, f"bench_ref_{name}")
@@ -86,6 +94,53 @@ class Layout:
         """The ``end_to_end`` or ``per_layer`` entries this cell reports."""
         return [m for m in self.spec()[kind]
                 if "workloads" not in m or cell in m["workloads"]]
+
+
+# each size or setting of the program's configuration (``group.field`` in
+# a sub-object) and the published key that states it; the two must agree
+PUBLISHED_KEY = {
+    "n_layers": "num_hidden_layers", "d_model": "hidden_size",
+    "n_heads": "num_attention_heads", "n_kv_heads": "num_key_value_heads",
+    "resolved_head_dim": "head_dim", "d_ff": "intermediate_size",
+    "vocab_size": "vocab_size", "mlp_act": "hidden_act",
+    "patch": "patch_size", "image_size": "image_size",
+    "n_classes": "num_labels",
+    "ssm.state_dim": "mamba_d_state", "ssm.head_dim": "mamba_d_head",
+    "ssm.expand": "mamba_expand", "ssm.conv_width": "mamba_d_conv",
+    "ssm.chunk": "mamba_chunk_size"}
+
+
+def check_config(c: dict, path) -> None:
+    """Refuse, naming the key or kind, a configuration whose ``program``
+    object the program's configuration class cannot take or states a size
+    other than the published key's (``PUBLISHED_KEY``), or whose
+    ``layer_types`` hold a kind that ``flops.py`` cannot count."""
+    from bench import flops
+    try:
+        built = program_config(c)
+        for attr, key in PUBLISHED_KEY.items():
+            have, want = _attr(built, attr), published(c, key)
+            if None not in (have, want) and have != want:
+                raise ValueError(f"program {attr} is {have!r} where the "
+                                 f"published {key} is {want!r}")
+        flops.layer_kinds(c)
+    except (ValueError, TypeError) as e:
+        raise ValueError(f"{path}: {e}") from None
+
+
+def published(c: dict, key: str):
+    """A published key's value; a head's size defaults to the width over
+    the heads, as in Hugging Face's configurations."""
+    if key == "head_dim":
+        return c.get("head_dim") or c["hidden_size"] \
+            // c["num_attention_heads"]
+    return c.get(key)
+
+
+def _attr(obj, path: str):
+    for name in path.split("."):
+        obj = getattr(obj, name, None)
+    return obj
 
 
 def _find(dirs, filename: str) -> Path:
@@ -252,24 +307,46 @@ def d2ft_config(t: dict):
                       n_po=t["n_po"])
 
 
-def vit_config(c: dict):
-    from repro.models.vit import ViTConfig
-    return ViTConfig(n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-                     n_heads=c["num_attention_heads"],
-                     d_ff=c["intermediate_size"], patch=c["patch_size"],
-                     image_size=c["image_size"], n_classes=c["num_labels"])
+# the program's model configuration class of each family
+PROGRAM_CLASS = {"lm": ("repro.configs.base", "ModelConfig"),
+                 "vit": ("repro.models.vit", "ViTConfig")}
 
 
-def lm_config(c: dict):
-    from repro.configs.base import ATTN_GLOBAL, ModelConfig
-    return ModelConfig(
-        name=c["name"], arch_type="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        block_pattern=(ATTN_GLOBAL,), mlp_act=c["hidden_act"],
-        mlp_gated=True, norm="layer", rope_theta=float(c["rope_theta"]),
-        tie_embeddings=c["tie_word_embeddings"])
+def program_config(c: dict):
+    """The program's model configuration, built from the configuration
+    file's ``program`` object (``build_dataclass``)."""
+    module, name = PROGRAM_CLASS[c["family"]]
+    return build_dataclass(getattr(importlib.import_module(module), name),
+                           c["program"])
+
+
+def build_dataclass(cls, kw: dict):
+    """``cls(**kw)``, where lists become tuples and a field whose type is a
+    dataclass is built from its sub-object; a key that ``cls`` has no field
+    for raises, naming the key."""
+    hints = typing.get_type_hints(cls)
+    unknown = sorted(set(kw) - {f.name for f in dataclasses.fields(cls)})
+    if unknown:
+        raise ValueError(f"{cls.__name__} has no field {unknown[0]!r} "
+                         f"(program keys {unknown})")
+    out = {}
+    for k, v in kw.items():
+        sub = _dataclass_of(hints[k])
+        out[k] = build_dataclass(sub, v) if sub and isinstance(v, dict) \
+            else _tuples(v)
+    return cls(**out)
+
+
+def _dataclass_of(tp):
+    """The dataclass a field's type names (``X`` or ``Optional[X]``)."""
+    for t in (tp, *typing.get_args(tp)):
+        if dataclasses.is_dataclass(t):
+            return t
+    return None
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
 
 
 def drive_vit(c, t, held, feed, log):
@@ -279,7 +356,7 @@ def drive_vit(c, t, held, feed, log):
     from repro.models.vit import vit_loss
     from repro.train.loop import finetune_vit
 
-    vcfg, d2 = vit_config(c), d2ft_config(t)
+    vcfg, d2 = program_config(c), d2ft_config(t)
     M = t["n_microbatches"]
 
     def loss_fn(p, mb):
@@ -301,7 +378,7 @@ def drive_vit(c, t, held, feed, log):
 
 def drive_lm(c, t, held, feed, log):
     from repro.train.loop import finetune
-    return finetune(held.pop(), lm_config(c), d2ft_config(t),
+    return finetune(held.pop(), program_config(c), d2ft_config(t),
                     make_optimizer(c["optimizer"]), feed, steps=BIG,
                     use_kernel=True, log=log)
 
@@ -313,7 +390,8 @@ def drive_lm_distributed(c, t, held, feed, log):
     n = t["data_parallel"]
     pc = ParallelConfig(mesh=MeshSpec(data=n), sync_mode=t["sync_mode"],
                         use_kernel=True)
-    return finetune_distributed(held.pop(), lm_config(c), d2ft_config(t),
+    return finetune_distributed(held.pop(), program_config(c),
+                                d2ft_config(t),
                                 make_optimizer(c["optimizer"]), feed,
                                 steps=BIG, mesh=make_data_mesh(n),
                                 parallel=pc, log=log)
@@ -390,9 +468,15 @@ class Run:
 
 
 def model_dims(c: dict, t: dict) -> dict:
-    """The shapes ``flops.py`` counts from."""
+    """The shapes ``flops.py`` counts from, all read from published
+    keys."""
+    from bench import flops
+    kinds = flops.layer_kinds(c)
     m = {"d_model": c["hidden_size"], "n_heads": c["num_attention_heads"],
-         "head_dim": c["head_dim"], "d_ff": c["intermediate_size"]}
+         "head_dim": published(c, "head_dim"),
+         "d_ff": c["intermediate_size"], "layer_kinds": kinds}
+    if flops.MAMBA in kinds:
+        m.update(flops.mamba_dims(c))
     if c["family"] == "vit":
         n = (c["image_size"] // c["patch_size"]) ** 2
         return dict(m, family="vit", n_kv_heads=m["n_heads"],
@@ -496,22 +580,42 @@ def metric_context(run: Run) -> dict:
     mb_of = microbatch_of(B, M)
     m = model_dims(c, t)
     attn_flops, attn_bytes = flops.required_attention(m, table, mb_of)
+    ssd_flops, ssd_bytes = flops.required_ssd(m, table, mb_of)
     traced = f.n_traced is not None
+    steps = f.n_traced if traced else f.n_window
     return {
         "n_chips": run.n_chips,
         "peaks": peaks(jax.devices()[0].device_kind),
+        "config": c,
+        "traffic": t,
+        "model": m,
         # numbers from the trace cover its span at the start of the window
-        "steps": f.n_traced if traced else f.n_window,
+        "steps": steps,
         # host-clock numbers come from the untraced rest of the window: the
         # profiler slows the host work between steps
         "untraced_s": run.t_close - (f.t_resume if traced else f.t_open),
         "untraced_steps": f.n_window - (f.n_traced if traced else 0),
         "warmup_s": f.t_open - f.requests[0],
         "step_flops": flops.required_step_flops(m, table, mb_of),
+        "kind_flops": flops.kind_flops(m, table, mb_of),
         "attn_flops": attn_flops,
         "attn_bytes": attn_bytes,
+        "ssd_flops": ssd_flops,
+        "ssd_bytes": ssd_bytes,
+        "kernel_ms": kernel_ms(run.trace, steps),
         "trace": run.trace,
     }
+
+
+def kernel_ms(trace: dict | None, steps: int) -> dict:
+    """{kernel name: device ms per step, averaged over the chips}; empty
+    without a trace."""
+    if trace is None:
+        return {}
+    devs = trace["devices"].values()
+    names = {n for d in devs for n in d["kernels"]}
+    return {n: sum(d["kernels"].get(n, 0.0) for d in devs) / len(devs)
+            / steps * 1e3 for n in sorted(names)}
 
 
 def per_layer(layout: Layout, run: Run) -> dict:
@@ -548,6 +652,12 @@ def run_cell(layout: Layout, cell_name: str, seed: int, seconds: float,
     print(f"window: {f.n_window} steps in {run.t_close - f.t_open:.3f} s, "
           f"compilations in the window: {run.window_compiles}",
           file=sys.stderr, flush=True)
+    gaps = np.diff(f.requests[f.i_open:]) * 1e3
+    slow = np.flatnonzero(gaps > 1.05 * np.median(gaps))
+    print(f"steps: median {np.median(gaps):.2f} ms, max {gaps.max():.2f} ms; "
+          f"over 1.05 x the median: "
+          + (", ".join(f"step {i} {gaps[i]:.1f} ms" for i in slow[:10])
+             or "none"), file=sys.stderr, flush=True)
     print(f"memory: runtime peak {run.runtime_peak_bytes} bytes, compiled "
           f"step {run.step_bytes} bytes", file=sys.stderr, flush=True)
     if run.window_compiles:

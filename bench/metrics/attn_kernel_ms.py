@@ -1,14 +1,9 @@
-"""Device milliseconds per step of the Pallas kernels (the gated attention
-kernels, the only Pallas kernels these configurations run), averaged over
-the chips; nothing where the trace holds no kernel."""
+"""Device milliseconds per step of the gated attention kernels
+(``kernels/d2ft_attention.py``, named ``d2ft_attn_{fwd,bwd}_{short,flash}``),
+averaged over the chips; nothing where the trace holds none."""
+PREFIX = "d2ft_attn_"
 
 
 def read(ctx):
-    tr = ctx["trace"]
-    if tr is None:
-        return None
-    devs = tr["devices"].values()
-    per_chip = sum(d["kernel_s"] for d in devs) / len(devs)
-    if per_chip <= 0:
-        return None
-    return per_chip / ctx["steps"] * 1e3
+    ms = sum(v for n, v in ctx["kernel_ms"].items() if n.startswith(PREFIX))
+    return ms if ms > 0 else None

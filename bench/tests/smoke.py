@@ -14,14 +14,20 @@ from bench import harness
 
 SMOKE_CONFIG = {
     "vit-s16": dict(hidden_size=96, num_hidden_layers=2, head_dim=16,
-                    intermediate_size=192, patch_size=8, image_size=32),
+                    intermediate_size=192, patch_size=8, image_size=32,
+                    program=dict(d_model=96, n_layers=2, d_ff=192, patch=8,
+                                 image_size=32)),
     "stablelm-3b-l4": dict(hidden_size=128, num_hidden_layers=2,
                            num_attention_heads=4, num_key_value_heads=4,
                            head_dim=32, intermediate_size=256,
-                           vocab_size=512),
+                           vocab_size=512,
+                           program=dict(d_model=128, n_layers=2, n_heads=4,
+                                        n_kv_heads=4, head_dim=32, d_ff=256,
+                                        vocab_size=512)),
 }
 SMOKE_TRAFFIC = {
     "vit-s16.d2ft-paper": dict(batch=10, ref_rows=5, pool=4),
+    "vit-s16.full-ft": dict(batch=10, ref_rows=5, pool=4),
 }
 # Cells of the issue not yet in BENCHMARK.json (PERF.md, Open questions):
 # rehearsed here, the four-chip one on four virtual CPU devices, so that
@@ -55,6 +61,14 @@ SMOKE_ONLY = [
 ]
 
 
+def smoke_config(real: dict) -> dict:
+    """A configuration at its smoke sizes: published keys and the program
+    object shrunk alike."""
+    small = dict(SMOKE_CONFIG[real["name"]])
+    program = dict(real["program"], **small.pop("program"))
+    return dict(real, **small, program=program)
+
+
 def smoke_layout(tmp: Path) -> harness.Layout:
     """Write the smoke copy under ``tmp``; return a Layout reading it."""
     real = harness.Layout()
@@ -64,7 +78,7 @@ def smoke_layout(tmp: Path) -> harness.Layout:
     spec["configs"] += SMOKE_ONLY_CONFIGS
     for c in spec["configs"]:
         src = harness.REPO / c["file"]
-        cfg = dict(json.loads(src.read_text()), **SMOKE_CONFIG[c["name"]])
+        cfg = smoke_config(json.loads(src.read_text()))
         (tmp / "configs" / src.name).write_text(json.dumps(cfg))
         ref = src.with_name(src.name[:-len(".json")] + ".reference.py")
         shutil.copy(ref, tmp / "configs" / ref.name)

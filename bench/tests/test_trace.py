@@ -65,3 +65,55 @@ def test_recorded_trace():
     gaps = dict(out["breakdown"]["idle_gaps"])
     assert sum(gaps.values()) == pytest.approx(
         out["window_s"] - dev["busy_s"], rel=1e-6)
+
+
+def test_recorded_trace_by_kernel():
+    """Kernel time by kernel name; the fixture's kernels predate their
+    names, so they go under the instruction names JAX gave the calls."""
+    from pathlib import Path
+    out = trace.reduce_trace(str(Path(__file__).parent / "fixtures"
+                                 / "attention.xplane.pb"))
+    (dev,) = out["devices"].values()
+    assert dev["kernels"] == pytest.approx({
+        "jvp_jit__gated_attention_impl__": 43595e-9,
+        "transpose_jvp_jit__gated_attention_impl___": 45606e-9}, rel=1e-9)
+    assert sum(dev["kernels"].values()) == pytest.approx(dev["kernel_s"],
+                                                         rel=1e-12)
+
+
+# the fixture's attention kernels predate the kernels' own names: the
+# names they carry now
+NAMED = {"jvp_jit__gated_attention_impl__": "d2ft_attn_fwd_flash",
+         "transpose_jvp_jit__gated_attention_impl___": "d2ft_attn_bwd_flash"}
+
+
+def test_attention_readers_on_the_recorded_trace():
+    """The attention readers read what they read from ``kernel_s`` before
+    kernels had names: the fixture holds only attention kernels, here under
+    the names they carry now."""
+    from pathlib import Path
+    from bench import harness
+    tr = trace.reduce_trace(str(Path(__file__).parent / "fixtures"
+                                / "attention.xplane.pb"))
+    steps, layout = 3, harness.Layout()
+    kernel_ms = {NAMED[n]: v for n, v in harness.kernel_ms(tr, steps).items()}
+    assert len(kernel_ms) == 2
+    ctx = {"trace": tr, "steps": steps, "n_chips": 1,
+           "kernel_ms": kernel_ms,
+           "peaks": {"flops": 197e12, "hbm_bw": 819e9},
+           "attn_flops": 4.0e8, "attn_bytes": 2.0e7}
+    kernel_s = tr["devices"][0]["kernel_s"] / steps        # before
+    assert layout.metric_reader("attn_kernel_ms")(ctx) == \
+        pytest.approx(kernel_s * 1e3, rel=1e-12)
+    least = max(4.0e8 / 197e12, 2.0e7 / 819e9)
+    assert layout.metric_reader("attn_roofline")(ctx) == \
+        pytest.approx(100.0 * least / kernel_s, rel=1e-12)
+
+
+def test_kernel_names():
+    named = ('%d2ft_attn_bwd_short.3 = (f32[1200,197,64]) custom-call('
+             's32[180] %c), custom_call_target="tpu_custom_call"')
+    assert trace.instruction(named) == "d2ft_attn_bwd_short"
+    assert trace.instruction("%transpose_jvp_jit__gated_attention_impl___.2"
+                             " = f32[8] custom-call()") == \
+        "transpose_jvp_jit__gated_attention_impl___"

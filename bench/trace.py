@@ -8,7 +8,11 @@ benchmark wraps its measured window in the span ``WINDOW_SPAN``; every
 number here is taken inside that span.
 
 * busy: the union of the op intervals of a device;
-* kernel time: the summed durations of the Pallas kernels (custom calls);
+* kernel time: the summed durations of the Pallas kernels (custom calls),
+  in all and by kernel name: the custom call's instruction name, which XLA
+  takes from the kernel's own ``name`` (``d2ft_attn_bwd_short``); a kernel
+  without one goes under the name JAX gave the call
+  (``jvp_jit__gated_attention_impl__``);
 * collective time: the summed durations of all-gather, reduce-scatter,
   all-reduce and collective-permute ops; the exposed part is what of
   their intervals no other op covers on that device;
@@ -63,12 +67,16 @@ def is_kernel(name: str, stats: dict) -> bool:
     return KERNEL_TARGET in name
 
 
+def instruction(name: str) -> str:
+    """An op's instruction name without the ``%`` and the numeric suffix,
+    so that the copies of one op in every layer add up (the trace names an
+    op by its whole HLO text)."""
+    return re.sub(r"(\.\d+)+$", "", name.split(" = ", 1)[0].lstrip("%"))
+
+
 def op_kind(name: str, stats: dict) -> str:
-    """A short name for an op: its HLO category and its instruction name
-    without the ``%`` and the numeric suffix, so that the copies of one op
-    in every layer add up (the trace names an op by its whole HLO text)."""
-    inst = name.split(" = ", 1)[0].lstrip("%")
-    inst = re.sub(r"(\.\d+)+$", "", inst)
+    """A short name for an op: its HLO category and its instruction name."""
+    inst = instruction(name)
     cat = stats.get("hlo_category")
     return f"{cat}: {inst}" if cat else inst
 
@@ -179,7 +187,8 @@ def gap_owners(spans, mids):
 def reduce_trace(path: str, top: int = 10) -> dict:
     """Numbers of the traced window; times in seconds.
 
-    Returns window_s, and per device busy_s, kernel_s, collective_s and
+    Returns window_s, and per device busy_s, kernel_s, ``kernels``
+    ({kernel name: seconds}, which sum to kernel_s), collective_s and
     exposed_collective_s, and the ``breakdown`` lists (``device_ops``: the
     kinds of op (``op_kind``) that took most device time, summed over
     devices and divided by their number; ``idle_gaps``: idle time of
@@ -197,7 +206,11 @@ def reduce_trace(path: str, top: int = 10) -> dict:
         evs = [(n, max(s, lo), min(e, hi), st) for n, s, e, st in evs
                if min(e, hi) > max(s, lo)]
         busy = union([(s, e) for _, s, e, _ in evs])
-        kern = [(s, e) for n, s, e, st in evs if is_kernel(n, st)]
+        kern = [(instruction(n), s, e) for n, s, e, st in evs
+                if is_kernel(n, st)]
+        by_kernel = defaultdict(float)
+        for n, s, e in kern:
+            by_kernel[n] += (e - s) * 1e-9
         coll = [(s, e) for n, s, e, st in evs if is_collective(n, st)]
         other = union([(s, e) for n, s, e, st in evs
                        if not is_collective(n, st)])
@@ -205,7 +218,8 @@ def reduce_trace(path: str, top: int = 10) -> dict:
         for n, s, e, st in evs:
             op_time[op_kind(n, st)] += (e - s) / len(ops)
         per_dev[dev] = {"busy_s": total(busy) * 1e-9,
-                        "kernel_s": total(kern) * 1e-9,
+                        "kernel_s": total((s, e) for _, s, e in kern) * 1e-9,
+                        "kernels": dict(by_kernel),
                         "collective_s": total(coll) * 1e-9,
                         "exposed_collective_s": total(exposed) * 1e-9,
                         "busy": busy}
