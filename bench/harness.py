@@ -97,7 +97,8 @@ class Layout:
 
 
 # each size or setting of the program's configuration (``group.field`` in
-# a sub-object) and the published key that states it; the two must agree
+# a sub-object) and the published key that states it; the two must agree.
+# A configuration file adds ties of its own under ``ties``.
 PUBLISHED_KEY = {
     "n_layers": "num_hidden_layers", "d_model": "hidden_size",
     "n_heads": "num_attention_heads", "n_kv_heads": "num_key_value_heads",
@@ -113,19 +114,40 @@ PUBLISHED_KEY = {
 def check_config(c: dict, path) -> None:
     """Refuse, naming the key or kind, a configuration whose ``program``
     object the program's configuration class cannot take or states a size
-    other than the published key's (``PUBLISHED_KEY``), or whose
+    other than the published key's (``PUBLISHED_KEY`` and the file's
+    ``ties``), whose ``ties`` are unsound (``own_ties``), or whose
     ``layer_types`` hold a kind that ``flops.py`` cannot count."""
     from bench import flops
     try:
         built = program_config(c)
-        for attr, key in PUBLISHED_KEY.items():
+        own = own_ties(c, built)
+        for attr, key in {**PUBLISHED_KEY, **own}.items():
             have, want = _attr(built, attr), published(c, key)
-            if None not in (have, want) and have != want:
+            # a tie of the file's own holds where either side is None too
+            if (attr in own or None not in (have, want)) and have != want:
                 raise ValueError(f"program {attr} is {have!r} where the "
                                  f"published {key} is {want!r}")
         flops.layer_kinds(c)
     except (ValueError, TypeError) as e:
         raise ValueError(f"{path}: {e}") from None
+
+
+def own_ties(c: dict, built) -> dict:
+    """The file's ``ties`` ({program attribute path: published key}); each
+    has to name an attribute the program's configuration has and a key the
+    file states, and none may tie an attribute of ``PUBLISHED_KEY`` to
+    another key."""
+    ties = c.get("ties", {})
+    for attr, key in ties.items():
+        if PUBLISHED_KEY.get(attr, key) != key:
+            raise ValueError(f"tie {attr}: {key!r} where PUBLISHED_KEY "
+                             f"ties it to {PUBLISHED_KEY[attr]!r}")
+        if not _has(built, attr):
+            raise ValueError(f"tie {attr}: {type(built).__name__} has no "
+                             f"attribute {attr!r}")
+        if key not in c:
+            raise ValueError(f"tie {attr}: the file states no {key!r}")
+    return ties
 
 
 def published(c: dict, key: str):
@@ -141,6 +163,14 @@ def _attr(obj, path: str):
     for name in path.split("."):
         obj = getattr(obj, name, None)
     return obj
+
+
+def _has(obj, path: str) -> bool:
+    for name in path.split("."):
+        if not hasattr(obj, name):
+            return False
+        obj = getattr(obj, name)
+    return True
 
 
 def _find(dirs, filename: str) -> Path:

@@ -1,7 +1,7 @@
 """One smoke-size run of a cell on the CPU, optionally with a planted fault.
 
     python -m bench.tests.run_smoke <cell> <seed> \
-        [frozen|half_batch|no_exchange]
+        [frozen|half_batch|no_exchange] [--benchmark BENCHMARK.json]
 
 Skips only the harness's look for a chip: the rest of a run (feed, entry
 point, window, metrics, the check against the reference) is the real one.
@@ -13,8 +13,10 @@ The faults break the timed path underneath the harness:
 * ``no_exchange``: the reduce-scatter between chips keeps each chip's own
   gradient shard (scaled as if it were the sum) instead of summing.
 
-Prints the result object as its last line.
+``--benchmark`` names the layout to shrink (``smoke.smoke_layout``), the
+repository's by default. Prints the result object as its last line.
 """
+import argparse
 import json
 import sys
 import tempfile
@@ -66,12 +68,18 @@ def plant(fault: str):
 
 def main(argv):
     from bench import harness
-    from bench.tests.smoke import smoke_layout
-    cell, seed = argv[0], int(argv[1])
-    plant(argv[2] if len(argv) > 2 else "")
+    from bench.tests.smoke import BENCHMARK, smoke_layout
+    ap = argparse.ArgumentParser()
+    ap.add_argument("cell")
+    ap.add_argument("seed", type=int)
+    ap.add_argument("fault", nargs="?", default="")
+    ap.add_argument("--benchmark", type=Path, default=BENCHMARK)
+    args = ap.parse_args(argv)
+    plant(args.fault)
     with tempfile.TemporaryDirectory() as tmp:
-        layout = smoke_layout(Path(tmp))
-        result = harness.run_cell(layout, cell, seed, 1.0, False, T_START)
+        layout = smoke_layout(Path(tmp), args.benchmark)
+        result = harness.run_cell(layout, args.cell, args.seed, 1.0, False,
+                                  T_START)
     print(json.dumps(result), flush=True)
 
 

@@ -1,95 +1,125 @@
-"""A copy of the benchmark's layout at smoke sizes, for runs on the CPU.
+"""A copy of a benchmark's layout at smoke sizes, for runs on the CPU.
 
 Every configuration keeps its reference and its keys; only the sizes
-shrink (widths, depth, vocabulary, batch, sequence). The cells, traffic
-parameters, limits and metric readers are the real ones.
+shrink (widths, depth, vocabulary, batch, sequence), as the ``smoke``
+object of each configuration file and each workload file says. The
+cells, traffic parameters, limits and metric readers are the real ones.
+The rehearsals (``rehearsals.json``) add configurations and cells that
+the benchmark does not hold yet. So a configuration and its cells enter
+the rig by their own files and entries, with no edit here.
 """
 from __future__ import annotations
 
 import json
+import os
 import shutil
+import subprocess
+import sys
 from pathlib import Path
 
 from bench import harness
 
-SMOKE_CONFIG = {
-    "vit-s16": dict(hidden_size=96, num_hidden_layers=2, head_dim=16,
-                    intermediate_size=192, patch_size=8, image_size=32,
-                    program=dict(d_model=96, n_layers=2, d_ff=192, patch=8,
-                                 image_size=32)),
-    "stablelm-3b-l4": dict(hidden_size=128, num_hidden_layers=2,
-                           num_attention_heads=4, num_key_value_heads=4,
-                           head_dim=32, intermediate_size=256,
-                           vocab_size=512,
-                           program=dict(d_model=128, n_layers=2, n_heads=4,
-                                        n_kv_heads=4, head_dim=32, d_ff=256,
-                                        vocab_size=512)),
-}
-SMOKE_TRAFFIC = {
-    "vit-s16.d2ft-paper": dict(batch=10, ref_rows=5, pool=4),
-    "vit-s16.full-ft": dict(batch=10, ref_rows=5, pool=4),
-}
-# Cells of the issue not yet in BENCHMARK.json (PERF.md, Open questions):
-# rehearsed here, the four-chip one on four virtual CPU devices, so that
-# their configuration, reference and entry drivers keep working until a
-# later change adds the cells. Their limits are the CPU's (float32 is
-# exact there), not the chip's.
-SMOKE_ONLY_CONFIGS = [{"name": "stablelm-3b-l4",
-                       "file": "bench/configs/stablelm-3b-l4.json"}]
-SMOKE_ONLY = [
-    ({"name": "stablelm-3b-l4.d2ft-paper", "config": "stablelm-3b-l4",
-      "traffic": "d2ft-paper", "chips": 1},
-     {"entry": "finetune", "batch": 5, "seq": 16, "n_microbatches": 5,
-      "n_pf": 3, "n_po": 1, "pool": 4, "data": {"order_bias": 6.0},
-      "ref_rows": 5,
-      "limits": {"loss_gap": 1e-5, "grad_gap": 1e-4, "update_gap": 1e-4,
-                 "plan_gap": 1e-3}}),
-    ({"name": "stablelm-3b-l4.full-ft", "config": "stablelm-3b-l4",
-      "traffic": "full-ft", "chips": 1},
-     {"entry": "finetune", "d2ft": False, "batch": 5, "seq": 16,
-      "n_microbatches": 5, "pool": 4, "data": {"order_bias": 6.0},
-      "ref_rows": 5,
-      "limits": {"loss_gap": 1e-5, "grad_gap": 1e-4, "update_gap": 1e-4}}),
-    ({"name": "stablelm-3b-l4.d2ft-zero3-4chip", "config": "stablelm-3b-l4",
-      "traffic": "d2ft-zero3-4chip", "chips": 4},
-     {"entry": "finetune_distributed", "batch": 20, "seq": 16,
-      "n_microbatches": 20, "n_pf": 12, "n_po": 4, "data_parallel": 4,
-      "sync_mode": "zero3", "pool": 4, "data": {"order_bias": 6.0},
-      "ref_rows": 5,
-      "limits": {"loss_gap": 1e-5, "grad_gap": 1e-4, "update_gap": 1e-4,
-                 "plan_gap": 1e-3}}),
-]
+BENCHMARK = harness.REPO / "BENCHMARK.json"
+REHEARSALS = Path(__file__).with_name("rehearsals.json")
+# what a workload's ``smoke`` object may shrink: sizes only, never a limit,
+# so that a smoke check is held to the cell's own limits
+SMOKE_SIZE_KEYS = {"batch", "seq", "n_microbatches", "ref_rows", "pool"}
 
 
-def smoke_config(real: dict) -> dict:
+def layout_of(benchmark: Path) -> harness.Layout:
+    """The layout of a ``BENCHMARK.json``: its files under its ``paths``,
+    relative to its own directory."""
+    benchmark = Path(benchmark)
+    root = benchmark.parent
+    paths = [root / p for p in json.loads(benchmark.read_text())["paths"]]
+    return harness.Layout(benchmark=benchmark, root=root,
+                          workload_dirs=[p / "workloads" for p in paths],
+                          metric_dirs=[p / "metrics" for p in paths])
+
+
+def rehearsals(spec: dict) -> tuple[list, list]:
+    """The rehearsed configurations and cells whose names ``spec`` does not
+    hold: ([{name, file}], [{cell, traffic}])."""
+    r = json.loads(REHEARSALS.read_text())
+    configs = {c["name"] for c in spec["configs"]}
+    cells = {w["name"] for w in spec["workloads"]}
+    return ([c for c in r["configs"] if c["name"] not in configs],
+            [x for x in r["cells"] if x["cell"]["name"] not in cells])
+
+
+def smoke_cells(benchmark: Path = BENCHMARK) -> list:
+    """Every cell the smoke layout of ``benchmark`` holds, rehearsals last."""
+    spec = layout_of(benchmark).spec()
+    return spec["workloads"] + [x["cell"] for x in rehearsals(spec)[1]]
+
+
+def _smoke(obj: dict, path: Path) -> dict:
+    if "smoke" not in obj:
+        raise ValueError(f"{path} has no smoke object")
+    return obj["smoke"]
+
+
+def smoke_config(real: dict, path: Path) -> dict:
     """A configuration at its smoke sizes: published keys and the program
     object shrunk alike."""
-    small = dict(SMOKE_CONFIG[real["name"]])
-    program = dict(real["program"], **small.pop("program"))
+    small = dict(_smoke(real, path))
+    program = dict(real["program"], **small.pop("program", {}))
     return dict(real, **small, program=program)
 
 
-def smoke_layout(tmp: Path) -> harness.Layout:
-    """Write the smoke copy under ``tmp``; return a Layout reading it."""
-    real = harness.Layout()
+def smoke_traffic(real: dict, path: Path) -> dict:
+    """A traffic mix at its smoke sizes; a ``smoke`` key that is not a size
+    (``SMOKE_SIZE_KEYS``) raises, naming the file."""
+    small = _smoke(real, path)
+    other = sorted(set(small) - SMOKE_SIZE_KEYS)
+    if other:
+        raise ValueError(f"{path}: smoke key {other[0]!r} is not one of "
+                         f"{sorted(SMOKE_SIZE_KEYS)}")
+    return dict(real, **small)
+
+
+def smoke_layout(tmp: Path, benchmark: Path = BENCHMARK) -> harness.Layout:
+    """Write the smoke copy of ``benchmark`` under ``tmp``; return a Layout
+    reading it."""
+    real = layout_of(benchmark)
     spec = real.spec()
+    configs, cells = rehearsals(spec)
     (tmp / "configs").mkdir(parents=True)
     (tmp / "workloads").mkdir()
-    spec["configs"] += SMOKE_ONLY_CONFIGS
-    for c in spec["configs"]:
-        src = harness.REPO / c["file"]
-        cfg = smoke_config(json.loads(src.read_text()))
+    sources = [real.root / c["file"] for c in spec["configs"]] + \
+        [harness.REPO / c["file"] for c in configs]
+    spec["configs"] += configs
+    for c, src in zip(spec["configs"], sources):
+        cfg = smoke_config(json.loads(src.read_text()), src)
         (tmp / "configs" / src.name).write_text(json.dumps(cfg))
         ref = src.with_name(src.name[:-len(".json")] + ".reference.py")
         shutil.copy(ref, tmp / "configs" / ref.name)
         c["file"] = f"configs/{src.name}"
     for w in spec["workloads"]:
-        t = dict(real.traffic(w["name"]), **SMOKE_TRAFFIC[w["name"]])
-        (tmp / "workloads" / f"{w['name']}.json").write_text(json.dumps(t))
-    for cell, traffic in SMOKE_ONLY:
-        spec["workloads"].append(cell)
-        (tmp / "workloads" / f"{cell['name']}.json").write_text(
-            json.dumps(traffic))
+        name = f"{w['name']}.json"
+        src = harness._find(real.workload_dirs, name)
+        t = smoke_traffic(json.loads(src.read_text()), src)
+        (tmp / "workloads" / name).write_text(json.dumps(t))
+    for x in cells:
+        spec["workloads"].append(x["cell"])
+        (tmp / "workloads" / f"{x['cell']['name']}.json").write_text(
+            json.dumps(x["traffic"]))
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
     return harness.Layout(benchmark=tmp / "BENCHMARK.json", root=tmp,
-                          workload_dirs=[tmp / "workloads"])
+                          workload_dirs=[tmp / "workloads"],
+                          metric_dirs=real.metric_dirs)
+
+
+def run_smoke(cell: str, seed: int, fault: str = "",
+              benchmark: Path = BENCHMARK) -> tuple[dict, str]:
+    """``run_smoke.py`` in a subprocess with as many virtual CPU devices as
+    the cell asks for chips: (its result object, its standard error)."""
+    chips = {w["name"]: w["chips"] for w in smoke_cells(benchmark)}[cell]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}")
+    proc = subprocess.run(
+        [sys.executable, "-m", "bench.tests.run_smoke", cell, str(seed),
+         fault, "--benchmark", str(benchmark)], cwd=harness.REPO, env=env,
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stderr

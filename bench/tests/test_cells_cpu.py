@@ -1,37 +1,19 @@
 """Every cell end to end at smoke size on the CPU, and its faults.
 
-Each run is a subprocess (``run_smoke``) so that it gets a fresh JAX with
-as many virtual devices as the cell asks for chips. A fault planted under
+Each run is a subprocess (``smoke.run_smoke``) so that it gets a fresh JAX
+with as many virtual devices as the cell asks for chips. A fault planted under
 the harness has to turn ``correct`` false; an unbroken run has to read
 true, with every compared number printed beside its limit."""
-import json
-import os
-import subprocess
-import sys
-
 import pytest
 
-from bench import harness
-from bench.tests.smoke import SMOKE_ONLY
+from bench.tests.smoke import run_smoke, smoke_cells
 
-WORKLOADS = harness.Layout().spec()["workloads"] + [c for c, _ in SMOKE_ONLY]
+WORKLOADS = smoke_cells()
 CELLS = [w["name"] for w in WORKLOADS]
 CHIPS = {w["name"]: w["chips"] for w in WORKLOADS}
 FAULTS = [(c, "frozen") for c in CELLS] + \
     [(c, "half_batch") for c in CELLS] + \
     [(c, "no_exchange") for c in CELLS if CHIPS[c] > 1]
-
-
-def run_smoke(cell, seed, fault=""):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS=f"--xla_force_host_platform_device_count="
-                         f"{CHIPS[cell]}")
-    proc = subprocess.run(
-        [sys.executable, "-m", "bench.tests.run_smoke", cell, str(seed),
-         fault], cwd=harness.REPO, env=env, capture_output=True, text=True,
-        timeout=600)
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stderr
 
 
 @pytest.mark.parametrize("cell", CELLS)
