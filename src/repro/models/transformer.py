@@ -15,6 +15,11 @@ Design notes
   the subnet for that sample; p_s removes the contribution so only the
   residual route remains. This is the masked reference path; the packed
   deployment path lives in core/d2ft.py.
+* Attention and dense-MLP groups are contiguous input columns of the output
+  projection (``wo``, ``w_down``), so the mix above is one masked matmul,
+  ``gated_linear``: the forward scales each group's columns by g_f, the
+  backward masks both cotangents by g_f * g_b. No [B,S,G,D] per-group
+  tensor is formed.
 * MoE blocks treat the routed FFN as a single D2FT group (G position 0).
 """
 from __future__ import annotations
@@ -44,14 +49,53 @@ def gate_mix(c_g, g_f, g_b):
     return gf * (gb * c_g + (1.0 - gb) * jax.lax.stop_gradient(c_g))
 
 
-def _group_project(heads_out, wo, G):
-    """heads_out: [B,S,H,hd]; wo: [H*hd, D]. Returns per-group projected
-    contributions [B,S,G,D] (sum over G == plain projection)."""
-    B, S, H, hd = heads_out.shape
-    D = wo.shape[-1]
-    w3 = wo.reshape(H, hd, D)
-    per_head = jnp.einsum("bshd,hdD->bshD", heads_out, w3)
-    return per_head.reshape(B, S, G, H // G, D).sum(axis=3)
+# Hook: when set to a callable, every gated output projection calls
+# ``on_gated_proj(kind, x.shape, G)`` once as it is traced, kind "attn" or
+# "mlp". Tests use it to count the projections that take gated_linear.
+on_gated_proj = None
+
+
+@jax.custom_vjp
+def gated_linear(x, w, gf_cols, gm_cols):
+    """``(x * gf_cols) @ w`` whose backward masks by ``gm_cols`` instead.
+
+    x: [..., F]; w: [F, D]; gf_cols, gm_cols: masks broadcastable to x,
+    g_f and g_f * g_b repeated over each group's input columns. This is
+    gate_mix of the per-group products, summed over groups, for any gate
+    values: dx = (dy @ w.T) * gm_cols, dw = (x * gm_cols).T @ dy, and the
+    masks (schedule constants) get no cotangent."""
+    return (x * gf_cols) @ w
+
+
+def _gated_linear_fwd(x, w, gf_cols, gm_cols):
+    return gated_linear(x, w, gf_cols, gm_cols), (x, w, gm_cols)
+
+
+def _gated_linear_bwd(res, dy):
+    x, w, gm_cols = res
+    dx = ((dy @ w.T) * gm_cols).astype(x.dtype)
+    lead = tuple(range(x.ndim - 1))
+    dw = jnp.tensordot(x * gm_cols, dy, axes=(lead, lead)).astype(w.dtype)
+    return dx, dw, None, None
+
+
+gated_linear.defvjp(_gated_linear_fwd, _gated_linear_bwd)
+
+
+def _gated_proj(kind, x, w, layer_gates):
+    """Gated output projection of x [B,S,F] by w [F,D]: group g of the G
+    (sample, group) gates owns input columns [g*F/G, (g+1)*F/G)."""
+    g_f, g_b = layer_gates
+    G = g_f.shape[-1]
+    if on_gated_proj is not None:
+        on_gated_proj(kind, x.shape, G)
+    rep = x.shape[-1] // G
+
+    def cols(g):
+        return jnp.repeat(g, rep, axis=1)[:, None, :].astype(x.dtype)
+
+    with jax.named_scope("gated_proj"):
+        return gated_linear(x, w, cols(g_f), cols(g_f * g_b))
 
 
 # =================================================== tensor-parallel helpers
@@ -230,12 +274,10 @@ def _apply_attn_inner(p, h, kind, cfg: ModelConfig, layer_gates, policy,
     if layer_gates is None:
         c = out.reshape(B, S, n_heads * hd) @ p["wo"]
         return _tp_sum(c, tp[0]) if tp is not None else c
-    # group-wise projection + gate_mix: on the kernel path this also cuts
-    # wo gradients for p_o groups, matching the masked reference exactly.
-    g_f, g_b = layer_gates
-    G = g_f.shape[-1]
-    c_g = _group_project(out, p["wo"], G)               # [B,S,G,D]
-    c = gate_mix(c_g, g_f, g_b).sum(axis=2)
+    # a group is H // G contiguous heads, (H // G) * hd columns of wo; on the
+    # kernel path the masked backward also cuts wo gradients for p_o groups
+    c = _gated_proj("attn", out.reshape(B, S, n_heads * hd), p["wo"],
+                    layer_gates)
     return _tp_sum(c, tp[0]) if tp is not None else c
 
 
@@ -286,7 +328,7 @@ def _apply_ffn(p, h, cfg: ModelConfig, layer_gates, policy,
     if tp is not None:
         # FFN columns over the tensor axis: T | G keeps each device's
         # F/T-column block an integral number of whole gate groups, so the
-        # grouped w_down reshape below stays exact on the local slice.
+        # gated w_down projection below stays exact on the local slice.
         tp_axis, T = tp
         assert policy is None, "tensor parallelism has no policy route"
         idx = jax.lax.axis_index(tp_axis)
@@ -315,13 +357,7 @@ def _apply_ffn(p, h, cfg: ModelConfig, layer_gates, policy,
     if layer_gates is None:
         y = hid @ mlp["w_down"]
         return (_tp_sum(y, tp[0]) if tp is not None else y), None
-    g_f, g_b = layer_gates
-    G = g_f.shape[-1]
-    B, S, F = hid.shape
-    D = mlp["w_down"].shape[-1]
-    wd = mlp["w_down"].reshape(G, F // G, D)
-    c_g = jnp.einsum("bsgf,gfD->bsgD", hid.reshape(B, S, G, F // G), wd)
-    y = gate_mix(c_g, g_f, g_b).sum(axis=2)
+    y = _gated_proj("mlp", hid, mlp["w_down"], layer_gates)
     return (_tp_sum(y, tp[0]) if tp is not None else y), None
 
 

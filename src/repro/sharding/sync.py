@@ -71,7 +71,7 @@ holds a full replica between steps. Inside the step, full-leaf views exist
 only transiently (``zero3_materialize``) and the gather mask becomes a
 **forward** question — a (layer, head-group) run that is p_s on every
 micro-batch of the schedule contributes *exactly zero* to the residual
-stream (``gate_mix`` multiplies the group contribution by g_f == 0), so
+stream (``gated_linear`` zeroes the group's input columns, g_f == 0), so
 its parameters are never all-gathered at all: a zeros view is
 bit-identical, for the forward and (trivially) the backward. Runs with any
 p_f or p_o cell are gathered; protected leaves gather densely. Gradients
@@ -132,7 +132,7 @@ def forward_live_groups(sched: Schedule) -> np.ndarray:
 
     The complement is the ZeRO-3 gather-elision set: a subnet that is p_s
     on every micro-batch contributes exactly zero to the residual stream
-    whatever its parameter values (``gate_mix`` multiplies by g_f == 0),
+    whatever its parameter values (``gated_linear`` zeroes its columns),
     so no device needs its params materialized that step. Superset of
     ``backward_live_groups`` (p_f cells are forward-live too), which keeps
     the zero-mode invariant gather ⊇ scatter intact."""
@@ -154,8 +154,8 @@ _ALL = SyncSpec("all")
 _NONE = SyncSpec("none")
 
 # Leaf name -> axis holding the G contiguous head-group blocks. Matches the
-# group decomposition of the masked path (models/transformer.py
-# _group_project / _apply_ffn) and the packed path's _slice_cols/_slice_rows.
+# column groups of the masked path's gated projections (models/transformer.py
+# _gated_proj of wo / w_down) and the packed path's _slice_cols/_slice_rows.
 _Q_AXIS = {"wq": 1, "bq": 0, "wo": 0}
 _KV_AXIS = {"wk": 1, "bk": 0, "wv": 1, "bv": 0}
 _FFN_AXIS = {"w_up": 1, "w_gate": 1, "w_down": 0}
