@@ -1,4 +1,4 @@
-"""Roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Roofline analysis from dry-run artifacts (``launch/dryrun.py`` JSON).
 
 Per (arch × shape × mesh) JSON produced by repro.launch.dryrun:
   compute term    = HLO_FLOPs_per_device / peak_FLOP/s      (197e12 bf16)
@@ -111,7 +111,7 @@ def analyze(rec: Dict) -> Dict:
     terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     lever = {
-        "compute": "cut redundant/remat FLOPs (packed D2FT path, fused "
+        "compute": "cut redundant/remat FLOPs (gated D2FT kernels, fused "
                    "attention) or add chips",
         "memory": "fuse elementwise chains + flash/chunked attention to cut "
                   "HBM traffic; bf16 activations",

@@ -3,7 +3,8 @@
 Prints ``name,us_per_call,derived`` CSV (us_per_call = wall time per
 fine-tune step where applicable). Datasets are synthetic (offline
 container); the deliverable is the ORDERING/BUDGET structure of each paper
-table, not absolute CIFAR numbers — see EXPERIMENTS.md §Paper-validation.
+table, not absolute CIFAR numbers. On-chip numbers live in ``bench/``
+(``BENCHMARK.json``, ``PERF.md``), not here.
 
   python -m benchmarks.run            # all tables
   python -m benchmarks.run --only workload_variance,po_sweep
@@ -256,45 +257,6 @@ def bench_lora():
     emit("fig3_small_rank_r2", 0.0, f"top1={acc_small:.3f};compute=0.60")
     acc_d2ft = lora_finetune(rank=24, schedule_fn=d2ft_schedule_fn(d2))
     emit("fig3_d2ft_lora_r24", 0.0, f"top1={acc_d2ft:.3f};compute=0.60")
-
-
-# --------------------------------------------- packed-path compiled savings
-def bench_packed_flops():
-    """The systems claim: compiled FLOPs of the packed D2FT step vs standard
-    full fine-tuning (same model/batch). Shows the compute cut in the
-    executable, not just in the cost model."""
-    from repro.configs.base import ModelConfig
-    from repro.core.d2ft import (mb_packed_indices, packed_forward_mb,
-                                 plan_schedule)
-    from repro.models.transformer import forward, init_model
-
-    cfg = ModelConfig(name="bench", arch_type="dense", n_layers=4,
-                      d_model=128, n_heads=4, n_kv_heads=4, d_ff=256,
-                      vocab_size=512)
-    params = init_model(jax.random.PRNGKey(0), cfg)
-    B, S, M = 20, 64, 5
-    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, 512)
-    rng = np.random.default_rng(0)
-    d2 = D2FTConfig(n_microbatches=M, n_pf=3, n_po=0, head_groups=4)
-    bw = np.repeat(rng.random((16, 1)) + .1, M, 1)
-    fw = rng.random((16, M)) + .1
-    sched = plan_schedule(d2, bw, fw, 4, 4)
-    idx, bwd, val = mb_packed_indices(sched, M)
-    arrays = tuple(map(jnp.asarray, (idx, bwd, val)))
-
-    def full_loss(p):
-        return jnp.mean(forward(p, cfg, tokens=toks)[0] ** 2)
-
-    def packed_loss(p):
-        return jnp.mean(packed_forward_mb(p, cfg, toks, arrays, M)[0] ** 2)
-
-    f_full = float(jax.jit(jax.grad(full_loss)).lower(params).compile()
-                   .cost_analysis().get("flops", 0))
-    f_packed = float(jax.jit(jax.grad(packed_loss)).lower(params).compile()
-                     .cost_analysis().get("flops", 0))
-    emit("packed_flops_fraction", 0.0,
-         f"full={f_full:.3e};packed={f_packed:.3e};"
-         f"fraction={f_packed / f_full:.3f};cost_model=0.60")
 
 
 # ------------------------------------------- gated kernel backward savings
@@ -651,7 +613,6 @@ BENCHES = {
     "po_sweep": bench_po_sweep,
     "bilevel_vs_scaler": bench_bilevel_vs_scaler,
     "lora": bench_lora,
-    "packed_flops": bench_packed_flops,
     "kernel_backward": bench_kernel_backward,
     "distributed_step": bench_distributed_step,
     "elastic": bench_elastic,

@@ -1,5 +1,6 @@
 """End-to-end driver: fine-tune a ~100M-param LLM with D2FT for a few
-hundred steps on synthetic Markov data, masked vs packed execution paths.
+hundred steps on synthetic Markov data, on the masked or the kernel
+execution path.
 
   PYTHONPATH=src python examples/d2ft_llm_finetune.py [--steps 200]
 """
@@ -17,14 +18,10 @@ from repro.train.loop import finetune
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=200)
-ap.add_argument("--packed", action="store_true")
 ap.add_argument("--kernel", action="store_true",
                 help="route attention through the Pallas gated flash kernel "
                      "(gate-aware backward; interpret mode on CPU)")
 args = ap.parse_args()
-if args.packed and args.kernel:
-    ap.error("--packed and --kernel are mutually exclusive (the packed "
-             "gather path bypasses the gated attention kernel)")
 
 # ~100M params: 12 layers, d_model 768 (GPT-2-small-ish)
 cfg = ModelConfig(name="llm100m", arch_type="dense", n_layers=12,
@@ -40,9 +37,8 @@ print(f"D2FT budget: compute {(2 + 0.4) / 4:.0%}, comm {(2 + 0.5) / 4:.0%}")
 batches = lm_batches(0, cfg.vocab_size, batch=8, seq=128, steps=args.steps)
 t0 = time.time()
 params, _, log = finetune(params, cfg, d2, adamw(3e-4), batches,
-                          steps=args.steps, packed=args.packed,
-                          use_kernel=args.kernel)
-path = "packed" if args.packed else ("kernel" if args.kernel else "masked")
+                          steps=args.steps, use_kernel=args.kernel)
+path = "kernel" if args.kernel else "masked"
 print(f"{args.steps} steps ({path} path) in {time.time()-t0:.0f}s")
 print(f"loss: {np.mean(log.losses[:10]):.3f} -> "
       f"{np.mean(log.losses[-10:]):.3f}")

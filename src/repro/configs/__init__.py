@@ -38,7 +38,7 @@ def get_smoke_config(arch: str) -> ModelConfig:
     return _module(arch).smoke_config()
 
 
-# (arch, shape) pairs skipped by design — see DESIGN.md "Shape skips".
+# (arch, shape) pairs skipped by design: each value says why.
 SKIPS = {
     ("hubert-xlarge", "decode_32k"): "encoder-only: no decode step",
     ("hubert-xlarge", "long_500k"): "encoder-only: no decode step",

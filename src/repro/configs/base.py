@@ -141,4 +141,3 @@ class D2FTConfig:
     backward_score: str = "weight_magnitude"   # paper's final choice
     forward_score: str = "fisher"
     head_groups: int = 0              # subnets per layer (0 = n_heads)
-    mode: str = "packed"              # packed|masked

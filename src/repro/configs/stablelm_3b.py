@@ -2,7 +2,8 @@
 
 [hf:stabilityai/stablelm-2-1_6b] 32 layers, d_model 2560, 32 heads (MHA),
 d_ff 6912, vocab 50304, SwiGLU-style gated MLP, RoPE (full, simplified
-from the model's 25% partial rotary — noted in DESIGN.md).
+from the model's 25% partial rotary: ``ModelConfig`` has no rotary
+fraction).
 """
 from repro.configs.base import ATTN_GLOBAL, ModelConfig
 

@@ -1,31 +1,20 @@
-"""D2FT orchestration: planning + the two execution paths.
+"""D2FT planning: the micro-batch budget -> knapsack -> ``Schedule``.
 
-``plan_schedule``    scores -> bi-level knapsack -> Schedule (host side).
-``gates_from_schedule`` (re-exported) -> masked reference path, used by
-                     models.transformer.forward / models.vit.
-``packed_*``         deployment path: gather each subnet's selected
-                     micro-batches (static capacity — the knapsack equalizes
-                     counts, paper Table I), compute packed, scatter-add
-                     back. This is where the 40% compute / 50% comm saving
-                     is visible in compiled FLOPs.
+``capacities``     per-device knapsack capacities from the budget.
+``plan_schedule``  scores -> bi-level knapsack (Alg. 1) -> Schedule, on the
+                   host. ``core.schedule.gates_from_schedule`` turns the
+                   Schedule into the gates that the masked and kernel
+                   execution paths (models.transformer, models.vit) read.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 
-import jax
-import jax.numpy as jnp
-
-from repro.configs.base import D2FTConfig, ModelConfig, ATTN_GLOBAL, ATTN_LOCAL
+from repro.configs.base import D2FTConfig
 from repro.core import knapsack
-from repro.core.schedule import (P_F, P_O, P_S, Schedule, build_schedule,
-                                 gates_from_schedule, merge_tables,
-                                 packed_indices)
-from repro.models import attention as attn
-from repro.models.layers import apply_norm, _act
+from repro.core.schedule import Schedule, merge_tables
 
 
 # ------------------------------------------------------------------ planning
@@ -64,386 +53,3 @@ def plan_schedule(d2ft: D2FTConfig, backward_scores: np.ndarray,
             fwd[sel_pf[k]] = 0.0
         sel_po[k] = knapsack.dp_knapsack(fwd, np.full(N, c_f), cap_po_arr[k])
     return Schedule(merge_tables(sel_pf, sel_po), n_layers, n_groups)
-
-
-# ---------------------------------------------------------------- packed path
-def _slice_cols(w, G):
-    """[..., X] -> [G, ..., X/G] (contiguous group slices on last dim)."""
-    parts = w.reshape(*w.shape[:-1], G, w.shape[-1] // G)
-    return jnp.moveaxis(parts, -2, 0)
-
-
-def _slice_rows(w, G):
-    return w.reshape(G, w.shape[0] // G, *w.shape[1:])
-
-
-def _kv_slices(p, G, n_kv, head_dim):
-    """Per-group KV projection weights. Returns (wk_g, wv_g, kv_per_group)."""
-    if n_kv % G == 0:
-        return _slice_cols(p["wk"], G), _slice_cols(p["wv"], G), n_kv // G
-    if G % n_kv == 0:
-        # each group uses exactly one kv head
-        kv_of_g = (jnp.arange(G) * n_kv) // G
-        wk3 = p["wk"].reshape(p["wk"].shape[0], n_kv, head_dim)
-        wv3 = p["wv"].reshape(p["wv"].shape[0], n_kv, head_dim)
-        wk_g = wk3[:, kv_of_g].transpose(1, 0, 2)      # [G, D, hd]
-        wv_g = wv3[:, kv_of_g].transpose(1, 0, 2)
-        return wk_g, wv_g, 1
-    # fallback: replicate full kv per group
-    return (jnp.broadcast_to(p["wk"], (G,) + p["wk"].shape),
-            jnp.broadcast_to(p["wv"], (G,) + p["wv"].shape), n_kv)
-
-
-def packed_attention_block(p, x, cfg: ModelConfig, idx, bwd, val,
-                           kind: str = ATTN_GLOBAL):
-    """Packed D2FT attention sub-block.
-
-    x: [B,S,D] residual stream; idx/bwd/val: [G,C] gather indices, backward
-    mask (1 = p_f), validity mask (0 = padding). Each head-group g computes
-    attention only for its C selected micro-batch samples.
-    Returns the residual contribution [B,S,D].
-    """
-    B, S, D = x.shape
-    G, C = idx.shape
-    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    assert H % G == 0, (H, G)
-    h = apply_norm(p["norm1"], x, cfg.norm)
-    hg = h[idx.reshape(-1)].reshape(G, C, S, D)
-
-    wq_g = _slice_cols(p["attn"]["wq"], G)             # [G, D, (H/G)*hd]
-    wk_g, wv_g, kv_pg = _kv_slices(p["attn"], G, Hkv, hd)
-    wo_g = _slice_rows(p["attn"]["wo"], G)             # [G, (H/G)*hd, D]
-    window = cfg.window if kind == ATTN_LOCAL else 0
-
-    def per_group(hx, wq, wk, wv, wo):
-        q = (hx @ wq).reshape(C, S, H // G, hd)
-        k = (hx @ wk).reshape(C, S, kv_pg, hd)
-        v = (hx @ wv).reshape(C, S, kv_pg, hd)
-        if cfg.rope:
-            pos = jnp.arange(S)[None, :]
-            q = attn.apply_rope(q, pos, cfg.rope_theta)
-            k = attn.apply_rope(k, pos, cfg.rope_theta)
-        if window and window > 0 and S > 2 * window and S % window == 0:
-            o = attn._block_local_attention(q, k, v, window)
-        elif window and window > 0:
-            o = attn._sdpa(q, k, v, attn._window_mask(S, S, window))
-        elif cfg.causal:
-            o = attn._sdpa(q, k, v, attn._causal_mask(S, S))
-        else:
-            o = attn._sdpa(q, k, v, jnp.ones((1, 1, S, S), bool))
-        return o.reshape(C, S, (H // G) * hd) @ wo
-
-    out_g = jax.vmap(per_group)(hg, wq_g, wk_g, wv_g, wo_g)   # [G,C,S,D]
-    m_b = bwd[:, :, None, None].astype(out_g.dtype)
-    m_v = val[:, :, None, None].astype(out_g.dtype)
-    out_g = m_v * (m_b * out_g + (1 - m_b) * jax.lax.stop_gradient(out_g))
-    y = jnp.zeros((B, S, D), x.dtype)
-    y = y.at[idx.reshape(-1)].add(out_g.reshape(G * C, S, D))
-    return y
-
-
-def packed_mlp_block(p, x, cfg: ModelConfig, idx, bwd, val):
-    """Packed D2FT FFN sub-block (dense MLP). Same contract as above."""
-    B, S, D = x.shape
-    G, C = idx.shape
-    F = cfg.d_ff
-    assert F % G == 0
-    h = apply_norm(p["norm2"], x, cfg.norm)
-    hg = h[idx.reshape(-1)].reshape(G, C, S, D)
-    wu_g = _slice_cols(p["mlp"]["w_up"], G)
-    wd_g = _slice_rows(p["mlp"]["w_down"], G)
-    wg_g = _slice_cols(p["mlp"]["w_gate"], G) if "w_gate" in p["mlp"] else None
-
-    def per_group(hx, wu, wd, wg):
-        up = hx @ wu
-        hid = _act(cfg.mlp_act)(hx @ wg) * up if wg is not None else \
-            _act(cfg.mlp_act)(up)
-        return hid @ wd
-
-    if wg_g is None:
-        out_g = jax.vmap(lambda a, b, c: per_group(a, b, c, None))(hg, wu_g, wd_g)
-    else:
-        out_g = jax.vmap(per_group)(hg, wu_g, wd_g, wg_g)
-    m_b = bwd[:, :, None, None].astype(out_g.dtype)
-    m_v = val[:, :, None, None].astype(out_g.dtype)
-    out_g = m_v * (m_b * out_g + (1 - m_b) * jax.lax.stop_gradient(out_g))
-    y = jnp.zeros((B, S, D), x.dtype)
-    y = y.at[idx.reshape(-1)].add(out_g.reshape(G * C, S, D))
-    return y
-
-
-def mb_packed_indices(sched, n_mb: int):
-    """Micro-batch-level gather plan: for each (layer, group) the selected
-    micro-batch ids (p_f first, then p_o), plus bwd/valid masks, all padded
-    to the max count C_mb. The knapsack's balanced budget makes C_mb equal
-    across subnets in the homogeneous case (paper Table I)."""
-    t = sched.layer_group_view()                          # [L, G, N]
-    L, G, N = t.shape
-    assert N == n_mb
-    counts = (t != P_S).sum(-1)
-    C = int(counts.max())
-    idx = np.zeros((L, G, C), np.int32)
-    bwd = np.zeros((L, G, C), np.float32)
-    val = np.zeros((L, G, C), np.float32)
-    for l in range(L):
-        for g in range(G):
-            f = np.nonzero(t[l, g] == P_F)[0]
-            o = np.nonzero(t[l, g] == P_O)[0]
-            take = np.concatenate([f, o])[:C]
-            idx[l, g, :len(take)] = take
-            bwd[l, g, :len(f)] = 1.0
-            val[l, g, :len(take)] = 1.0
-    return idx, bwd, val
-
-
-def _mb_gather(x, idx):
-    """x: [M, B', S, D]; idx: [G, C] micro-batch ids -> [G, C*B', S, D].
-    The gather runs over the small UNSHARDED micro-batch axis, so no
-    cross-device data movement happens for sample selection (the
-    batch shard layout is untouched) — this is what makes the packed path
-    GSPMD-friendly at pod scale (EXPERIMENTS.md §Perf)."""
-    G, C = idx.shape
-    M, Bp, S, D = x.shape
-    return x[idx.reshape(-1)].reshape(G, C * Bp, S, D)
-
-
-def _mb_scatter(y, out_g, idx, shape):
-    """Scatter-add group contributions back onto the micro-batch axis."""
-    M, Bp, S, D = shape
-    G, C = idx.shape
-    return y.at[idx.reshape(-1)].add(
-        out_g.reshape(G * C, Bp, S, D))
-
-
-def _split_fo(idx, bwd, val, n_pf: int):
-    """Split the gather plan into the p_f part and the p_o part. The
-    backward for the p_o part is wrapped in stop_gradient at the CALL level
-    so autodiff emits NO backward graph for it at all — masking the
-    cotangent instead leaves the backward matmuls dense (measured: only
-    −4% step FLOPs; with the split the p_o backward is DCE'd,
-    EXPERIMENTS.md §Perf iter s2)."""
-    return (idx[:, :n_pf], val[:, :n_pf]), (idx[:, n_pf:], val[:, n_pf:])
-
-
-def packed_attention_block_mb(p, x, cfg: ModelConfig, idx, bwd, val,
-                              kind=ATTN_GLOBAL, policy=None, n_pf=None):
-    """Micro-batch-axis packed attention. x: [M, B', S, D]."""
-    M, Bp, S, D = x.shape
-    G, C = idx.shape
-    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    h = apply_norm(p["norm1"], x, cfg.norm)
-    wq_g = _slice_cols(p["attn"]["wq"], G)
-    wk_g, wv_g, kv_pg = _kv_slices(p["attn"], G, Hkv, hd)
-    wo_g = _slice_rows(p["attn"]["wo"], G)
-    window = cfg.window if kind == ATTN_LOCAL else 0
-
-    def per_group(hx, wq, wk, wv, wo):
-        CB = hx.shape[0]
-        q = (hx @ wq).reshape(CB, S, H // G, hd)
-        k = (hx @ wk).reshape(CB, S, kv_pg, hd)
-        v = (hx @ wv).reshape(CB, S, kv_pg, hd)
-        if cfg.rope:
-            pos = jnp.arange(S)[None, :]
-            q = attn.apply_rope(q, pos, cfg.rope_theta)
-            k = attn.apply_rope(k, pos, cfg.rope_theta)
-        if window and window > 0 and S > 2 * window and S % window == 0:
-            o = attn._block_local_attention(q, k, v, window)
-        elif window and window > 0:
-            o = attn._sdpa(q, k, v, attn._window_mask(S, S, window))
-        elif cfg.causal:
-            o = attn._sdpa(q, k, v, attn._causal_mask(S, S))
-        else:
-            o = attn._sdpa(q, k, v, jnp.ones((1, 1, S, S), bool))
-        return o.reshape(CB, S, (H // G) * hd) @ wo
-
-    def run(sub_idx, sub_val):
-        hg = _mb_gather(h, sub_idx)
-        if policy is not None:
-            hg = policy.packed_groups(hg)
-        out = jax.vmap(per_group)(hg, wq_g, wk_g, wv_g, wo_g)
-        Csub = sub_idx.shape[1]
-        out = out.reshape(G, Csub, Bp, S, D)
-        return out * sub_val[:, :, None, None, None].astype(out.dtype)
-
-    return _fo_combine(run, idx, bwd, val, n_pf, (M, Bp, S, D), x.dtype)
-
-
-def _fo_combine(run, idx, bwd, val, n_pf, shape, dtype):
-    """Run the p_f part with gradients and the p_o part fully
-    stop-gradient'd (backward DCE'd), scatter both onto the mb axis."""
-    if n_pf is None:
-        n_pf = int(bwd.sum(-1).max()) if hasattr(bwd, "sum") else idx.shape[1]
-    (idx_f, val_f), (idx_o, val_o) = _split_fo(idx, bwd, val, n_pf)
-    y = jnp.zeros(shape, dtype)
-    if idx_f.shape[1] > 0:
-        y = _mb_scatter(y, run(idx_f, val_f), idx_f, shape)
-    if idx_o.shape[1] > 0:
-        out_o = jax.lax.stop_gradient(run(idx_o, val_o))
-        y = _mb_scatter(y, out_o, idx_o, shape)
-    return y
-
-
-def packed_mlp_block_mb(p, x, cfg: ModelConfig, idx, bwd, val, policy=None,
-                        n_pf=None):
-    M, Bp, S, D = x.shape
-    G, C = idx.shape
-    h = apply_norm(p["norm2"], x, cfg.norm)
-    wu_g = _slice_cols(p["mlp"]["w_up"], G)
-    wd_g = _slice_rows(p["mlp"]["w_down"], G)
-    wg_g = _slice_cols(p["mlp"]["w_gate"], G) if "w_gate" in p["mlp"] \
-        else None
-
-    def per_group(hx, wu, wd, wg):
-        up = hx @ wu
-        hid = _act(cfg.mlp_act)(hx @ wg) * up if wg is not None else \
-            _act(cfg.mlp_act)(up)
-        return hid @ wd
-
-    def run(sub_idx, sub_val):
-        hg = _mb_gather(h, sub_idx)
-        if policy is not None:
-            hg = policy.packed_groups(hg)
-        if wg_g is None:
-            out = jax.vmap(lambda a, b, c: per_group(a, b, c, None))(
-                hg, wu_g, wd_g)
-        else:
-            out = jax.vmap(per_group)(hg, wu_g, wd_g, wg_g)
-        Csub = sub_idx.shape[1]
-        out = out.reshape(G, Csub, Bp, S, D)
-        return out * sub_val[:, :, None, None, None].astype(out.dtype)
-
-    return _fo_combine(run, idx, bwd, val, n_pf, (M, Bp, S, D), x.dtype)
-
-
-def packed_forward_mb(params, cfg: ModelConfig, tokens, sched_arrays,
-                      n_mb: int, policy=None, remat: bool = False,
-                      n_pf: Optional[int] = None):
-    """Micro-batch-axis packed path (deployment form).
-
-    tokens: [B, S] with contiguous micro-batch blocks (sample i belongs to
-    micro-batch i // (B/n_mb)); sched_arrays = mb_packed_indices(...) of
-    shapes [L, G, C_mb]. Sample selection happens on the unsharded
-    micro-batch axis; the batch stays data-sharded throughout.
-    """
-    from repro.models.layers import apply_embedding, softcap
-    from repro.models.transformer import layer_groups
-    idx, bwd, val = sched_arrays
-    if n_pf is None:
-        n_pf = int(np.asarray(bwd).sum(-1).max())   # static (balanced table)
-    cdt = jnp.dtype(cfg.compute_dtype)
-    B, S = tokens.shape
-    Bp = B // n_mb
-    x = apply_embedding(params["embed"], tokens).astype(cdt)
-    x = x.reshape(n_mb, Bp, S, -1)
-    if policy is not None:
-        x = policy.packed_residual(x)
-    n_cycles, pat, rem = layer_groups(cfg)
-    P = len(pat)
-    assert all(k in (ATTN_GLOBAL, ATTN_LOCAL) for k in pat + tuple(rem))
-
-    def one_block(blk, x, kind, i3):
-        li, bi, vi = i3
-        x = x + packed_attention_block_mb(blk, x, cfg, li, bi, vi, kind,
-                                          policy, n_pf=n_pf)
-        if policy is not None:
-            x = policy.packed_residual(x)
-        if "norm2" in blk and "mlp" in blk:
-            x = x + packed_mlp_block_mb(blk, x, cfg, li, bi, vi, policy,
-                                        n_pf=n_pf)
-            if policy is not None:
-                x = policy.packed_residual(x)
-        return x
-
-    if n_cycles > 0:
-        idx_c = idx[:n_cycles * P].reshape(n_cycles, P, *idx.shape[1:])
-        bwd_c = bwd[:n_cycles * P].reshape(n_cycles, P, *bwd.shape[1:])
-        val_c = val[:n_cycles * P].reshape(n_cycles, P, *val.shape[1:])
-
-        def body(x, xs):
-            blocks, ic, bc, vc = xs
-            for i in range(P):
-                x = one_block(blocks[i], x, pat[i], (ic[i], bc[i], vc[i]))
-            return x, None
-        if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
-        xs = (params["cycles"], idx_c, bwd_c, val_c)
-        if n_cycles <= 2:      # unrolled for dry-run cost extrapolation
-            for c in range(n_cycles):
-                x, _ = body(x, jax.tree.map(lambda a: a[c], xs))
-        else:
-            x, _ = jax.lax.scan(body, x, xs)
-
-    for i, kind in enumerate(rem):
-        j = n_cycles * P + i
-        x = one_block(params["rest"][i], x, kind, (idx[j], bwd[j], val[j]))
-
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    x = x.reshape(B, S, -1)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["table"].T.astype(cdt)
-    else:
-        logits = x @ params["unembed"].astype(cdt)
-    if policy is not None:
-        logits = policy.logits(logits)
-    return softcap(logits, cfg.logit_softcap), \
-        {"aux_loss": jnp.zeros((), jnp.float32)}
-
-
-def packed_forward(params, cfg: ModelConfig, tokens, sched_arrays,
-                   policy=None, remat: bool = False):
-    """Packed-path forward for attention-pattern configs.
-
-    sched_arrays = (idx, bwd, val) with shapes [L, G, C] (from
-    schedule.packed_indices). Only ATTN_* block patterns are supported —
-    other families use the masked path (see DESIGN.md §Arch-applicability).
-    Returns (logits, aux).
-    """
-    from repro.models.layers import apply_embedding, softcap
-    from repro.models.transformer import layer_groups
-    idx, bwd, val = sched_arrays
-    cdt = jnp.dtype(cfg.compute_dtype)
-    x = apply_embedding(params["embed"], tokens).astype(cdt)
-    if policy is not None:
-        x = policy.residual(x)
-    n_cycles, pat, rem = layer_groups(cfg)
-    P = len(pat)
-    assert all(k in (ATTN_GLOBAL, ATTN_LOCAL) for k in pat + tuple(rem)), \
-        "packed path supports attention blocks only"
-
-    def one_block(blk, x, kind, i3):
-        li, bi, vi = i3
-        x = x + packed_attention_block(blk, x, cfg, li, bi, vi, kind)
-        if policy is not None:
-            x = policy.residual(x)
-        if "norm2" in blk and "mlp" in blk:
-            x = x + packed_mlp_block(blk, x, cfg, li, bi, vi)
-            if policy is not None:
-                x = policy.residual(x)
-        return x
-
-    if n_cycles > 0:
-        idx_c = idx[:n_cycles * P].reshape(n_cycles, P, *idx.shape[1:])
-        bwd_c = bwd[:n_cycles * P].reshape(n_cycles, P, *bwd.shape[1:])
-        val_c = val[:n_cycles * P].reshape(n_cycles, P, *val.shape[1:])
-
-        def body(x, xs):
-            blocks, ic, bc, vc = xs
-            for i in range(P):
-                x = one_block(blocks[i], x, pat[i], (ic[i], bc[i], vc[i]))
-            return x, None
-        if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
-        x, _ = jax.lax.scan(body, x, (params["cycles"], idx_c, bwd_c, val_c))
-
-    for i, kind in enumerate(rem):
-        j = n_cycles * P + i
-        x = one_block(params["rest"][i], x, kind, (idx[j], bwd[j], val[j]))
-
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["table"].T.astype(cdt)
-    else:
-        logits = x @ params["unembed"].astype(cdt)
-    if policy is not None:
-        logits = policy.logits(logits)
-    return softcap(logits, cfg.logit_softcap), {"aux_loss": jnp.zeros((), jnp.float32)}

@@ -3,8 +3,8 @@
 LoRA adapters attach to the Q/K/V projections of every attention block; the
 foundation weights stay frozen, gradients flow only into the low-rank A/B
 matrices. Each adapter is co-located with its head's subnet, so the D2FT
-gates/packed selection act on the LoRA contribution exactly as on full
-fine-tuning (the paper's "subnet = frozen head + its LoRA matrices").
+gates act on the LoRA contribution exactly as on full fine-tuning (the
+paper's "subnet = frozen head + its LoRA matrices").
 
 Cost model (paper §III-A): rank controls the LoRA compute; the operation
 schedule controls how many micro-batches exercise fwd/bwd per subnet.

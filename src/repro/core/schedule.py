@@ -6,12 +6,13 @@ i with entries  1 = p_f (full),  2 = p_o (forward-only),  3 = p_s (shortcut)
 
 Subnets are indexed k = l * G + g for layer l and head-group g; this module
 converts tables to the (g_f, g_b) gate arrays consumed by
-models.transformer.forward and to packed-path gather indices.
+models.transformer.forward and to the kernel path's static live-slice
+bounds.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 
@@ -94,43 +95,6 @@ def live_slice_bounds(sched: Schedule, mb_of_sample: np.ndarray
     live_f = int((per_sample != P_S).sum(axis=(1, 2)).max())
     live_b = int((per_sample == P_F).sum(axis=(1, 2)).max())
     return live_f, live_b
-
-
-def packed_indices(sched: Schedule, mb_of_sample: np.ndarray,
-                   pad_to: Optional[int] = None
-                   ) -> Tuple[np.ndarray, np.ndarray, int, int]:
-    """Gather indices for the packed path.
-
-    Returns (idx [L, G, C], bwd_mask [L, G, C], C_f, C) where idx[l,g] lists
-    the samples each subnet processes forward (p_f first, then p_o) and
-    bwd_mask is 1 for the p_f entries. Requires the schedule to be balanced
-    (equal counts per subnet) — which the knapsack guarantees when scores
-    are positive; otherwise pad_to sets the capacity and entries are
-    repeated (repeats are masked out via a zero in bwd/fwd scale... padding
-    repeats the first selected sample and contributes via scatter with a
-    zero weight handled by the caller).
-    """
-    t = sched.layer_group_view()                         # [L, G, N]
-    L, G, N = t.shape
-    per_sample = t[:, :, mb_of_sample]                   # [L, G, B]
-    B = per_sample.shape[-1]
-    counts_f = (per_sample == P_F).sum(-1)
-    counts_o = (per_sample == P_O).sum(-1)
-    C_f = int(counts_f.max())
-    C_o = int(counts_o.max())
-    C = pad_to or (C_f + C_o)
-    idx = np.zeros((L, G, C), np.int32)
-    bwd = np.zeros((L, G, C), np.float32)
-    val = np.zeros((L, G, C), np.float32)
-    for l in range(L):
-        for g in range(G):
-            f = np.nonzero(per_sample[l, g] == P_F)[0]
-            o = np.nonzero(per_sample[l, g] == P_O)[0]
-            take = np.concatenate([f, o])[:C]
-            idx[l, g, :len(take)] = take
-            bwd[l, g, :len(f)] = 1.0
-            val[l, g, :len(take)] = 1.0
-    return idx, bwd, val, C_f
 
 
 def op_counts(sched: Schedule) -> dict:

@@ -60,12 +60,11 @@ def lower_pair(cfg: ModelConfig, shape: InputShape, mesh,
                seq_parallel: bool = True, fsdp: bool = True,
                compute_dtype: str = "bfloat16", pad_heads: bool = False,
                attn_q_chunk: int = 0, max_pad_overhead: float = 1.5,
-               d2ft_packed=None, capacity_factor: float = 0.0):
+               capacity_factor: float = 0.0):
     """Build (lower-ready jit, args, policy) for one (arch, shape, mesh).
 
-    pad_heads / attn_q_chunk / d2ft_packed are the §Perf hillclimb levers;
-    d2ft_packed = D2FTConfig lowers the packed D2FT train step instead of
-    standard full fine-tuning.
+    pad_heads / attn_q_chunk / capacity_factor are the memory and
+    collective levers a dry run can try on a published config.
     """
     cfg = cfg.replace(param_dtype=compute_dtype, compute_dtype=compute_dtype)
     if capacity_factor and cfg.moe is not None:
@@ -79,8 +78,8 @@ def lower_pair(cfg: ModelConfig, shape: InputShape, mesh,
         # Materialize the padding at "checkpoint load": zero-padded wq/wo
         # head blocks keep the function exact while letting every attention
         # weight shard head-aligned on `model` (trace-time padding leaves
-        # the weights replicated and resharded per layer — measured worse,
-        # EXPERIMENTS.md §Perf). The dry-run lowers the padded config.
+        # the weights replicated and resharded per layer). The dry-run
+        # lowers the padded config.
         Hp, Hkvp = policy.head_padding()
         cfg = cfg.replace(n_heads=Hp, n_kv_heads=Hkvp,
                           head_dim=cfg.resolved_head_dim)
@@ -88,17 +87,6 @@ def lower_pair(cfg: ModelConfig, shape: InputShape, mesh,
                                 fsdp=fsdp, attn_q_chunk=attn_q_chunk)
     params = S.param_shapes(cfg)
     pspecs = policy.param_specs(params)
-
-    if shape.kind == "train" and d2ft_packed is not None:
-        step, opt = S.make_packed_train_step_fn(cfg, policy, shape,
-                                                d2ft_packed)
-        opt_state = jax.eval_shape(opt.init, params)
-        ospecs = _opt_state_shardings(policy, pspecs)
-        batch = S.batch_specs(cfg, shape)
-        bspecs = {k: policy.batch_spec(v.shape) for k, v in batch.items()}
-        jitted = jax.jit(step, in_shardings=(pspecs, ospecs, bspecs),
-                         out_shardings=(pspecs, ospecs, None))
-        return jitted, (params, opt_state, batch), policy
 
     if shape.kind == "train":
         step, opt = S.make_train_step_fn(cfg, policy)
@@ -230,14 +218,8 @@ def main():
     ap.add_argument("--pad-heads", action="store_true")
     ap.add_argument("--max-pad-overhead", type=float, default=1.5)
     ap.add_argument("--attn-q-chunk", type=int, default=0)
-    ap.add_argument("--d2ft-packed", action="store_true",
-                    help="lower the packed D2FT train step (3pf/1po of 5)")
-    ap.add_argument("--n-pf", type=int, default=3)
-    ap.add_argument("--n-po", type=int, default=1)
-    ap.add_argument("--n-mb", type=int, default=4)
     ap.add_argument("--capacity-factor", type=float, default=0.0,
                     help="override MoE capacity factor (hillclimb lever)")
-    ap.add_argument("--head-groups", type=int, default=0)
     ap.add_argument("--distributed-step", action="store_true",
                     help="lower the shard_map distributed D2FT step on a "
                          "data mesh carved from the host devices and report "
@@ -285,11 +267,6 @@ def main():
         lever_kw["attn_q_chunk"] = args.attn_q_chunk
     if args.capacity_factor:
         lever_kw["capacity_factor"] = args.capacity_factor
-    if args.d2ft_packed:
-        from repro.configs.base import D2FTConfig
-        lever_kw["d2ft_packed"] = D2FTConfig(
-            n_microbatches=args.n_mb, n_pf=args.n_pf, n_po=args.n_po,
-            head_groups=args.head_groups)
     failures = []
     for arch, shape in pairs:
         if (arch, shape) in SKIPS:

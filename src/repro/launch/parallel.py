@@ -16,12 +16,10 @@ distributed D2FT path speaks:
 
 ``ParallelConfig`` is the frozen bundle of mesh spec + execution options
 that ``train.loop.make_distributed_train_step`` / ``finetune_distributed``
-and ``launch/train.py`` accept in place of the historical pile of loose
-kwargs (``sync_mode=`` / ``streamed=`` / ``guard=`` / ...). All
-cross-option validation lives in ``ParallelConfig.validate()`` — run at
-construction — instead of being scattered across call sites. The old
-kwargs still work for one release through a ``DeprecationWarning`` shim
-in ``train.loop``.
+and ``launch/train.py`` take as their one way to set the distributed
+step's options. All cross-option validation lives in
+``ParallelConfig.validate()`` — run at construction — instead of being
+scattered across call sites.
 """
 from __future__ import annotations
 
@@ -141,10 +139,9 @@ class MeshSpec:
 class ParallelConfig:
     """Frozen execution config for the distributed D2FT train step.
 
-    Replaces the loose ``sync_mode= / streamed= / opt_chunk= / guard= /
-    use_kernel=`` kwargs of ``make_distributed_train_step`` /
-    ``finetune_distributed`` (still accepted through a DeprecationWarning
-    shim). All cross-option validation happens at construction.
+    The only way to set ``make_distributed_train_step`` /
+    ``finetune_distributed``'s options. All cross-option validation
+    happens at construction.
 
     microbatches: pipeline microbatches per data shard (required > 0 when
     ``mesh.stage > 1``, must be 0 otherwise).

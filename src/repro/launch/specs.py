@@ -61,41 +61,6 @@ def make_train_step_fn(cfg: ModelConfig, policy, lr: float = 1e-4):
     return train_step, opt
 
 
-def make_packed_train_step_fn(cfg: ModelConfig, policy, shape: InputShape,
-                              d2ft):
-    """Packed D2FT fine-tuning step (the paper's technique at production
-    scale): every head-group processes only its knapsack-selected
-    micro-batches. The schedule is planned host-side (here: uniform scores
-    -> balanced table, the structure the knapsack guarantees) and baked in
-    as static gather indices."""
-    import numpy as np
-    from repro.core.d2ft import (mb_packed_indices, packed_forward_mb,
-                                 plan_schedule)
-    from repro.models.transformer import fused_xent
-
-    G = d2ft.head_groups or policy.model_size
-    rng = np.random.default_rng(0)
-    K, N = cfg.n_layers * G, d2ft.n_microbatches
-    bw = np.repeat(rng.random((K, 1)) + 0.1, N, 1)
-    fw = rng.random((K, N)) + 0.1
-    sched = plan_schedule(d2ft, bw, fw, cfg.n_layers, G)
-    idx, bwd, val = mb_packed_indices(sched, N)
-    arrays = (jnp.asarray(idx), jnp.asarray(bwd), jnp.asarray(val))
-    opt = adamw(1e-4)
-
-    def train_step(params, opt_state, batch):
-        def loss_of(p):
-            logits, _ = packed_forward_mb(p, cfg, batch["tokens"], arrays,
-                                          N, policy=policy, remat=True)
-            return fused_xent(logits, batch["labels"])
-        loss, grads = jax.value_and_grad(loss_of)(params)
-        grads, gnorm = clip_by_global_norm(grads, 1.0)
-        params, opt_state = opt.update(grads, opt_state, params)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm}
-
-    return train_step, opt
-
-
 def make_prefill_fn(cfg: ModelConfig, policy):
     def prefill_step(params, batch):
         logits, _ = forward(params, cfg, tokens=batch.get("tokens"),

@@ -41,13 +41,11 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--optimizer", choices=("sgd", "adamw"), default="adamw")
     ap.add_argument("--d2ft", action="store_true")
-    ap.add_argument("--packed", action="store_true",
-                    help="use the packed D2FT execution path")
     ap.add_argument("--distributed", action="store_true",
                     help="data-parallel D2FT over the mesh's data axis: "
                          "multiple-knapsack device assignment + shard_map "
                          "gated step with the schedule-masked grad psum "
-                         "(requires --d2ft, excludes --packed)")
+                         "(requires --d2ft)")
     ap.add_argument("--kernel", action="store_true",
                     help="route attention through the compacted Pallas "
                          "gated kernel path (single-device or per-shard "
@@ -129,9 +127,6 @@ def main():
     if cfg.frontend != "none":
         raise SystemExit("text-training launcher; audio/vlm archs use the "
                          "example drivers (examples/)")
-    if args.packed and args.kernel:
-        raise SystemExit("--packed and --kernel are exclusive (the packed "
-                         "gather path bypasses the gated attention kernel)")
     if not args.distributed and (args.sync_mode != "masked"
                                  or args.refresh_every is not None):
         raise SystemExit("--sync-mode/--refresh-every only apply to the "
@@ -161,9 +156,6 @@ def main():
     if args.distributed:
         if d2 is None:
             raise SystemExit("--distributed requires --d2ft")
-        if args.packed:
-            raise SystemExit("--distributed and --packed are exclusive "
-                             "(the shard_map step drives the gated paths)")
         if spec is None:
             spec = MeshSpec(data=int(mesh.shape["data"]))
         pconf = ParallelConfig(
@@ -247,7 +239,6 @@ def main():
     else:
         params, opt_state, log = finetune(params, cfg, d2, opt, batches,
                                           steps=args.steps,
-                                          packed=args.packed,
                                           use_kernel=args.kernel)
     dt = time.time() - t0
     print(f"{args.steps} steps in {dt:.1f}s — loss "

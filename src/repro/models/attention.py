@@ -1,8 +1,8 @@
 """GQA attention: global/causal, bidirectional, sliding-window (block-local
 subquadratic), plus single-token decode against full-length or ring KV caches.
 
-D2FT head-group gating happens in transformer.py at the block level; this
-module optionally accepts per-(sample, head) multipliers for the packed path.
+D2FT head-group gating happens in transformer.py at the block level, on
+the output projection of this module's attention.
 """
 from __future__ import annotations
 
@@ -52,7 +52,7 @@ def _repeat_kv(k, Hq: int):
     """[B,S,Hkv,hd] -> [B,S,Hq,hd]. Keeping the einsum 4-D (instead of a
     5-D [B,S,Hkv,G,hd] grouping) lets GSPMD shard the head dim cleanly —
     the grouped form forced involuntary full rematerialization when
-    Hkv % model_axis != 0 (see EXPERIMENTS.md §Perf)."""
+    Hkv % model_axis != 0."""
     Hkv = k.shape[2]
     if Hkv == Hq:
         return k
@@ -121,7 +121,7 @@ def pad_attention_params(p, n_heads, n_kv, head_dim, Hp, Hkvp):
     to Hp. Exact — padded heads' wo rows are zero so their contribution
     vanishes; real head h keeps kv head h // (Hp/Hkvp) (ratio preserved).
     Enables head-TP sharding when the true head count is not divisible by
-    the model axis (EXPERIMENTS.md §Perf)."""
+    the model axis (``ShardingPolicy.pad_heads``)."""
     dq = (Hp - n_heads) * head_dim
     dkv = (Hkvp - n_kv) * head_dim
     out = dict(p)
@@ -169,14 +169,11 @@ def apply_attention(params, x, *, n_heads: int, n_kv_heads: int, head_dim: int,
                     causal: bool, window: int = 0, rope: bool = True,
                     rope_theta: float = 10_000.0,
                     positions: Optional[jnp.ndarray] = None,
-                    head_scale: Optional[jnp.ndarray] = None,
                     return_kv: bool = False):
     """Returns attention block output [B,S,d_model].
 
     window > 0 selects sliding-window attention; when S > 2*window a
     block-local (chunked) subquadratic implementation is used.
-    head_scale: optional [B, n_heads] multiplier applied to per-head outputs
-    before the output projection (D2FT packed-path gating hook).
     return_kv: additionally return the post-rope (k, v) [B,S,n_kv,hd] —
     the serving prefill captures them into the KV cache (forward() + cache
     dump instead of a sequential decode loop, see serving/decode.py).
@@ -200,8 +197,6 @@ def apply_attention(params, x, *, n_heads: int, n_kv_heads: int, head_dim: int,
             mask = jnp.ones((1, 1, S, S), bool)
         out = _sdpa(q, k, v, mask)
 
-    if head_scale is not None:
-        out = out * head_scale[:, None, :, None].astype(out.dtype)
     out = out.reshape(B, S, n_heads * head_dim) @ params["wo"]
     if return_kv:
         return out, k, v

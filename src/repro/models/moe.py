@@ -163,9 +163,8 @@ def apply_moe_ep(params, x, cfg: MoEConfig, act: str, mesh, batch_axes,
     """Sharded MoE via shard_map with explicit collectives.
 
     The global sort-based dispatch above is correct single-device JAX, but
-    its data-dependent gather/scatter defeats GSPMD (the compiler replicates
-    the token buffers — see EXPERIMENTS.md §Perf). Production dispatch is
-    explicit. Two modes:
+    its data-dependent gather/scatter defeats GSPMD: the compiler
+    replicates the token buffers. Production dispatch is explicit. Two modes:
 
     * expert_parallel (E % model_size == 0): each device routes its LOCAL
       tokens, builds a per-expert send buffer, all-to-alls over ``model``

@@ -104,7 +104,6 @@ def _gated_scan_ref(log_a, b, g_f, g_b):
 
 
 def apply_rglru(params, x, cfg: RGLRUConfig,
-                head_scale: Optional[jnp.ndarray] = None,
                 return_state: bool = False,
                 gates: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
                 use_kernel: bool = False,
@@ -140,11 +139,6 @@ def apply_rglru(params, x, cfg: RGLRUConfig,
         a, b = _rglru_gates(params, u)
         h32 = _assoc_scan(a, b)                         # [B,S,W] f32
     h = h32.astype(x.dtype)
-    if head_scale is not None:
-        H = head_scale.shape[-1]
-        W = h.shape[-1]
-        hs = jnp.repeat(head_scale, W // H, axis=-1)    # block-diagonal groups
-        h = h * hs[:, None, :].astype(h.dtype)
     y = h * gate
     out = y @ params["w_out"]
     if return_state:

@@ -140,7 +140,6 @@ def ssd_chunked(xh, dt, A, Bm, Cm, chunk: int, return_final_state=False):
 
 
 def apply_ssd(params, x, d_model: int, cfg: SSMConfig,
-              head_scale: Optional[jnp.ndarray] = None,
               return_state: bool = False,
               gates: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
               use_kernel: bool = False,
@@ -192,8 +191,6 @@ def apply_ssd(params, x, d_model: int, cfg: SSMConfig,
             gb = g_b[:, None, :, None].astype(y.dtype)
             y = gf * (gb * y + (1.0 - gb) * jax.lax.stop_gradient(y))
     y = y + params["D"][None, None, :, None] * xin
-    if head_scale is not None:
-        y = y * head_scale[:, None, :, None].astype(y.dtype)
     y = y.reshape(*x.shape[:2], d_inner)
     y = _gated_rmsnorm(y, z, params["norm_scale"])
     out = y @ params["w_out"]

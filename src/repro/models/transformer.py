@@ -13,8 +13,9 @@ Design notes
   which implements p_f (1,1), p_o (1,0), p_s (0,·) exactly: p_o keeps the
   forward value but kills every gradient (params *and* activations) through
   the subnet for that sample; p_s removes the contribution so only the
-  residual route remains. This is the masked reference path; the packed
-  deployment path lives in core/d2ft.py.
+  residual route remains. This is the masked reference path; the kernel
+  path (``use_kernel=True``) computes the same function with gated Pallas
+  kernels that skip the gated slices.
 * Attention and dense-MLP groups are contiguous input columns of the output
   projection (``wo``, ``w_down``), so the mix above is one masked matmul,
   ``gated_linear``: the forward scales each group's columns by g_f, the
@@ -797,7 +798,7 @@ def fused_xent(logits, labels):
     gathered first; logsumexp reduces with fused elementwise ops) and the
     backward emits the cotangent (softmax - onehot) directly in the logits
     dtype — the naive log_softmax formulation kept several f32 logits-sized
-    temps alive, dominating HBM in the dry-run (EXPERIMENTS.md §Perf).
+    temps alive, which dominated HBM in the dry run.
     """
     loss, _ = _xent_fwd_impl(logits, labels)
     return loss

@@ -41,7 +41,7 @@ class ShardingPolicy:
     cfg: ModelConfig
     seq_parallel: bool = True
     fsdp: bool = True
-    # --- beyond-paper perf levers (EXPERIMENTS.md §Perf) ---
+    # --- beyond-paper perf levers (launch/dryrun.py's lever flags) ---
     # pad attention heads with zero heads up to the next mesh-divisible
     # count so head-TP applies where context-parallelism would otherwise be
     # forced (exact: zero wo rows null the padded heads' contribution).
@@ -177,7 +177,7 @@ class ShardingPolicy:
         elif name == "table":
             # embedding [V, D]: vocab over model AND data (2-axis shard);
             # keeping D replicated avoids batch-gathering reshards in the
-            # embedding-scatter backward (see EXPERIMENTS.md §Perf).
+            # embedding-scatter backward.
             if _divisible(body[0], ms * self.mesh.shape.get("data", 1)):
                 spec = (("model", "data"), None)
             elif _divisible(body[0], ms):
@@ -254,31 +254,11 @@ class ShardingPolicy:
             return self._c(q, bspec, "model", None, None)
         return self._c(q, bspec, None, None, None)
 
-    def packed_residual(self, x):
-        """[M, B', S, D] (micro-batch axis leading): batch over data,
-        sequence over model; M stays unsharded (selection axis)."""
-        if x.ndim != 4:
-            return x
-        b_ok = _divisible(x.shape[1], self.data_size)
-        s_ok = self.seq_parallel and _divisible(x.shape[2], self.model_size)
-        return self._c(x, None, self.batch_axes if b_ok else None,
-                       "model" if s_ok else None, None)
-
-    def packed_groups(self, hg):
-        """[G, C*B', S, D] gathered per-group inputs: head-groups over
-        model (the D2FT subnet axis), batch within groups over data."""
-        if hg.ndim != 4:
-            return hg
-        g_ok = _divisible(hg.shape[0], self.model_size)
-        b_ok = _divisible(hg.shape[1], self.data_size)
-        return self._c(hg, "model" if g_ok else None,
-                       self.batch_axes if b_ok else None, None, None)
-
     def kv(self, k):
         """[B, S, Hkv, hd]. In context-parallel fallback mode the K/V for
         attention must be sequence-replicated — constraining them here (post
         projection + RoPE) makes GSPMD gather the small [B,S,Hkv,hd] heads
-        instead of the full residual stream (see EXPERIMENTS.md §Perf)."""
+        instead of the full residual stream."""
         if k.ndim != 4:
             return k
         b_ok = _divisible(k.shape[0], self.data_size)

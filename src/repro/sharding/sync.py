@@ -153,9 +153,10 @@ class SyncSpec:
 _ALL = SyncSpec("all")
 _NONE = SyncSpec("none")
 
-# Leaf name -> axis holding the G contiguous head-group blocks. Matches the
-# column groups of the masked path's gated projections (models/transformer.py
-# _gated_proj of wo / w_down) and the packed path's _slice_cols/_slice_rows.
+# Leaf name -> axis holding the G contiguous head-group blocks: the groups
+# of contiguous heads (wq/wk/wv columns, wo rows) and of MLP width
+# (w_up/w_gate columns, w_down rows) whose outputs the gated projections
+# (models/transformer.py _gated_proj of wo / w_down) gate.
 _Q_AXIS = {"wq": 1, "bq": 0, "wo": 0}
 _KV_AXIS = {"wk": 1, "bk": 0, "wv": 1, "bv": 0}
 _FFN_AXIS = {"w_up": 1, "w_gate": 1, "w_down": 0}

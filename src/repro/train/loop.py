@@ -1,11 +1,11 @@
 """Training loops: standard fine-tuning and the D2FT-orchestrated variants.
 
-`finetune` drives either path on LLM backbones; `finetune_vit` mirrors the
-paper's ViT experiments and is what the paper-table benchmarks call.
+`finetune` drives the masked or the kernel path on LLM backbones;
+`finetune_vit` mirrors the paper's ViT experiments and is what the
+paper-table benchmarks call.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Optional
 
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.configs.base import D2FTConfig, ModelConfig
 from repro.core import d2ft as d2ft_mod
 from repro.core.schedule import (Schedule, gates_from_schedule,
-                                 live_slice_bounds, packed_indices)
+                                 live_slice_bounds)
 from repro.core.scores import compute_scores, transformer_blocks, vit_blocks
 from repro.data.synthetic import microbatch_assignment
 from repro.models.transformer import lm_loss
@@ -56,15 +56,13 @@ def _read_back(log: TrainLog, metrics):
 
 # ------------------------------------------------------------------ LLM path
 def make_train_step(cfg: ModelConfig, opt: Optimizer, *, use_gates: bool,
-                    packed: bool = False, policy=None, remat: bool = False,
+                    policy=None, remat: bool = False,
                     clip: float = 1.0, use_kernel: bool = False,
                     live_bounds=None):
     """Returns jit-able step(params, opt_state, batch[, sched_args]).
 
     use_kernel: run attention through the Pallas gated flash kernel whose
     custom-VJP backward skips p_o / p_s (sample, head-group) slices.
-    Ignored on the packed path (packed gathers subnet micro-batches
-    instead of gating).
     live_bounds: static (live_fwd, live_bwd) (sample, group) slice bounds
     from ``core.schedule.live_slice_bounds`` — enables the kernel path's
     compaction dispatch. Baked into the jitted step: re-make (and re-jit)
@@ -72,14 +70,6 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, *, use_gates: bool,
     """
 
     def loss_of(params, batch, sched_args):
-        if packed:
-            logits, aux = d2ft_mod.packed_forward(
-                params, cfg, batch["tokens"], sched_args, policy=policy,
-                remat=remat)
-            labels = batch["labels"]
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-            ll = jnp.take_along_axis(logp, labels[..., None], -1)[..., 0]
-            return -jnp.mean(ll), {"ce": -jnp.mean(ll)}
         gates = sched_args if use_gates else None
         return lm_loss(params, cfg, batch.get("tokens"), batch["labels"],
                        features=batch.get("features"), gates=gates,
@@ -113,7 +103,7 @@ def plan_from_scores(cfg: ModelConfig, d2: D2FTConfig, params,
 
 def finetune(params, cfg: ModelConfig, d2: Optional[D2FTConfig],
              opt: Optimizer, batches: Iterable, *, steps: int,
-             packed: bool = False, use_kernel: bool = False,
+             use_kernel: bool = False,
              log: Optional[TrainLog] = None) -> tuple:
     """Fine-tune; if d2 is given, schedule ops per batch via D2FT."""
     log = log or TrainLog()
@@ -126,8 +116,8 @@ def finetune(params, cfg: ModelConfig, d2: Optional[D2FTConfig],
     def get_step(bounds):
         if bounds not in step_fns:
             step_fns[bounds] = jax.jit(make_train_step(
-                cfg, opt, use_gates=d2 is not None, packed=packed,
-                use_kernel=use_kernel, live_bounds=bounds))
+                cfg, opt, use_gates=d2 is not None, use_kernel=use_kernel,
+                live_bounds=bounds))
             log.counters["step_builds"] += 1
         return step_fns[bounds]
 
@@ -149,17 +139,10 @@ def finetune(params, cfg: ModelConfig, d2: Optional[D2FTConfig],
             if d2 is not None:
                 B = batch["labels"].shape[0]
                 mb_of = microbatch_assignment(B, d2.n_microbatches)
-                if packed:
-                    idx, bwd, val, _ = packed_indices(sched, mb_of)
-                else:
-                    sched_args = gates_from_schedule(sched, mb_of)
-                    if use_kernel:
-                        bounds = live_slice_bounds(sched, mb_of)
+                sched_args = gates_from_schedule(sched, mb_of)
+                if use_kernel:
+                    bounds = live_slice_bounds(sched, mb_of)
             step_fn = get_step(bounds)
-        if d2 is not None and packed:
-            with span(log, "h2d"):
-                sched_args = (jnp.asarray(idx), jnp.asarray(bwd),
-                              jnp.asarray(val))
         with span(log, "dispatch"):
             params, opt_state, metrics = step_fn(params, opt_state, batch,
                                                  sched_args)
@@ -228,42 +211,14 @@ def _tree_where(cond, a, b):
     return jax.tree.map(lambda x, y: jnp.where(cond, x, y), a, b)
 
 
-_UNSET = object()     # sentinel: a deprecated loose kwarg was not passed
-
-
-def _resolve_parallel(parallel, mesh, given: dict, *, where: str):
-    """Deprecation shim: fold the historical loose kwargs into a
-    ``launch.parallel.ParallelConfig``.
-
-    ``given`` holds only the deprecated kwargs the caller actually passed
-    (callers filter out ``_UNSET``). Exactly one of ``parallel`` / loose
-    kwargs may be used. Legacy validation errors keep their types and
-    messages — ``ParallelConfig.validate()`` raises the same
-    AssertionError for streamed/opt_chunk off zero3 and the same
-    ``"streamed ZeRO-3 cannot guard"`` ValueError.
-
-    Returns (config, data_axis_name): the axis name stays a separate
-    return so the legacy ``axis_name=`` kwarg keeps working on meshes
-    whose data axis is not literally called "data"."""
+def _mesh_parallel(mesh):
+    """The ``ParallelConfig`` of a caller that passes none: the mesh's own
+    (data, stage, tensor) axis sizes, every option at its default."""
     from repro.launch.parallel import MeshSpec, ParallelConfig
 
-    if parallel is not None:
-        if given:
-            raise TypeError(
-                f"{where}: pass either parallel=ParallelConfig(...) or the "
-                f"deprecated kwargs {sorted(given)}, not both")
-        return parallel, parallel.data_axis
-    if given:
-        warnings.warn(
-            f"{where}({', '.join(sorted(given))}=...) is deprecated; pass "
-            "parallel=repro.launch.parallel.ParallelConfig(...) instead",
-            DeprecationWarning, stacklevel=3)
-    axis = given.pop("axis_name", "data")
     shape = dict(mesh.shape) if mesh is not None else {}
-    spec = MeshSpec(data=int(shape.get(axis, 1)),
-                    stage=int(shape.get("stage", 1)),
-                    tensor=int(shape.get("tensor", 1)))
-    return ParallelConfig(mesh=spec, **given), axis
+    return ParallelConfig(mesh=MeshSpec(
+        *(int(shape.get(name, 1)) for name in MeshSpec().axis_names)))
 
 
 def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
@@ -272,18 +227,13 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
                                 params=None, n_replicas=None,
                                 residency_recorder=None,
                                 stage_assignment=None,
-                                pipeline_recorder=None,
-                                use_kernel=_UNSET, axis_name=_UNSET,
-                                sync_mode=_UNSET, guard=_UNSET,
-                                streamed=_UNSET, opt_chunk=_UNSET):
+                                pipeline_recorder=None):
     """shard_map multi-axis gated train step (paper's *distributed* D2FT).
 
     ``parallel`` (a ``launch.parallel.ParallelConfig``) is the one knob
     bundle: mesh spec (data/stage/tensor sizes), sync_mode, streamed,
-    opt_chunk, guard, use_kernel and pipeline microbatches. The loose
-    ``sync_mode=``/``guard=``/... kwargs below the sentinel line are the
-    deprecated pre-ParallelConfig spelling — still honored, with a
-    DeprecationWarning — and may not be mixed with ``parallel=``.
+    opt_chunk, guard, use_kernel and pipeline microbatches. ``None`` takes
+    the mesh's axis sizes with every option at its default.
 
     Axes beyond data compose around the same bodies:
 
@@ -388,12 +338,8 @@ def make_distributed_train_step(cfg: ModelConfig, opt: Optimizer, mesh,
                                      zero_param_specs, zero_shard_params)
     from repro.train.pipeline import pipeline_loss
 
-    given = {k: v for k, v in dict(
-        use_kernel=use_kernel, axis_name=axis_name, sync_mode=sync_mode,
-        guard=guard, streamed=streamed, opt_chunk=opt_chunk).items()
-        if v is not _UNSET}
-    parallel, axis_name = _resolve_parallel(
-        parallel, mesh, given, where="make_distributed_train_step")
+    parallel = parallel or _mesh_parallel(mesh)
+    axis_name = parallel.data_axis
     sync_mode, guard = parallel.sync_mode, parallel.guard
     streamed, opt_chunk = parallel.streamed, parallel.opt_chunk
     use_kernel = parallel.use_kernel
@@ -675,9 +621,7 @@ def finetune_distributed(params, cfg: ModelConfig, d2: D2FTConfig,
                          opt: Optimizer, batches: Iterable, *, steps: int,
                          mesh, parallel=None, clip: float = 1.0,
                          refresh_every: Optional[int] = None,
-                         log: Optional[TrainLog] = None,
-                         use_kernel=_UNSET, sync_mode=_UNSET,
-                         streamed=_UNSET, opt_chunk=_UNSET) -> tuple:
+                         log: Optional[TrainLog] = None) -> tuple:
     """Distributed D2FT fine-tuning: plan, balance micro-batches over the
     mesh's data axis with the multiple-knapsack assigner, then drive the
     shard_map gated step. ``refresh_every=k`` re-plans the schedule every k
@@ -688,9 +632,8 @@ def finetune_distributed(params, cfg: ModelConfig, d2: D2FTConfig,
     is appended to ``log.extras["refreshes"]``.
 
     ``parallel`` (``launch.parallel.ParallelConfig``) selects sync mode and
-    extra mesh axes; the loose ``sync_mode=``/``streamed=``/... kwargs are
-    the deprecated spelling (DeprecationWarning, may not be mixed with
-    ``parallel=``). With ``parallel.mesh.stage > 1`` every refresh also
+    extra mesh axes; ``None`` takes the mesh's axis sizes with every
+    option at its default. With ``parallel.mesh.stage > 1`` every refresh also
     re-runs the schedule-aware stage assigner
     (``core.assignment.plan_stage_assignment``) — pipeline stages are
     packed by the NEW schedule's live FLOP cost and the jitted step is
@@ -724,11 +667,7 @@ def finetune_distributed(params, cfg: ModelConfig, d2: D2FTConfig,
                                      zero_reshard)
     from repro.train.pipeline import analytic_bubble_fraction
 
-    given = {k: v for k, v in dict(
-        use_kernel=use_kernel, sync_mode=sync_mode, streamed=streamed,
-        opt_chunk=opt_chunk).items() if v is not _UNSET}
-    parallel, _ = _resolve_parallel(parallel, mesh, given,
-                                    where="finetune_distributed")
+    parallel = parallel or _mesh_parallel(mesh)
     sync_mode, use_kernel = parallel.sync_mode, parallel.use_kernel
     S = parallel.mesh.stage
     parallel.validate_model(cfg)

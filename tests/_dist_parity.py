@@ -26,6 +26,7 @@ from repro.data.synthetic import lm_batches, microbatch_assignment
 from repro.launch.diststep import measure_distributed_step
 from repro.launch.hlo import collective_bytes
 from repro.launch.mesh import make_data_mesh
+from repro.launch.parallel import MeshSpec, ParallelConfig
 from repro.models.transformer import init_model
 from repro.optim.optimizers import sgd
 from repro.train.loop import make_distributed_train_step, make_train_step
@@ -57,6 +58,12 @@ from repro.sharding.sync import (grad_sync_plan, sync_byte_report,
 params = init_model(jax.random.PRNGKey(0), cfg)
 opt = sgd(1e-2)       # linear in grads: parity is pure FP reordering noise
 mesh = make_data_mesh(K)
+
+
+def pc(**options):
+    """The distributed step's options on the K-device data mesh."""
+    return ParallelConfig(mesh=MeshSpec(data=K), **options)
+
 batch = next(lm_batches(0, cfg.vocab_size, B, S, 1))
 mb_of = microbatch_assignment(B, N)
 
@@ -85,7 +92,8 @@ assert abs(float(m_d["loss"]) - float(m_r["loss"])) <= 1e-5
 bounds = distributed_live_bounds(sched, mb_of, assignment)
 gbounds = live_slice_bounds(sched, mb_of)
 assert bounds[0] <= gbounds[0] and bounds[1] <= gbounds[1], (bounds, gbounds)
-kstep = make_distributed_train_step(cfg, opt, mesh, plan, use_kernel=True,
+kstep = make_distributed_train_step(cfg, opt, mesh, plan,
+                                    parallel=pc(use_kernel=True),
                                     live_bounds=bounds)
 kref = jax.jit(make_train_step(cfg, opt, use_gates=True, use_kernel=True,
                                live_bounds=gbounds))
@@ -99,7 +107,8 @@ assert kdiff <= 1e-6, f"kernel-path params diverged: {kdiff}"
 # reference as the masked path (p_r / s_r above)
 zplan = grad_sync_plan(params, cfg, sched, mode="zero", n_shards=K,
                        elide_gather=opt.elidable)
-zstep = make_distributed_train_step(cfg, opt, mesh, zplan, sync_mode="zero",
+zstep = make_distributed_train_step(cfg, opt, mesh, zplan,
+                                    parallel=pc(sync_mode="zero"),
                                     params=params)
 p_z, s_z = params, opt.init(params)
 for _ in range(3):
@@ -132,7 +141,8 @@ for name, s, b, g in [("paper_mix", mix_sched, mix_batch, mix_gates),
     z3plan = grad_sync_plan(params, cfg, s, mode="zero3", n_shards=K)
     z3rep = zero3_param_byte_report(z3plan, params, K)
     z3step = make_distributed_train_step(cfg, opt, mesh, z3plan,
-                                         sync_mode="zero3", params=params)
+                                         parallel=pc(sync_mode="zero3"),
+                                         params=params)
     rstep = jax.jit(make_train_step(cfg, opt, use_gates=True))
     p_3, s_3 = zero_reshard(params, None, z3plan), opt.init(params)
     p_m, s_m = params, opt.init(params)
@@ -163,11 +173,13 @@ from repro.sharding.sync import ResidencyRecorder, check_zero3_residency
 z3plan = grad_sync_plan(params, cfg, mix_sched, mode="zero3", n_shards=K)
 rec3 = ResidencyRecorder()
 sstep = make_distributed_train_step(cfg, opt, mesh, z3plan,
-                                    sync_mode="zero3", params=params,
-                                    streamed=True, opt_chunk=1024,
-                                    residency_recorder=rec3)
+                                    parallel=pc(sync_mode="zero3",
+                                                streamed=True,
+                                                opt_chunk=1024),
+                                    params=params, residency_recorder=rec3)
 ustep = make_distributed_train_step(cfg, opt, mesh, z3plan,
-                                    sync_mode="zero3", params=params)
+                                    parallel=pc(sync_mode="zero3"),
+                                    params=params)
 mref = jax.jit(make_train_step(cfg, opt, use_gates=True))
 p_s3, s_s3 = zero_reshard(params, None, z3plan), opt.init(params)
 p_u3, s_u3 = zero_reshard(params, None, z3plan), opt.init(params)

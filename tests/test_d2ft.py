@@ -1,5 +1,5 @@
-"""D2FT operation semantics: masked path gating, packed == masked, p_o
-kills subnet gradients, p_s kills subnet contribution; scores."""
+"""D2FT operation semantics: masked path gating, p_o kills subnet
+gradients, p_s kills subnet contribution; scores."""
 import functools
 
 import jax
@@ -7,9 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import D2FTConfig, ModelConfig
-from repro.core.d2ft import packed_forward, plan_schedule
-from repro.core.schedule import (P_F, P_O, P_S, Schedule,
-                                 gates_from_schedule, packed_indices)
+from repro.core.d2ft import plan_schedule
+from repro.core.schedule import P_F, P_O, P_S, Schedule
 from repro.core.scores import (compute_scores, transformer_blocks,
                                weight_magnitude)
 from repro.models.transformer import forward, init_model, lm_loss
@@ -38,32 +37,6 @@ def test_all_pf_gates_equal_ungated():
     l0, _ = forward(params, CFG, tokens=toks)
     l1, _ = forward(params, CFG, tokens=toks, gates=(ones, ones))
     np.testing.assert_allclose(np.asarray(l0), np.asarray(l1), atol=1e-5)
-
-
-def test_masked_equals_packed_forward_and_grad():
-    params, toks = _setup()
-    sched = _schedule()
-    mb_of = np.repeat(np.arange(5), 2)
-    gates = gates_from_schedule(sched, mb_of)
-    idx, bwd, val, _ = packed_indices(sched, mb_of)
-    sched_arrays = tuple(map(jnp.asarray, (idx, bwd, val)))
-
-    lm, _ = forward(params, CFG, tokens=toks, gates=gates)
-    lp, _ = packed_forward(params, CFG, toks, sched_arrays)
-    np.testing.assert_allclose(np.asarray(lm), np.asarray(lp), atol=2e-5,
-                               rtol=1e-4)
-
-    def loss_masked(p):
-        return jnp.mean(forward(p, CFG, tokens=toks, gates=gates)[0] ** 2)
-
-    def loss_packed(p):
-        return jnp.mean(packed_forward(p, CFG, toks, sched_arrays)[0] ** 2)
-
-    gm = jax.grad(loss_masked)(params)
-    gp = jax.grad(loss_packed)(params)
-    for a, b in zip(jax.tree.leaves(gm), jax.tree.leaves(gp)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
-                                   rtol=1e-3)
 
 
 def test_po_blocks_param_grads_and_ps_blocks_contribution():
@@ -121,29 +94,3 @@ def test_heterogeneous_capacities():
     sched = plan_schedule(d2, bw, fw, L, G, cap_pf=cap_pf, cap_po=0.8)
     per_dev_pf = (sched.table == P_F).sum(1)
     assert (per_dev_pf[:4] == 3).all() and (per_dev_pf[4:] == 2).all()
-
-
-def test_mb_packed_equals_masked():
-    """Micro-batch-axis packed path (deployment form, p_f/p_o split with
-    backward DCE) == masked reference, values and gradients."""
-    from repro.core.d2ft import mb_packed_indices, packed_forward_mb
-    params, toks = _setup(B=12)
-    M = 4
-    sched = _schedule(N=M, n_pf=2, n_po=1)
-    mb_of = np.repeat(np.arange(M), 12 // M)
-    gates = gates_from_schedule(sched, mb_of)
-    idx, bwd, val = mb_packed_indices(sched, M)
-    arrays = tuple(map(jnp.asarray, (idx, bwd, val)))
-
-    lm, _ = forward(params, CFG, tokens=toks, gates=gates)
-    lp, _ = packed_forward_mb(params, CFG, toks, arrays, M)
-    np.testing.assert_allclose(np.asarray(lm), np.asarray(lp), atol=2e-5,
-                               rtol=1e-4)
-
-    gm = jax.grad(lambda p: jnp.mean(
-        forward(p, CFG, tokens=toks, gates=gates)[0] ** 2))(params)
-    gp = jax.grad(lambda p: jnp.mean(
-        packed_forward_mb(p, CFG, toks, arrays, M)[0] ** 2))(params)
-    for a, b in zip(jax.tree.leaves(gm), jax.tree.leaves(gp)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
-                                   rtol=1e-3)

@@ -407,6 +407,7 @@ def test_finetune_distributed_replans_per_refresh():
     from repro.configs.base import D2FTConfig
     from repro.data.synthetic import lm_batches
     from repro.launch.mesh import make_data_mesh
+    from repro.launch.parallel import MeshSpec, ParallelConfig
     from repro.optim.optimizers import sgd
     from repro.train.loop import finetune_distributed
 
@@ -422,7 +423,9 @@ def test_finetune_distributed_replans_per_refresh():
         batches = lm_batches(0, cfg.vocab_size, 8, 8, 5)
         p, _, log = finetune_distributed(
             params, cfg, d2, sgd(1e-2), batches, steps=5, mesh=mesh,
-            sync_mode=sync_mode, refresh_every=2)
+            parallel=ParallelConfig(mesh=MeshSpec(data=1),
+                                    sync_mode=sync_mode),
+            refresh_every=2)
         refreshes = log.extras["refreshes"]
         assert [r["step"] for r in refreshes] == [0, 2, 4]
         for r in refreshes:
@@ -475,6 +478,7 @@ def test_streamed_residency_counter_matches_model():
     from repro.core.schedule import gates_from_schedule
     from repro.data.synthetic import lm_batches, microbatch_assignment
     from repro.launch.mesh import make_data_mesh
+    from repro.launch.parallel import ParallelConfig
     from repro.optim.optimizers import sgd
     from repro.sharding.sync import (ResidencyRecorder,
                                      check_zero3_residency)
@@ -486,9 +490,10 @@ def test_streamed_residency_counter_matches_model():
     opt = sgd(1e-2)
     rec = ResidencyRecorder()
     step = make_distributed_train_step(
-        CFG, opt, make_data_mesh(1), plan, sync_mode="zero3",
-        params=params, streamed=True, opt_chunk=64,
-        residency_recorder=rec)
+        CFG, opt, make_data_mesh(1), plan,
+        parallel=ParallelConfig(sync_mode="zero3", streamed=True,
+                                opt_chunk=64),
+        params=params, residency_recorder=rec)
     batch = next(lm_batches(0, CFG.vocab_size, 8, 8, 1))
     gates = gates_from_schedule(sched, microbatch_assignment(8, N))
     shards = zero_reshard(params, None, plan)
@@ -504,6 +509,7 @@ def test_streamed_mode_validation():
     with the pre-sync NaN guard (the reduce-scatters are fused into the
     backward, so there is no point where local grads exist to zero)."""
     from repro.launch.mesh import make_data_mesh
+    from repro.launch.parallel import ParallelConfig
     from repro.optim.optimizers import sgd
     from repro.train.loop import make_distributed_train_step
 
@@ -512,14 +518,15 @@ def test_streamed_mode_validation():
     mesh = make_data_mesh(1)
     plan = grad_sync_plan(params, CFG, sched, mode="zero3", n_shards=1)
     with pytest.raises(ValueError, match="guard"):
-        make_distributed_train_step(CFG, sgd(1e-2), mesh, plan,
-                                    sync_mode="zero3", params=params,
-                                    streamed=True, guard=True)
+        make_distributed_train_step(
+            CFG, sgd(1e-2), mesh, plan, params=params,
+            parallel=ParallelConfig(sync_mode="zero3", streamed=True,
+                                    guard=True))
     plan_m = grad_sync_plan(params, CFG, sched, mode="masked")
     with pytest.raises(AssertionError):
-        make_distributed_train_step(CFG, sgd(1e-2), mesh, plan_m,
-                                    sync_mode="masked", params=params,
-                                    streamed=True)
+        make_distributed_train_step(
+            CFG, sgd(1e-2), mesh, plan_m, params=params,
+            parallel=ParallelConfig(sync_mode="masked", streamed=True))
 
 
 def test_zero3_overlap_report_model():

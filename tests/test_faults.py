@@ -23,6 +23,7 @@ from repro.core.schedule import P_F, P_O, P_S, Schedule
 from repro.data.synthetic import lm_batches
 from repro.launch.faults import NO_FAULTS, FaultPlan, random_fault_plan
 from repro.launch.mesh import make_data_mesh
+from repro.launch.parallel import ParallelConfig
 from repro.models.transformer import init_model
 from repro.optim.optimizers import adamw, sgd
 from repro.sharding.sync import (grad_sync_plan, lofi_merge, stack_replicas)
@@ -175,7 +176,7 @@ def test_elastic_no_faults_matches_distributed():
     for mode in ("masked", "zero", "zero3"):
         ref, _, _ = finetune_distributed(
             _params(), CFG, D2, sgd(0.1), _batches(5), steps=4, mesh=mesh,
-            sync_mode=mode, refresh_every=3)
+            parallel=ParallelConfig(sync_mode=mode), refresh_every=3)
         el = ElasticConfig(refresh_every=3, ckpt_every=0,
                            ckpt_dir=tempfile.mkdtemp())
         got, _, _ = finetune_elastic(

@@ -341,3 +341,31 @@ def test_llm_loss_kernel_matches_masked_path():
     diffs = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
                          out[False][1], out[True][1])
     assert max(jax.tree.leaves(diffs)) < 5e-4
+
+
+@pytest.mark.parametrize("d2ft", [True, False], ids=["d2ft", "full"])
+def test_finetune_kernel_matches_masked_path(d2ft):
+    """The LM loop ``finetune``, kernel vs masked path over 3 steps: the
+    same losses and final params, with a planned D2FT schedule (the kernel
+    path's live-slice bounds and compaction dispatch) and without one."""
+    from repro.configs.base import D2FTConfig, ModelConfig
+    from repro.data.synthetic import lm_batches
+    from repro.models.transformer import init_model
+    from repro.optim.optimizers import sgd
+    from repro.train.loop import finetune
+
+    cfg = ModelConfig(name="t", arch_type="dense", n_layers=2, d_model=32,
+                      n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=64)
+    d2 = D2FTConfig(n_microbatches=4, n_pf=2, n_po=1, head_groups=4) \
+        if d2ft else None
+    out = {}
+    for uk in (False, True):
+        params = init_model(jax.random.PRNGKey(0), cfg)
+        batches = lm_batches(0, cfg.vocab_size, batch=8, seq=16, steps=3)
+        out[uk] = finetune(params, cfg, d2, sgd(0.1), batches, steps=3,
+                           use_kernel=uk)
+    (p_m, _, log_m), (p_k, _, log_k) = out[False], out[True]
+    assert len(log_k.losses) == 3
+    np.testing.assert_allclose(log_k.losses, log_m.losses, atol=1e-5)
+    diffs = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()), p_m, p_k)
+    assert max(jax.tree.leaves(diffs)) < TOL

@@ -1,6 +1,6 @@
 """Unit tests for the unified multi-axis parallelism API: MeshSpec /
-ParallelConfig validation, the deprecation shim on the train-step
-factories, the schedule-aware pipeline stage assigner and the bubble
+ParallelConfig validation, ``parallel=`` as the train-step factories'
+one way in, the schedule-aware pipeline stage assigner and the bubble
 model. The 8-device multi-axis parity (collectives actually moving
 bytes) is the ``multidevice``-marked subprocess test at the bottom
 (tests/_dist_parity_multiaxis.py)."""
@@ -130,9 +130,11 @@ def test_parallel_config_validate_mesh():
     ParallelConfig().validate_mesh(legacy)
 
 
-# --------------------------------------------------------- deprecation shim
+# ------------------------------------------------- one way to set options
 def test_deprecated_kwargs_still_work():
-    """Old loose kwargs run the same step, under a DeprecationWarning."""
+    """What callers of the removed loose kwargs still get: ``parallel=None``
+    builds the config from the mesh's axis sizes, and both it and an
+    explicit ``ParallelConfig`` run the same step as ``make_train_step``."""
     sched = _schedule()
     params = init_model(jax.random.PRNGKey(0), CFG)
     batch = next(lm_batches(0, CFG.vocab_size, B, S, 1))
@@ -141,31 +143,57 @@ def test_deprecated_kwargs_still_work():
     opt = sgd(1e-2)
     from repro.launch.mesh import make_data_mesh
     mesh = make_data_mesh(1)
-    with pytest.warns(DeprecationWarning, match="ParallelConfig"):
-        step_old = make_distributed_train_step(CFG, opt, mesh, plan,
-                                               sync_mode="masked")
-    step_new = make_distributed_train_step(
+    step_mesh = make_distributed_train_step(CFG, opt, mesh, plan)
+    step_conf = make_distributed_train_step(
         CFG, opt, mesh, plan, parallel=ParallelConfig(mesh=MeshSpec(data=1)))
     ref = jax.jit(make_train_step(CFG, opt, use_gates=True))
-    p_o, s_o, _ = step_old(params, opt.init(params), batch, gates)
-    p_n, s_n, _ = step_new(params, opt.init(params), batch, gates)
-    p_r, s_r, _ = ref(params, opt.init(params), batch, gates)
-    for p in (p_o, p_n):
+    p_m, _, _ = step_mesh(params, opt.init(params), batch, gates)
+    p_c, _, _ = step_conf(params, opt.init(params), batch, gates)
+    p_r, _, _ = ref(params, opt.init(params), batch, gates)
+    for p in (p_m, p_c):
         diff = max(jax.tree.leaves(jax.tree.map(
             lambda x, y: float(jnp.abs(x - y).max()), p, p_r)))
         assert diff <= 1e-6, diff
 
 
-def test_mixing_parallel_and_deprecated_kwargs_raises():
-    sched = _schedule()
-    params = init_model(jax.random.PRNGKey(0), CFG)
-    plan = grad_sync_plan(params, CFG, sched)
+@pytest.mark.parametrize("names,sizes,want", [
+    (("data",), (1,), MeshSpec()),
+    (("data", "stage", "tensor"), (4, 1, 2), MeshSpec(data=4, tensor=2)),
+    (("data", "model"), (2, 4), MeshSpec(data=2)),
+], ids=["data", "three-axes", "gspmd-host"])
+def test_parallel_none_takes_the_mesh_axis_sizes(names, sizes, want):
+    """With ``parallel=None`` the entries run the mesh's (data, stage,
+    tensor) sizes with default options; axes of other names (the GSPMD
+    ``model`` axis) are not the distributed step's."""
+    from jax.sharding import AbstractMesh
+
+    from repro.train.loop import _mesh_parallel
+    got = _mesh_parallel(AbstractMesh(sizes, names))
+    assert got == ParallelConfig(mesh=want)
+
+
+@pytest.mark.parametrize("entry", ["make_distributed_train_step",
+                                   "finetune_distributed"])
+def test_loose_option_kwargs_are_rejected(entry):
+    """``parallel=`` is the only way to set the distributed options: a
+    loose ``sync_mode=`` is an unknown argument, not a deprecated one."""
+    from repro.configs.base import D2FTConfig
     from repro.launch.mesh import make_data_mesh
+    from repro.train import loop
+
+    params = init_model(jax.random.PRNGKey(0), CFG)
     mesh = make_data_mesh(1)
-    with pytest.raises(TypeError, match="not both"):
-        make_distributed_train_step(CFG, sgd(1e-2), mesh, plan,
-                                    parallel=ParallelConfig(),
-                                    sync_mode="masked")
+    plan = grad_sync_plan(params, CFG, _schedule())
+    calls = {
+        "make_distributed_train_step": lambda: loop.make_distributed_train_step(
+            CFG, sgd(1e-2), mesh, plan, sync_mode="masked"),
+        "finetune_distributed": lambda: loop.finetune_distributed(
+            params, CFG, D2FTConfig(n_microbatches=N), sgd(1e-2),
+            lm_batches(0, CFG.vocab_size, B, S, 1), steps=1, mesh=mesh,
+            sync_mode="masked"),
+    }
+    with pytest.raises(TypeError, match="sync_mode"):
+        calls[entry]()
 
 
 # -------------------------------------------------------- stage assignment

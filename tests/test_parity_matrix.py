@@ -89,6 +89,7 @@ def test_parity_matrix(path, setup, reference):
                                        use_kernel=True, live_bounds=bounds))
     else:
         from repro.launch.mesh import make_data_mesh
+        from repro.launch.parallel import MeshSpec, ParallelConfig
         mesh = make_data_mesh(1)
         mode = {"dist_masked": "masked", "dist_zero": "zero",
                 "dist_zero3": "zero3",
@@ -98,11 +99,11 @@ def test_parity_matrix(path, setup, reference):
         streamed = path == "dist_zero3_streamed"
         plan = grad_sync_plan(params, CFG, sched, mode=mode, n_shards=1,
                               elide_gather=opt.elidable)
-        step = make_distributed_train_step(CFG, opt, mesh, plan,
-                                           sync_mode=mode, params=params,
-                                           streamed=streamed,
-                                           opt_chunk=(48 if streamed
-                                                      else None))
+        step = make_distributed_train_step(
+            CFG, opt, mesh, plan, params=params,
+            parallel=ParallelConfig(mesh=MeshSpec(data=1), sync_mode=mode,
+                                    streamed=streamed,
+                                    opt_chunk=48 if streamed else None))
         if mode == "zero3":
             # zero3 holds the params in the plan's shard layout between
             # steps; run layout-in, layout-out and compare canonically

@@ -1,4 +1,4 @@
-"""Schedule construction, gates, packed indices, cost model."""
+"""Schedule construction, gates, cost model."""
 import numpy as np
 
 from repro.configs.base import D2FTConfig
@@ -8,7 +8,7 @@ from repro.core.d2ft import capacities, plan_schedule
 from repro.core.baselines import (dpruning_schedule, gshard_schedule,
                                   random_schedule)
 from repro.core.schedule import (P_F, P_O, P_S, gates_from_schedule,
-                                 op_counts, packed_indices)
+                                 op_counts)
 
 
 def _sched(L=4, G=4, N=5, n_pf=3, n_po=1, seed=0):
@@ -47,18 +47,6 @@ def test_gates_shapes_and_semantics():
                 op = t[l, g, mb_of[b]]
                 assert float(g_b[l, b, g]) == (1.0 if op == P_F else 0.0)
                 assert float(g_f[l, b, g]) == (1.0 if op != P_S else 0.0)
-
-
-def test_packed_indices_cover_selected_samples():
-    sched, _ = _sched(L=2, G=2, N=5)
-    mb_of = np.repeat(np.arange(5), 2)
-    idx, bwd, val, C_f = packed_indices(sched, mb_of)
-    t = sched.layer_group_view()
-    for l in range(2):
-        for g in range(2):
-            selected = set(np.nonzero(t[l, g, mb_of] != P_S)[0].tolist())
-            got = set(idx[l, g][val[l, g] > 0].tolist())
-            assert got == selected
 
 
 def test_random_baseline_unbalanced_dpruning_balanced_full():

@@ -3,9 +3,13 @@
 1. D2FT at a 60-70% compute budget fine-tunes better than Random scheduling
    at the same budget (Fig. 1/2 ordering).
 2. D2FT workload variance is 0; Random/GShard > 0 (Table I).
-3. The packed deployment path trains equivalently to the masked path.
-4. Sharded-model parity: the policy-constrained model on a host mesh equals
+3. Sharded-model parity: the policy-constrained model on a host mesh equals
    the unsharded model (run in a subprocess with fake devices).
+4. The dry run's perf levers (padded heads, q-chunked attention) are exact
+   rewrites.
+
+The kernel path's equivalence to the masked reference is
+tests/test_kernel_grads.py's.
 """
 import subprocess
 import sys
